@@ -31,10 +31,3 @@ Package layout
 """
 
 __version__ = "0.1.0"
-
-# jax-version compatibility shims (utils/compat.py): on jax 0.4.x runtimes
-# this installs ``jax.shard_map``/``jax.lax.pcast`` aliases over the
-# experimental-namespace ancestors so the jax>=0.9-targeted call sites run
-# unchanged; a no-op on jax>=0.9.  Must happen at package import, before
-# any step builder references the new names.
-from .utils import compat as _compat  # noqa: E402,F401

@@ -47,7 +47,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from .findings import Finding, make_finding
-from .jaxpr_audit import COLLECTIVE_PRIMITIVES, MIB, _sub_jaxprs
+from .jaxpr_audit import (COLLECTIVE_PRIMITIVES, MIB, _sub_jaxprs,
+                          primitive_name)
 
 # Pure data-movement / metadata primitives: zero flops (bytes still count).
 _ZERO_FLOP = frozenset((
@@ -199,7 +200,7 @@ def _collective_axes(eqn) -> Tuple[str, ...]:
 
 
 def cost_of_eqn(eqn) -> Cost:
-    name = eqn.primitive.name
+    name = primitive_name(eqn)
     if name == "scan":
         body = cost_of_jaxpr(eqn.params["jaxpr"].jaxpr)
         return body.scaled(int(eqn.params["length"]))

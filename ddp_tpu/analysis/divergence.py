@@ -62,11 +62,11 @@ COLLECTIVE_CALLS = frozenset((
     "epoch_straggler_record", "_gather_host_rows",
 ))
 
-# Calls whose result is identical on every host: mesh topology reads and
-# the runtime-semantics probe.  (``process_index`` is deliberately NOT
-# here — a rank check is the canonical divergent condition.)
+# Calls whose result is identical on every host: mesh topology reads.
+# (``process_index`` is deliberately NOT here — a rank check is the
+# canonical divergent condition.)
 UNIFORM_CALLS = frozenset(("process_count", "device_count",
-                           "local_device_count", "vma_semantics"))
+                           "local_device_count"))
 
 _OK_RE = re.compile(r"#\s*analysis:\s*divergence-ok\(([^)]*)\)")
 

@@ -9,8 +9,7 @@ equation parameter that holds a sub-jaxpr (``pjit``, ``shard_map``,
 where all the interesting equations live: a jitted shard_map program's
 top level is a single ``pjit`` equation.
 
-Primitive-name facts this encodes (verified on the jax 0.4.x compat
-runtime AND stable on jax>=0.9): ``lax.pmean`` lowers to ``psum`` + div,
+Primitive-name facts this encodes: ``lax.pmean`` lowers to ``psum`` + div,
 so gradient pmeans inventory as ``psum``; the psum equation carries its
 axis names in ``params["axes"]``, while ``all_gather`` / ``reduce_scatter``
 / ``ppermute`` carry ``params["axis_name"]``; ``lax.psum_scatter`` is the
@@ -35,6 +34,16 @@ from .findings import Finding, make_finding
 COLLECTIVE_PRIMITIVES = ("psum", "pmin", "pmax", "all_gather",
                         "reduce_scatter", "ppermute", "all_to_all",
                         "pbroadcast")
+
+
+
+def primitive_name(eqn) -> str:
+    """The equation's primitive, with the vma type system's variants
+    folded into the collective they lower to: under ``check_vma=True`` a
+    ``lax.psum`` of a varying value traces as ``psum_invariant`` (and
+    ``all_gather`` as ``all_gather_invariant``) — same traffic."""
+    return eqn.primitive.name.removesuffix("_invariant")
+
 
 MIB = 2 ** 20
 LARGE_CONST_BYTES = 1 * MIB     # constant-capture bloat threshold
@@ -78,8 +87,8 @@ def collective_inventory(closed_jaxpr) -> Dict[Tuple[str, Tuple[str, ...]],
     """``{(primitive, named axes): count}`` over the whole program."""
     inv: collections.Counter = collections.Counter()
     for eqn in iter_eqns(closed_jaxpr.jaxpr):
-        if eqn.primitive.name in COLLECTIVE_PRIMITIVES:
-            inv[(eqn.primitive.name, _axes_of(eqn))] += 1
+        if primitive_name(eqn) in COLLECTIVE_PRIMITIVES:
+            inv[(primitive_name(eqn), _axes_of(eqn))] += 1
     return dict(inv)
 
 

@@ -42,7 +42,7 @@ from typing import Dict, List, Tuple
 from .costmodel import _var_bytes
 
 # Single-equation wrappers the body finder descends through.
-_WRAPPER_PRIMITIVES = ("pjit", "shard_map", "closed_call", "core_call",
+_WRAPPER_PRIMITIVES = ("jit", "shard_map", "closed_call", "core_call",
                        "remat", "checkpoint")
 
 

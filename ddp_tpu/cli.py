@@ -22,14 +22,6 @@ import time
 from typing import Optional
 
 import jax
-
-# Honor an explicit platform pin before any backend init — without it a
-# --spawn child told to run on the CPU backend would silently grab the TPU
-# (plugin platforms override JAX_PLATFORMS; see utils/platform.py).
-from .utils.platform import pin_platform_from_env
-
-pin_platform_from_env()
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,6 +32,7 @@ from .parallel import dist, make_mesh
 from .train import Trainer, evaluate
 from .utils import MiB, get_model_size
 from .utils.metrics import MetricsLogger
+from .utils.platform import device_line, enable_compile_cache
 
 
 def build_parser(description: str) -> argparse.ArgumentParser:
@@ -127,9 +120,10 @@ def build_parser(description: str) -> argparse.ArgumentParser:
     p.add_argument("--spawn", default=0, type=int, metavar="N",
                    help="Fork N local processes wired by a fresh rendezvous "
                         "and run this exact command in each (the reference's "
-                        "mp.spawn fan-out, multigpu.py:262-263); device "
-                        "visibility per process is the caller's (env) "
-                        "concern")
+                        "mp.spawn fan-out, multigpu.py:262-263).  For "
+                        "multi-process drills on a virtual CPU mesh only: "
+                        "on a TPU host one process drives every local chip, "
+                        "so --spawn refuses there")
     p.add_argument("--metrics_path", default=None,
                    help="Append per-step {step, epoch, loss, lr, wall_s} "
                         "JSON lines here (the loss stream the reference "
@@ -397,6 +391,14 @@ def main(args: argparse.Namespace, *, num_devices: Optional[int]) -> None:
     that is already a spawned child (rendezvous env set) never re-spawns —
     the backstop against any recursion."""
     if args.spawn and "DDP_TPU_PROCESS_ID" not in os.environ:
+        # The one backend touch a --spawn parent makes: on CPU it costs
+        # the children nothing; on a TPU no child is started at all.
+        if jax.default_backend() == "tpu":
+            raise SystemExit(
+                "--spawn refuses on a TPU host: every child would claim all "
+                "local chips and all but one would fail or hang; run one "
+                "process per host (python multigpu.py drives every local "
+                "chip) or set JAX_PLATFORMS=cpu for a multi-process drill")
         raise SystemExit(spawn_local(args.spawn))
     if args.audit and "DDP_TPU_PROCESS_ID" not in os.environ:
         _preflight_audit(args)
@@ -509,37 +511,6 @@ def _export_torch(model_name: str, path: str, trainer) -> None:
     print(f"Torch state_dict exported to {path}")
 
 
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache (~/.cache/ddp_tpu/xla).
-
-    First compile of the VGG train step is ~8s on TPU (tens of seconds for
-    the scan-epoch program); caching the serialized executables makes every
-    later invocation of the CLI start hot.  The reference has no analogue —
-    torch eager rebuilds cuDNN autotuning state per process.  Off via
-    DDP_TPU_COMPILATION_CACHE=0 (e.g. read-only home directories).
-    """
-    import os
-    if os.environ.get("DDP_TPU_COMPILATION_CACHE", "1") == "0":
-        return
-    from .utils.compat import persistent_cache_safe
-    if not persistent_cache_safe():
-        # jax-0.4.x images: deserialized XLA:CPU executables poison the
-        # heap for later torch ops (--init_from_torch/--export_torch run
-        # torch in THIS process) — compile fresh there (see
-        # utils/compat.py::persistent_cache_safe for the measurement).
-        return
-    cache = os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.join(os.path.expanduser("~"), ".cache")),
-        "ddp_tpu", "xla")
-    try:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except (OSError, AttributeError):
-        pass  # unwritable cache dir or older jax: run without the cache
-
-
 def run(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     """Train + report, reference ``main()`` order (multigpu.py:224-250):
     setup -> objs -> loader -> train -> time print -> size print -> eval ->
@@ -605,7 +576,7 @@ def _hard_exit(code: int) -> None:  # monkeypatch seam for tests
 def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     """The reference ``main()`` body proper (multigpu.py:224-248), between
     rendezvous and teardown — both owned by :func:`run`."""
-    _enable_compilation_cache()
+    enable_compile_cache()
     # A searched plan doc (--auto_plan) IS the mesh/zero configuration:
     # the search already chose the shape and the ZeRO setting, so the doc
     # drives both and any redundant flags must agree rather than win.
@@ -666,6 +637,14 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     # axis replicates the batch (parallel/mesh.py:data_axis_size).
     from .parallel.mesh import data_axis_size
     n_replicas = data_axis_size(mesh)
+    # The native kernel serves host augmentation only; a run that
+    # augments on device never builds it.
+    if args.device_augment or args.resident:
+        native_augment = "n/a"
+    else:
+        from .data import native
+        native_augment = "on" if native.get_lib() is not None else "off"
+    print(device_line(mesh, native_augment=native_augment), flush=True)
 
     if args.synthetic:
         train_ds, test_ds = cifar10.synthetic(
@@ -711,8 +690,11 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     elif args.mesh_shape:
         from .parallel.mesh import model_axis_size
         from .parallel.tp.plan import format_plan_table, plan_for_model
-        tp_plan = plan_for_model(args.model, params, batch_stats,
-                                 model_size=model_axis_size(mesh))
+        try:
+            tp_plan = plan_for_model(args.model, params, batch_stats,
+                                     model_size=model_axis_size(mesh))
+        except ValueError as e:
+            raise SystemExit(f"--mesh_shape: {e}")
         if jax.process_index() == 0:
             print(format_plan_table(tp_plan))
 

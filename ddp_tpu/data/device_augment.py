@@ -8,9 +8,10 @@ noise next to the convolutions.
 
 The crop+flip is expressed as two one-hot MATMULS (row-select, then
 col-select with the flip folded in) rather than a gather: XLA:TPU lowers
-per-sample advanced-indexing gathers to a slow generic gather (~6 ms per
-512 images on v5e), while the equivalent one-hot einsum rides the MXU at
-~1 ms.  Out-of-range one-hot rows are all-zero, which supplies the
+per-sample advanced-indexing gathers to a generic gather, while the
+equivalent one-hot einsum rides the MXU (cost of either on the chip: not
+measured on this stack).  Out-of-range one-hot rows are all-zero, which
+supplies the
 reference's zero padding (torchvision RandomCrop fill=0) for free.  The
 selection is numerically exact (each output pixel is 1*value + 0*rest with
 fp32 accumulation), so the result is cast back to the input dtype
@@ -48,9 +49,8 @@ def gather_crop_flip(rng: jax.Array, table: jax.Array,
 
     ``table`` is the whole resident dataset ``[M,32,32,3]``; the batch is
     pulled by the Pallas DMA row gather (ops/gather.py) and augmented by
-    the one-hot matmuls below — together ~2 ms per 512 images on v5e
-    against ~7.6 ms for the fused clamped-gather formulation this
-    replaces."""
+    the one-hot matmuls below, in place of a fused clamped-gather
+    formulation."""
     return _crop_flip_onehot(rng, gather_rows(table, idx_row))
 
 

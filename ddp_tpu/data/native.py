@@ -9,9 +9,13 @@ Python.h dependency.
 
 The Python side draws all randomness (data/augment.py) and passes the
 offsets in, so the native path is bit-identical to the numpy path and can
-be swapped freely; ``DDP_TPU_NATIVE=0`` disables it, and any build failure
-falls back to numpy silently (the .so is a throughput optimisation, not a
-semantic dependency).
+be swapped freely; ``DDP_TPU_NATIVE=0`` disables it.  A failed build is not
+fatal (the .so is a throughput optimisation, not a semantic dependency) but
+it is said once on stderr with the compiler's message, and the trainer's
+start-up line carries ``native_augment=on|off`` (``n/a`` for a run that
+augments on device and never builds it).  The .so is keyed by the
+hash of its source and built inside the checkout (utils/platform.py), so
+a machine that discards the home directory still finds it.
 """
 from __future__ import annotations
 
@@ -19,28 +23,27 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 from typing import Optional
 
 import numpy as np
+
+from ..utils.platform import NATIVE_BUILD_DIR
 
 _SRC = os.path.join(os.path.dirname(__file__), "_native", "crop_flip.cpp")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build_and_load() -> Optional[ctypes.CDLL]:
+def _build_and_load() -> ctypes.CDLL:
     with open(_SRC, "rb") as f:
         src = f.read()
     tag = hashlib.sha256(src).hexdigest()[:16]
-    cache_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.join(os.path.expanduser("~"), ".cache")),
-        "ddp_tpu")
-    so_path = os.path.join(cache_dir, f"crop_flip_{tag}.so")
+    so_path = os.path.join(NATIVE_BUILD_DIR, f"crop_flip_{tag}.so")
     if not os.path.exists(so_path):
-        os.makedirs(cache_dir, exist_ok=True)
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=cache_dir)
+        os.makedirs(NATIVE_BUILD_DIR, exist_ok=True)
+        fd, tmp = tempfile.mkstemp(suffix=".so", dir=NATIVE_BUILD_DIR)
         os.close(fd)
         base = ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", tmp]
         for extra in (["-fopenmp"], []):  # OpenMP if available
@@ -48,11 +51,13 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 subprocess.run(base[:-2] + extra + base[-2:], check=True,
                                capture_output=True, timeout=120)
                 break
-            except (subprocess.SubprocessError, FileNotFoundError):
-                continue
+            except subprocess.CalledProcessError as e:
+                why = e.stderr.decode(errors="replace").strip()
+            except (subprocess.TimeoutExpired, FileNotFoundError) as e:
+                why = str(e)
         else:
             os.unlink(tmp)
-            return None
+            raise OSError(why)
         os.replace(tmp, so_path)  # atomic: concurrent builders race safely
     lib = ctypes.CDLL(so_path)
     lib.crop_flip_u8.argtypes = [
@@ -64,15 +69,16 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded kernel library, building it on first call; None if the
-    toolchain is unavailable or ``DDP_TPU_NATIVE=0``."""
+    build failed (said once on stderr) or ``DDP_TPU_NATIVE=0``."""
     global _lib, _tried
     if not _tried:
         _tried = True
         if os.environ.get("DDP_TPU_NATIVE", "1") != "0":
             try:
                 _lib = _build_and_load()
-            except OSError:
-                _lib = None
+            except OSError as e:
+                print("native augment kernel unavailable, host augmentation "
+                      f"runs in numpy: {e}", file=sys.stderr)
     return _lib
 
 

@@ -27,7 +27,7 @@ Contracts the tests pin (tests/test_prefetch.py):
 host busy time (materialise + augment), H2D enqueue time, and consumer
 wait time (the dispatch gap: how long the device-feeding loop sat waiting
 for a batch that was not ready).  ``bench.py --stream_attr`` builds the
-BASELINE.md streaming-gap table from these plus the tracer's span record
+streaming-gap table from these plus the tracer's span record
 (utils/profiling.py:attribute_streaming).
 
 Telemetry (round 7): every stage also reports into the run's span tracer
@@ -62,8 +62,8 @@ class PrefetchStats:
 
     ``host_s``  — producer time materialising/augmenting batches (sums
     across pool workers, so it can exceed wall time when workers overlap);
-    ``h2d_s``   — time in ``shard_batch`` (device_put enqueue; on CPU and
-    through remote-device tunnels this is where the copy cost lands);
+    ``h2d_s``   — time in ``shard_batch`` (device_put enqueue; on CPU
+    this is where the copy cost lands);
     ``wait_s``  — consumer time blocked waiting for a batch that was not
     ready: the measured pipeline bubble.  ``wait_s`` ~ 0 with the engine
     keeping up means the input pipeline is fully hidden behind compute —

@@ -5,12 +5,12 @@ MetricsLogger` (JSONL + TensorBoard) every ``--log_every`` steps.
 This is the always-on answer to "is the run healthy *right now*" —
 median/p90 step time over a rolling window (p90 >> median is the local
 straggler/input-stall signature), achieved samples/sec, MFU against the
-measured MXU peak when the model has a FLOP model, and the prefetch
+device kind's table peak when the model has a FLOP model, and the prefetch
 engine's occupancy (consumer wait ≈ 0 means the input pipeline is fully
 hidden behind compute).  The offline twin — exact per-step attribution —
 is the span spill (obs/tracer.py + ``python -m ddp_tpu.obs``).
 
-The FLOP model and measured-peak tables live HERE (single home);
+The FLOP model and the peak table live HERE (single home);
 bench.py imports them for its offline MFU records, so the live and
 bench numbers can never disagree on the denominator.
 """
@@ -19,17 +19,13 @@ from __future__ import annotations
 from collections import deque
 from typing import Dict, Optional
 
-# MFU is reported against the bf16-pass MXU peak MEASURED on the chip
-# family actually running — the right denominator for fp32 too, because
-# the fp32 path's convs also run as single-pass bf16-input/fp32-accum
-# MXU passes (BASELINE.md).  A device kind with no entry gets a
-# runtime-probed peak (:func:`probed_peak_tflops`) instead of a silent
-# null: the old behaviour omitted MFU entirely off-TPU, which left every
-# ``bench.py --tp_sweep`` cell with ``"mfu": null`` on the CPU boxes the
-# committed BENCH records come from.  The probe is still a MEASURED
-# denominator — never a datasheet guess, per ADVICE r4; ``mfu_peak``
-# reports which kind fed the number so records can say so.
-PEAK_TFLOPS_BF16_PASS = {"TPU v5 lite": 197.0}  # measured, BASELINE.md
+# MFU denominator: the published bf16 peak of one chip, keyed by
+# ``jax.devices()[0].device_kind`` — the right denominator for fp32 too,
+# because the fp32 path's convs also run as single-pass bf16-input/
+# fp32-accum MXU passes.  This table is the ONLY source: a device kind
+# that is not in it gets no MFU field.
+# v5e: 197 TFLOP/s bf16 (Google Cloud documentation, "TPU v5e").
+PEAK_TFLOPS_BF16_PASS = {"TPU v5 lite": 197.0}
 
 # Per-sample train FLOPs, derived per model from the SAME cost model
 # BUDGETS.json gates (analysis/costmodel.py counts the fwd+bwd heavy
@@ -81,83 +77,17 @@ def train_gflop_per_sample(model_name: Optional[str]) -> Optional[float]:
     return gflop
 
 
-# Runtime-probed matmul peak per device kind, TFLOP/s.  None caches a
-# failed probe so a broken backend costs one attempt per process.
-_PROBED_PEAK: Dict[str, Optional[float]] = {}
-
-
-def probed_peak_tflops(device_kind: Optional[str] = None
-                       ) -> Optional[float]:
-    """Best-of-N square-matmul throughput of ONE device of ``device_kind``
-    (default: the default backend's first device), in TFLOP/s — the MFU
-    denominator fallback for device kinds absent from the offline
-    ``PEAK_TFLOPS_BF16_PASS`` table.  bf16 inputs with fp32 accumulation
-    (the MXU pass the table's peaks were measured in) except on the CPU
-    backend, where bf16 matmul is an emulated slow path and fp32 is the
-    honest machine peak.  Cached per kind per process; ~0.5 s once."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    try:
-        dev = None
-        if device_kind:
-            dev = next((d for d in jax.devices()
-                        if d.device_kind == device_kind), None)
-            if dev is None:
-                return None
-        else:
-            dev = jax.devices()[0]
-        kind = dev.device_kind
-        if kind in _PROBED_PEAK:
-            return _PROBED_PEAK[kind]
-        n = 1024
-        dtype = jnp.float32 if dev.platform == "cpu" else jnp.bfloat16
-        x = jax.device_put(jnp.ones((n, n), dtype), dev)
-
-        @jax.jit
-        def mm(a):
-            return jax.lax.dot(a, a,
-                               preferred_element_type=jnp.float32)
-
-        mm(x).block_until_ready()  # compile outside the timed window
-        best = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            mm(x).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        peak = 2.0 * n ** 3 / best / 1e12
-    except Exception:  # no MFU beats a crashing one
-        peak = None
-        kind = device_kind or ""
-    _PROBED_PEAK[kind] = peak
-    return peak
-
-
-def mfu_peak(device_kind: Optional[str]) -> Optional[tuple]:
-    """The MFU denominator for a device kind: ``(tflops, source)`` where
-    source is ``"measured"`` (offline table) or ``"probed"`` (runtime
-    matmul probe); None when neither exists."""
-    peak = PEAK_TFLOPS_BF16_PASS.get(device_kind or "")
-    if peak is not None:
-        return peak, "measured"
-    peak = probed_peak_tflops(device_kind)
-    if peak is not None:
-        return peak, "probed"
-    return None
-
-
 def model_mfu(samples_per_sec_per_chip: float, model: Optional[str],
               device_kind: Optional[str]) -> Optional[float]:
     """MFU for a measured per-chip rate: counted-jaxpr FLOPs achieved
-    per second over the device's peak (offline-measured, else
-    runtime-probed — :func:`mfu_peak`).  None only when the model cannot
-    be FLOP-counted or no peak is obtainable at all."""
+    per second over the device kind's peak in ``PEAK_TFLOPS_BF16_PASS``.
+    None when the model cannot be FLOP-counted or the kind is not in the
+    table."""
     gflop = train_gflop_per_sample(model)
-    peak = mfu_peak(device_kind)
+    peak = PEAK_TFLOPS_BF16_PASS.get(device_kind or "")
     if gflop is None or peak is None:
         return None
-    return samples_per_sec_per_chip * gflop * 1e9 / (peak[0] * 1e12)
+    return samples_per_sec_per_chip * gflop * 1e9 / (peak * 1e12)
 
 
 class LiveStats:

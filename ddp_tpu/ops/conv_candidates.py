@@ -1,13 +1,14 @@
 """Alternative 3x3 SAME-conv implementations for the conv-probe seam.
 
-BASELINE.md's round-3 kernel-substitution analysis concluded "a hand kernel
+The round-3 kernel-substitution analysis concluded "a hand kernel
 can't win under fp32 semantics" from a fusion-barrier argument plus emitter
 measurements — but the probe's pluggable ``conv=`` seam
 (:func:`~ddp_tpu.ops.conv_probe.probe`) never had an actual candidate
 plugged in (VERDICT r3 missing #3).  This module supplies three real
 candidates and a CLI to measure them under the identical marginal-cost
-harness, targeting the two sub-peak shapes (32x32 64->128 trains at
-~96 TFLOP/s; 8x8 256->512 at ~134, vs 170-195 elsewhere):
+harness, targeting the two shapes an earlier roofline flagged as
+sub-peak (32x32 64->128 and 8x8 256->512; rates not measured on this
+stack):
 
 - ``conv2d_shift9``: pure-lax shift-and-matmul — nine accumulated
   ``[N*H*W, Cin] @ [Cin, Cout]`` matmuls on 1-pixel-shifted views.  No
@@ -31,8 +32,8 @@ shifted-matmul einsum.  Measure with::
 
     python -m ddp_tpu.ops.conv_candidates [--bf16] [--all_shapes]
 
-One JSON line per (candidate, shape, direction) — the BASELINE.md
-evidence row, win or negative result.
+One JSON line per (candidate, shape, direction) — the evidence row, win
+or negative result.
 """
 from __future__ import annotations
 
@@ -193,10 +194,9 @@ def _with_vjp(fwd, bwd=None):
 conv2d_shift9 = _with_vjp(_shift9_fwd)
 conv2d_im2col = _with_vjp(_im2col_fwd)
 conv2d_pallas = _with_vjp(_pallas_fwd)
-# The hybrid an early single-candidate run suggested could win (Pallas
-# forward at an apparent 197.6 TFLOP/s vs 175.6).  The same-process
-# head-to-head (BASELINE.md round-4 table) shows it LOSING every cell —
-# that early delta was harness noise.  Kept as the measured negative.
+# The hybrid an early single-candidate run suggested could win; a later
+# same-process head-to-head had it losing every cell (records deleted,
+# not re-measured on this stack).  Kept as a candidate for the seam.
 conv2d_pallas_fwd_xla_bwd = _with_vjp(_pallas_fwd, bwd=_xla_bwd)
 
 CANDIDATES = {

@@ -96,10 +96,8 @@ def max_pool(x: jax.Array, window: int = 2, stride: int = 2,
 
     Deliberately the ``reduce_window`` form: a reshape-max alternative
     with an elementwise first-tie VJP (``ops/pool_candidates.py``)
-    measured 1.6x FASTER in isolation but 20% SLOWER at the whole-step
-    level (its window-view transposes force activation relayouts that
-    fight the conv layouts) — the recorded negative result in
-    BASELINE.md round 4 "pool backward candidate"."""
+    forces activation relayouts in the composed step (its window-view
+    transposes fight the conv layouts); not measured on this stack."""
     return lax.reduce_window(
         x, -jnp.inf, lax.max,
         window_dimensions=(1, window, window, 1),
@@ -178,8 +176,8 @@ def _bn_stats(xf: jax.Array, axis: Optional[str]):
     """Batch statistics in fp32 — the ONE encoding of the trade-off both
     :func:`batch_norm` and :func:`bn_relu` use: one-pass ``E[x^2]-E[x]^2``
     per-shard (XLA fuses both channel reductions into a single read of the
-    activation — BN is bandwidth-bound on TPU; measured +13% whole-step
-    for VGG/512 on v5e vs two-pass), or the better-conditioned centered
+    activation — BN is bandwidth-bound on TPU; the whole-step effect on
+    the chip is not measured on this stack), or the better-conditioned centered
     two-pass form when syncing over ``axis`` (under cancellation the
     one-pass form amplifies the psum's rounding ~10x more than centering
     does, verified against an f64 reference — sync-BN is opt-in, so the
@@ -218,12 +216,11 @@ def _bn_relu_train(eps: float, axis: Optional[str], grad_axis: Optional[str],
     alone, so the whole backward touches only ``(x, dz)``: one fused
     reduction pass (dβ, dγ) and one fused elementwise pass (dx) — the
     5-activation-pass minimum, exact fp32 math (the mask recompute is
-    bit-exact against the forward's own ŷ).  NB the hypothesis that
-    autodiff needed ~7-8 passes here (reading ``z`` for the mask and
-    materialising dŷ) was MEASURED FALSE on v5e: XLA:TPU reaches the same
-    structure by fusing the reductions into the conv epilogues, so this
-    op is perf-neutral and kept for the explicit structure + collective
-    semantics (BASELINE.md "fp32 kernel-level attack").
+    bit-exact against the forward's own ŷ).  NB autodiff does not need
+    more passes here: the optimized HLO shows XLA:TPU reaching the same
+    structure by fusing the reductions into the conv epilogues, so no
+    step-level gain is claimed (not measured on this stack) and the op is
+    kept for the explicit structure + collective semantics.
 
     Returns ``(z, batch_mean, unbiased_var)``; the running-stats blend
     happens outside in plain JAX so its (normally zero) cotangents stay
@@ -291,10 +288,10 @@ def bn_relu(x: jax.Array, scale: jax.Array, bias: jax.Array,
     :func:`batch_norm` followed by ``jax.nn.relu`` (torch defaults, same
     sync-BN context), with the hand-written backward of
     :func:`_bn_relu_train` (reads only ``(x, dz)`` — the 5-activation-pass
-    minimum).  Measured step-level perf is EQUAL to the autodiff
-    composition on v5e (XLA:TPU already fuses the BN reductions into conv
-    epilogues and reaches the same pass structure — the HLO-evidenced
-    negative result in BASELINE.md); the op is kept because it makes that
+    minimum).  No step-level gain over the autodiff composition is
+    claimed (XLA:TPU already fuses the BN reductions into conv epilogues
+    and reaches the same pass structure; chip time not measured on this
+    stack); the op is kept because it makes that
     traffic structure explicit and pins the collective semantics
     (bn_grad_axis) the ZeRO/replicated cores rely on.  Use for
     conv→BN→ReLU chains; use :func:`batch_norm` where no ReLU immediately

@@ -1,6 +1,6 @@
 """Alternative 2x2/2 max-pool aimed at the pool-backward residue.
 
-BASELINE.md's phase split charges ~1.4-1.8 ms/step to "pool backward":
+The VGG step's phase split charges a visible share to "pool backward":
 the autodiff VJP of ``lax.reduce_window`` max is ``select-and-scatter``,
 a windowed scan op.  For the VGG case (window == stride == 2, no
 padding, even spatial dims) the same pooling is expressible as a
@@ -16,8 +16,7 @@ identical to :func:`~ddp_tpu.ops.layers.max_pool` forward AND backward.
 
 Measure with ``python -m ddp_tpu.ops.pool_candidates`` (marginal-cost
 chains, same differencing methodology as ``conv_probe``); one JSON line
-per (impl, shape).  The result — win or negative — belongs next to the
-conv-candidate table in BASELINE.md.
+per (impl, shape).
 """
 from __future__ import annotations
 
@@ -39,11 +38,11 @@ VGG_POOL_SHAPES = [(32, 128), (16, 256), (8, 512), (4, 512)]
 @jax.custom_vjp
 def max_pool_reshape(x: jax.Array) -> jax.Array:
     """2x2 stride-2 max pool of NHWC ``x`` (even H and W) as reshape+max
-    with a pure-elementwise first-tie backward — the CANDIDATE.  Wins
-    the isolated chains 1.6x but loses the composed step by 20% (its
+    with a pure-elementwise first-tie backward — the CANDIDATE.  Its
     window-view transposes force activation relayouts that fight the
-    conv layouts), so the shipped ``max_pool`` stays on
-    ``reduce_window`` (layers.py)."""
+    conv layouts in the composed step, so the shipped ``max_pool`` stays
+    on ``reduce_window`` (layers.py); neither side is measured on this
+    stack."""
     n, h, w, c = x.shape
     return x.reshape(n, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
 

@@ -112,8 +112,7 @@ def abstract_mesh(shape: Tuple[int, ...]):
         dims = (dims[0], 1)
     if len(dims) == 3 and dims[2] == 1:
         dims = dims[:2]
-    return jax.sharding.AbstractMesh(
-        tuple(zip(_AXIS_ORDER[:len(dims)], dims)))
+    return jax.sharding.AbstractMesh(dims, _AXIS_ORDER[:len(dims)])
 
 
 def mesh_size(mesh) -> int:
@@ -162,8 +161,7 @@ def scan_unroll(mesh: Optional[Mesh] = None, length: Optional[int] = None):
     a real 98-step CIFAR epoch on a CPU-only box — keeps the rolled
     program instead of compiling 98 inlined fwd+bwd bodies.  On TPU the
     rolled scan is always right: compile time stays independent of epoch
-    length and the loop costs nothing (BASELINE.md round-4 dispatch
-    measurements).
+    length.
     """
     platform = (mesh.devices.flat[0].platform if mesh is not None
                 else jax.default_backend())

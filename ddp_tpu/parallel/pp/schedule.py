@@ -43,13 +43,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...optim import sgd as sgd_lib
 from ...ops.losses import cross_entropy_sum_count
-from ...utils.compat import vma_semantics  # installs the shard_map shim
 from ..mesh import (DATA_AXIS, MODEL_AXIS, STAGE_AXIS, data_axis_size,
                     stage_axis_size)
 from .partition import (StagePlan, _MODULE_FOR, merge_subtrees,
                         predicted_bubble, stage_subtree)
-
-del vma_semantics  # imported for the side effect only
 
 
 def stage_submesh(mesh: Mesh, k: int) -> Mesh:

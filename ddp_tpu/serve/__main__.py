@@ -26,12 +26,9 @@ import threading
 import time
 from typing import Optional
 
-from ..utils.platform import pin_platform_from_env
+import jax.numpy as jnp
 
-pin_platform_from_env()
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+from ..utils.platform import device_line, enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -144,7 +141,9 @@ def main(argv: Optional[list] = None) -> int:
     else:
         tracer = SpanTracer(spill_path=trace_spill or None,
                             ring=65536, host=0)
+    enable_compile_cache()
     mesh = make_mesh(args.num_devices)
+    print(device_line(mesh), file=sys.stderr, flush=True)
     registry = MetricsRegistry()  # one /metrics surface per process
     buckets = [int(b) for b in args.buckets.split(",") if b]
     compute_dtype = jnp.bfloat16 if args.bf16 else None

@@ -9,9 +9,8 @@ step builders mirror :func:`~ddp_tpu.train.step.make_train_step`'s two
 gradient cores exactly:
 
 - 1-D / trivial plan: differentiate the GLOBAL-mean loss
-  ``psum(ce_sum)/psum(count)`` — under vma semantics shard_map's autodiff
-  inserts the ``data`` gradient psum itself; the legacy shim gets the
-  explicit ``pmean`` (the same two-branch subtlety step.py documents);
+  ``psum(ce_sum)/psum(count)`` — shard_map's autodiff inserts the
+  ``data`` gradient psum itself (vma semantics, as step.py documents);
 - 2-D tp plan: differentiate the collective-free LOCAL objective
   ``ce_sum/(count*d)`` (the zero-style core — the tp forward's row psums
   carry identity transposes, parallel/tp/layers.py), then explicitly
@@ -53,7 +52,7 @@ from ..optim import sgd as sgd_lib
 from ..ops.losses import cross_entropy_sum_count
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, data_axis_size,
                              make_mesh, replicated_sharding)
-from ..utils.compat import vma_semantics
+from ..utils.platform import device_line, enable_compile_cache
 from .step import TrainState, init_train_state
 
 
@@ -61,7 +60,7 @@ def make_lm_loss_and_grads(model, compute_dtype=None):
     """Replicated-params gradient core for token batches:
     ``fn(params, batch_stats, tokens, rng) -> (loss, stats, grads)`` —
     the LM twin of :func:`~ddp_tpu.train.step.make_loss_and_grads` (same
-    vma/legacy two-branch gradient-collective contract)."""
+    gradient-collective contract)."""
 
     def loss_and_grads(params, batch_stats, tokens, rng):
         def loss_fn(params):
@@ -77,13 +76,6 @@ def make_lm_loss_and_grads(model, compute_dtype=None):
 
         (loss, new_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        if not vma_semantics():
-            # Legacy transpose regime: the psum-in-loss transpose scales
-            # each shard's cotangent by the shard count, so the MEAN over
-            # shards reconstructs the global-mean gradient exactly (the
-            # same identity step.py:make_loss_and_grads documents).
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.pmean(g, DATA_AXIS), grads)
         return loss, new_stats, grads
 
     return loss_and_grads
@@ -263,11 +255,13 @@ def main(argv=None) -> int:
     p.add_argument("--snapshot_path", type=str, default=None)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     if args.mesh_shape:
         shape = tuple(int(v) for v in args.mesh_shape.split(","))
         mesh = make_mesh(shape=shape)
     else:
         mesh = make_mesh(args.num_devices)
+    print(device_line(mesh), flush=True)
 
     plan = None
     if len(mesh.axis_names) >= 2 and mesh.shape[MODEL_AXIS] > 1:

@@ -47,7 +47,6 @@ from ..ops.losses import cross_entropy_sum_count
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, assemble_from_local,
                              batch_sharding, data_axis_size, scan_unroll,
                              replicated_sharding)
-from ..utils.compat import vma_semantics
 
 
 def _as_input(x: jax.Array, compute_dtype=None) -> jax.Array:
@@ -85,17 +84,14 @@ def make_loss_and_grads(model, compute_dtype=None, sync_bn: bool = False):
             # sync_bn: BN statistics psum'd over the global batch — the
             # SyncBatchNorm the reference leaves commented out
             # (multigpu.py:127), as an opt-in (ops/layers.py:bn_sync_axis).
-            # bn_grad_axis: this is the REPLICATED-params core, so under
-            # jax>=0.9 the fused bn_relu VJP must all-reduce its
-            # scale/bias cotangents itself (custom_vjp opts out of
-            # shard_map's vma transpose psum); the ZeRO local-grads core
-            # deliberately leaves it unset.  On a shimmed 0.4.x runtime
-            # (utils/compat.py) the transpose machinery reduces custom_vjp
-            # cotangents too, so the explicit psum must be OFF or γ/β
-            # grads come back mesh-size-times too large.
+            # bn_grad_axis: this is the REPLICATED-params core, so the
+            # fused bn_relu VJP must all-reduce its scale/bias cotangents
+            # itself (custom_vjp opts out of shard_map's vma transpose
+            # psum); the ZeRO local-grads core deliberately leaves it
+            # unset.
             from ..ops.layers import bn_grad_axis, bn_sync_axis
             with bn_sync_axis(DATA_AXIS if sync_bn else None), \
-                    bn_grad_axis(DATA_AXIS if vma_semantics() else None):
+                    bn_grad_axis(DATA_AXIS):
                 logits, new_stats = model.apply(
                     params, batch_stats,
                     _as_input(images, compute_dtype), train=True,
@@ -110,24 +106,13 @@ def make_loss_and_grads(model, compute_dtype=None, sync_bn: bool = False):
 
         (loss, new_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        # On jax>=0.9, NO explicit gradient collective: differentiating
-        # w.r.t. the replicated (in_specs=P()) params makes shard_map's
-        # autodiff insert the psum over ``data`` itself (the transpose of
+        # NO explicit gradient collective: differentiating w.r.t. the
+        # replicated (in_specs=P()) params makes shard_map's autodiff
+        # insert the psum over ``data`` itself (the transpose of
         # replication — vma semantics).  That auto-psum of the global-mean
         # loss IS DDP's bucketed all-reduce(mean) (multigpu.py:96); an
         # explicit pmean there would double-count by the mesh size
         # (tests/test_train_step.py pins this numerically).
-        if not vma_semantics():
-            # Shimmed 0.4.x runtime (utils/compat.py): no vma transpose
-            # exists, so the all-reduce must be explicit.  The legacy
-            # psum-in-loss transpose scales each shard's cotangent by R
-            # (the known legacy behavior train/zero.py's local objective
-            # is designed around), so the per-device grad is R x that
-            # shard's contribution to the global-mean gradient — the MEAN
-            # over shards reconstructs it exactly:
-            #   pmean_j[(R/C)·ds_j/dw] = (1/C)·Σ_j ds_j/dw.
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.pmean(g, DATA_AXIS), grads)
         new_stats = jax.tree_util.tree_map(
             lambda s: lax.pmean(s, DATA_AXIS), new_stats)
         return loss, new_stats, grads
@@ -148,9 +133,7 @@ def make_loss_and_grads_tp(model, data_size: int, compute_dtype=None,
     psums, carry identity transposes), then the grads are EXPLICITLY
     ``psum``-ed over ``data`` only.  The sum of the local objectives over
     the d data shards is the global-mean loss, so that psum IS the DDP
-    all-reduce — and because no collective is ever differentiated, the
-    core behaves identically under the vma and legacy transpose regimes
-    (the subtlety :func:`make_loss_and_grads`'s two branches exist for).
+    all-reduce, and no collective is ever differentiated.
     Model-sharded leaves get their own slice's gradient (their data-axis
     replicas agree; no ``model``-axis gradient collective exists — axis
     correctness is the whole game, tests/test_tp.py pins it bitwise at

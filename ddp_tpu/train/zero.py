@@ -41,8 +41,7 @@ the differentiated objective is the *local* ``ce_sum/(count*R)`` whose
 shard-sum is the global-mean loss: the transpose of any ``psum`` inside the
 forward (sync-BN statistics) then contributes exactly the cross-shard
 cotangents of that summed objective, while the loss itself is deliberately
-NOT psum'd inside ``jax.grad`` (the legacy psum transpose would scale the
-cotangents by R if it were).
+NOT psum'd inside ``jax.grad``.
 """
 from __future__ import annotations
 

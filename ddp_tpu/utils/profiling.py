@@ -1,5 +1,5 @@
-"""Per-op summary of a jax.profiler trace — the analysis behind
-BASELINE.md's roofline table, as a reusable tool.
+"""Per-op summary of a jax.profiler trace — the analysis behind a
+roofline table, as a reusable tool.
 
 The reference's only timing is two ``time.time()`` calls around training
 (singlegpu.py:232-234); this framework additionally captures XLA traces
@@ -87,8 +87,8 @@ def device_op_summary(trace_dir: str, steps: int = 1,
 
 
 # Op-name → phase rules for categorize().  Order matters: first match wins.
-# Derived from reading the optimized HLO of the VGG train step on v5e
-# (BASELINE.md "fp32 kernel-level attack"): conv work appears as
+# Derived from reading the optimized HLO of the VGG train step on v5e:
+# conv work appears as
 # %convolution OR as kOutput fusions carrying a
 # ``convolution_algorithm_config`` — multiply_reduce_fusion (dgrad conv +
 # fused dγ/dβ epilogue), multiply_subtract_fusion (wgrad conv fused with
@@ -181,7 +181,7 @@ def device_busy_ms_per_step(trace_dir: str, steps: int = 1
 def attribute_streaming(host_ms: float, h2d_ms: float, step_ms: float,
                         wall_ms: float) -> Dict[str, float]:
     """Pipeline-model decomposition of a streaming run's per-step wall time
-    (the BASELINE.md streaming-gap table; VERDICT r5 weak #5 / next #4).
+    (the streaming-gap table).
 
     Inputs are the three stages measured in ISOLATION at the same shape
     (sequential host materialise+augment, blocking H2D upload,

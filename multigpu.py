@@ -11,7 +11,8 @@ batch_size x num_devices exactly as in DDP.  Multi-host rendezvous (the
 MASTER_ADDR/PORT analogue) comes from ``jax.distributed.initialize`` via
 DDP_TPU_COORDINATOR/NUM_PROCESSES/PROCESS_ID (ddp_tpu/parallel/dist.py);
 ``--spawn N`` forks N wired local processes — the reference's ``mp.spawn``
-UX — with per-process device visibility left to the environment.
+UX — for multi-process drills on a virtual CPU mesh; it refuses on a TPU
+host, where one process drives every local chip.
 """
 from ddp_tpu.entry import main_multi
 

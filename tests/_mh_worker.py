@@ -63,8 +63,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 
 def main() -> None:
     pid, coordinator, ckpt_path = (_PID, sys.argv[2], sys.argv[3])

@@ -2,7 +2,7 @@
 
 The real acceptance artifact — final accuracy after a 20-epoch CIFAR-10
 run (/root/reference/singlegpu.py:248-249) — needs the real 163 MB
-dataset, which an egress-less host cannot fetch (BASELINE.md "Accuracy").
+dataset, which an egress-less host cannot fetch.
 This generator produces an archive that is byte-layout-identical to what
 ``torchvision.datasets.CIFAR10(download=True)`` leaves on disk (the layout
 ``ddp_tpu.data.cifar10.load`` parses, reference singlegpu.py:161-171):

@@ -289,6 +289,32 @@ def test_tp_search_cli_writes_golden_equivalent(tmp_path):
         assert out.read_text() == fh.read()
 
 
+def test_autoplan_bench_child_search_equals_in_process():
+    """``bench.py --autoplan_bench`` searches in a one-device CPU child
+    (its parent must not touch a backend): the plan the auto child then
+    loads is byte-identical to ``search_plan`` called in-process with the
+    same inputs, and the record names the child's wall for what it is."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--autoplan_bench",
+         "--calib", GOLDEN, "--num_devices", "4", "--batch_size", "8",
+         "--steps", "1", "--warmup", "1", "--repeats", "1",
+         "--autoplan_models", "deepnn"],
+        capture_output=True, text=True, cwd=REPO)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    auto = rec["autoplan_bench"]["deepnn"]["auto"]
+    with open(GOLDEN, "r", encoding="utf-8") as fh:
+        coeffs = coefficients_from(json.load(fh))
+    want = search_plan("deepnn", coefficients=coeffs, total_devices=4,
+                       global_batch=8)
+    assert plan_doc_dumps(auto["plan_doc"]) == plan_doc_dumps(want.doc)
+    assert auto["mesh"] == "x".join(map(str, want.doc["mesh_shape"]))
+    assert auto["search_child_s"] > 0 and "search_s" not in auto
+    assert auto["ms_per_step"] > 0
+    # --calib was a plan doc, not a calibrate record: no residual.
+    assert rec["calibration_gap_pct"] is None
+
+
 # ----------------------------------------------------- trivial-plan path
 
 def test_trivial_plan_resolves_to_plain_dp():
@@ -304,21 +330,15 @@ def test_trivial_plan_resolves_to_plain_dp():
     assert result.doc["recipe"] == {}
 
 
-# ----------------------------------------------------------- MFU fallback
+# ------------------------------------------------------- MFU denominator
 
-def test_mfu_probed_peak_fallback_on_cpu():
-    """model_mfu no longer returns None off-TPU: unknown device kinds
-    fall back to a runtime-probed matmul peak, so every --tp_sweep cell
-    gets a real MFU on the CPU boxes the committed BENCH records come
-    from (ISSUE 17 satellite)."""
+def test_mfu_unknown_device_kind_yields_none():
+    """The peak table is the only MFU denominator: a device kind that is
+    not in it (this CPU box) gets no MFU — never a runtime-probed one —
+    while a known kind is priced against its table entry."""
     from ddp_tpu.obs import live
     kind = jax.devices()[0].device_kind
     assert kind not in live.PEAK_TFLOPS_BF16_PASS  # cpu box
-    peak = live.mfu_peak(kind)
-    assert peak is not None and peak[0] > 0 and peak[1] == "probed"
-    # Probe result is cached per kind per process.
-    assert live.probed_peak_tflops(kind) == peak[0]
-    mfu = live.model_mfu(10.0, "deepnn", kind)
+    assert live.model_mfu(10.0, "deepnn", kind) is None
+    mfu = live.model_mfu(10.0, "deepnn", "TPU v5 lite")
     assert mfu is not None and mfu > 0
-    # The measured table still wins where it exists.
-    assert live.mfu_peak("TPU v5 lite") == (197.0, "measured")

@@ -109,30 +109,21 @@ def test_sync_bn_matches_composition_under_shard_map():
     x, scale, bias, st = _inputs(shape=(16, 4, 4, 6))
     w = jax.random.normal(jax.random.key(6), x.shape)
 
-    from ddp_tpu.utils.compat import vma_semantics
-
     def make(op):
         def body(x, scale, bias, w):
             # Mirror the replicated-params core's contexts (step.py):
             # sync the statistics AND mark the gradient all-reduce axis
-            # exactly as the core does — runtime-gated (utils/compat.py):
-            # under vma semantics the custom VJP must psum dγ/dβ itself to
-            # match what autodiff's composition gets from the replication
-            # transpose; on the shimmed 0.4.x runtime the step-level
-            # machinery reduces both identically, so the explicit axis
-            # would make only the fused op global.
-            with bn_sync_axis("data"), \
-                    bn_grad_axis("data" if vma_semantics() else None):
+            # exactly as the core does: under vma semantics the custom
+            # VJP must psum dγ/dβ itself to match what autodiff's
+            # composition gets from the replication transpose.
+            with bn_sync_axis("data"), bn_grad_axis("data"):
                 def lf(x, scale, bias):
                     z, ns = op(x, scale, bias, st, train=True)
                     # Running-stats cotangents are identically zero in
                     # real training (the stats are EMA aux outputs) and
                     # the hand-written VJP's terms for them encode the vma
-                    # transpose scaling — only exercisable where that
-                    # scaling is in force; the legacy runtime's psum
-                    # transpose scales them by R in the composition.
-                    extra = ((ns.mean.sum() + 0.1 * ns.var.sum())
-                             if vma_semantics() else 0.0)
+                    # transpose scaling.
+                    extra = ns.mean.sum() + 0.1 * ns.var.sum()
                     return lax.psum((z * w).sum(), "data") + extra
                 return jax.value_and_grad(lf, argnums=(0, 1, 2))(
                     x, scale, bias)
@@ -143,14 +134,8 @@ def test_sync_bn_matches_composition_under_shard_map():
     l1, g1 = make(bn_relu)(x, scale, bias, w)
     l2, g2 = make(_ref_op)(x, scale, bias, w)
     np.testing.assert_allclose(l1, l2, rtol=1e-5)
-    # Legacy tolerance: the composition runs the two-pass centered
-    # variance vs the fused op's one-pass form, and the legacy runtime's
-    # reduction order differs — ~2e-4 max rel measured, fp-noise not
-    # semantics (semantic errors are O(1) here).
-    tol = (dict(rtol=2e-5, atol=2e-6) if vma_semantics()
-           else dict(rtol=1e-3, atol=1e-5))
     for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, **tol)
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
 
 
 def test_vgg_fused_grads_match_unfused_composition():

@@ -193,8 +193,7 @@ def test_spawn_launcher_matches_single_process(tmp_path):
     multigpu.py:262-263): two auto-wired local processes x 4 CPU devices
     must train to a checkpoint matching the plain single-process 8-device
     run of the same command."""
-    base_env = {k: v for k, v in os.environ.items()
-                if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    base_env = dict(os.environ)
     base_env["PYTHONPATH"] = _REPO + os.pathsep + base_env.get(
         "PYTHONPATH", "")
     common = ["2", "100", "--batch_size", "4", "--synthetic", "--model",
@@ -203,7 +202,7 @@ def test_spawn_launcher_matches_single_process(tmp_path):
     runs = {"spawn.pt": ("4", ["--spawn", "2"]),
             "single.pt": ("8", [])}
     for name, (ndev, extra) in runs.items():
-        env = dict(base_env, DDP_TPU_PLATFORM="cpu",
+        env = dict(base_env, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}")
         out = subprocess.run(
             [sys.executable, "multigpu.py", *common, *extra,

@@ -53,3 +53,20 @@ def test_dispatch_is_deterministic_across_backends(monkeypatch):
     monkeypatch.setattr(native, "_tried", True)
     out_numpy = random_crop_flip(batch, np.random.default_rng(42))
     np.testing.assert_array_equal(out_native, out_numpy)
+
+
+def test_failed_build_says_so_once(tmp_path, monkeypatch, capsys):
+    """A build that fails is not silent: one stderr line carrying the
+    compiler's own message, then the numpy path (None) on every call."""
+    bad = tmp_path / "broken.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(bad))
+    monkeypatch.setattr(native, "NATIVE_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    assert native.get_lib() is None
+    err = capsys.readouterr().err
+    assert err.count("native augment kernel unavailable") == 1
+    assert "error" in err and "broken.cpp" in err  # g++'s message
+    assert native.get_lib() is None
+    assert capsys.readouterr().err == ""

@@ -38,7 +38,6 @@ from ddp_tpu.resilience.preemption import (PreemptionGuard,
 from ddp_tpu.resilience.watchdog import WATCHDOG_EXIT_STATUS, Watchdog
 from ddp_tpu.train import Trainer, load_checkpoint, save_checkpoint
 from ddp_tpu.train.checkpoint import CheckpointError, sha256_of_file
-from ddp_tpu.utils.compat import vma_semantics
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -839,10 +838,9 @@ def test_bench_scan_record_carries_unroll_marker():
 
 
 def _clean_env(ndev: int) -> dict:
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env = dict(os.environ)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["DDP_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     return env
 
@@ -982,11 +980,6 @@ def test_watchdog_exits_stalled_single_process_run(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not vma_semantics(),
-    reason="jax 0.4.x CPU backend lacks multiprocess collectives — every "
-           "multihost test fails on this runtime (seed-failing); the "
-           "2-process stall drill needs a jax>=0.9 image")
 def test_watchdog_unsticks_stalled_two_process_run(tmp_path):
     """Acceptance: a stalled rank in a 2-process CPU run must NOT hang its
     peer for the 300 s graceful-shutdown timeout — the healthy rank's

@@ -139,10 +139,10 @@ def test_eval_bf16_close_to_fp32():
     assert abs(acc32 - accbf) <= 5.0  # 64 samples -> <= ~3 tied flips
 
 
-def test_bench_bf16_vs_baseline_is_real():
-    """A bf16 bench record must report a REAL vs_baseline against the
-    recorded bf16 constant (VERDICT r2 weak #2: the hardcoded 1.0 made the
-    driver-parsed headline under-report the round)."""
+def test_bench_bf16_record_has_no_unmeasured_baseline():
+    """No chip measurement exists on the installed stack, so a record's
+    vs_baseline is 1.0 — never a ratio against an unrecorded constant
+    (ROADMAP A1 supplies the measured denominator)."""
     import bench
 
     args = argparse.Namespace(
@@ -150,8 +150,8 @@ def test_bench_bf16_vs_baseline_is_real():
         num_devices=2, dispatch="step", profile_dir=None,
         shard_update=False)
     rec = bench._bench_step(args, bf16=True, extras=False)[0]
-    assert rec["vs_baseline"] == round(
-        rec["value"] / bench.BASELINE_BENCH_BF16, 3)
+    assert rec["vs_baseline"] == 1.0
+    assert not hasattr(bench, "BASELINE_BENCH_BF16")
     assert "bf16" in rec["metric"]
 
 
@@ -327,8 +327,8 @@ def test_momentum_weight_decay_flags_reach_sgd_config(monkeypatch):
 
 def test_conv_probe_flops_and_shapes():
     """conv_probe's FLOP accounting and shape table stay consistent with
-    the VGG architecture (the BASELINE.md emitter analysis rests on
-    them): 8 convs total, spatial sizes halving at each pool, and the
+    the VGG architecture (the emitter analysis rests on them): 8 convs
+    total, spatial sizes halving at each pool, and the
     summed fwd FLOPs matching the known ~1.2 GFLOP/sample VGG forward
     at batch 1."""
     from ddp_tpu.ops.conv_probe import VGG_CONV_SHAPES, conv_flops
@@ -336,7 +336,7 @@ def test_conv_probe_flops_and_shapes():
     assert sum(reps for *_s, reps in VGG_CONV_SHAPES) == 8
     fwd = sum(conv_flops(1, h, cin, cout) * reps
               for h, cin, cout, reps in VGG_CONV_SHAPES)
-    # 3.6 GFLOP/sample trained (BASELINE.md roofline) = 3x forward.
+    # 3.6 GFLOP/sample trained = 3x forward.
     assert 1.0e9 < fwd < 1.4e9, fwd
     # Spatial sizes follow the pool structure of VGG.ARCH.
     assert [h for h, *_ in VGG_CONV_SHAPES] == [32, 32, 16, 16, 8, 8, 4]

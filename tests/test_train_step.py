@@ -355,8 +355,8 @@ def test_golden_trace_exact_recipe_prefix():
     of a real run, in lockstep with the torch reference.  Measured drift
     on this seed over 6 steps: max |rel loss| 3.1e-5 (1.2e-5 by step 4),
     max |param delta| 4.4e-5 — asserted with >=6x headroom.  4 steps are
-    run here (each batch-512 lockstep step costs ~30 s of torch CPU time
-    on this box; the 6-step measurement is recorded in BASELINE.md).
+    run here (each batch-512 lockstep step costs ~30 s of torch CPU
+    time).
     (The full 20-epoch horizon at this batch is not CPU-tractable; the
     scaled-recipe test below carries the 2-epoch-horizon claim.)"""
     jl, tl, got, want = _golden_run(n_batch=512, base_lr=0.4, spe=98,

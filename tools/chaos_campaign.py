@@ -118,9 +118,8 @@ _DRILLS = {
 
 def _env(ndev: int) -> dict:
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
     env.pop("DDP_TPU_FAULT", None)
-    env["DDP_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     # Supervisor device probe: trust this count instead of paying a jax
