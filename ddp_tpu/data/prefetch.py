@@ -32,12 +32,20 @@ streaming-gap table from these plus the tracer's span record
 
 Telemetry (round 7): every stage also reports into the run's span tracer
 (obs/tracer.py) — ``host_augment`` and ``h2d`` spans from wherever they
-actually run (marked ``overlap=True`` on producer threads, whose time
-hides behind the consumer loop), ``data_wait`` from the consumer's side
-of the queue.  ``step0`` anchors span step numbers at the trainer's
-global step so "where did step 4817 go" is answerable from the spill.
-With the default NullTracer the spans are shared no-op context managers
-— the ``--obs_off`` zero-overhead contract.
+actually run, ``data_wait`` from the consumer's side of the queue.  Which
+thread that is depends on the engine: the pool's workers materialise
+(``host_augment``, ``overlap=True``: their time hides behind the consumer
+loop) while its ``h2d`` runs on the CONSUMER thread, serial, after each
+``data_wait``; the single pipelining thread does both (``overlap=True``);
+at depth 0 both are the consumer's, serial.  Every ``h2d`` carries
+``nbytes``, the host batch it ships.  The engine's ends are named too:
+``on_ready`` fires when it is built, just before it first waits for a
+batch (the trainer ends its ``epoch_setup`` span there), and its shutdown
+(workers joined) is an ``epoch_close`` span at ``step0``.  ``step0``
+anchors span step numbers at the trainer's global step so "where did step
+4817 go" is answerable from the spill.  With the default NullTracer the
+spans are shared no-op context managers — the ``--obs_off`` zero-overhead
+contract.
 """
 from __future__ import annotations
 
@@ -123,11 +131,16 @@ class PrefetchStats:
                     "batches": self.batches}
 
 
+def _nothing() -> None:
+    pass
+
+
 def prefetch_to_device(batches: Iterable[Dict[str, np.ndarray]], mesh,
                        depth: int = 2, workers: int = 4,
                        stats: Optional[PrefetchStats] = None,
                        shard_fn=None, tracer=None,
-                       step0: int = 0, start: int = 0) -> Iterator[dict]:
+                       step0: int = 0, start: int = 0,
+                       on_ready=_nothing) -> Iterator[dict]:
     """Yield device-resident, data-sharded batches ahead of consumption.
 
     ``depth`` is how many batches may be in flight beyond the workers'
@@ -139,7 +152,10 @@ def prefetch_to_device(batches: Iterable[Dict[str, np.ndarray]], mesh,
     :func:`~ddp_tpu.train.step.shard_batch`; the accumulation path passes
     ``shard_batch_stacked`` for its ``[A, B, ...]`` group stacks).
     ``tracer`` (default: the process tracer) receives host_augment/h2d/
-    data_wait spans, step-numbered from ``step0``.
+    data_wait spans, step-numbered from ``step0``.  ``on_ready()`` is
+    called once on the consumer thread, inside the first ``next()``, when
+    the engine is built (pool or thread started, skipped prefix dropped)
+    and about to produce its first batch.
 
     ``start`` fast-forwards the epoch to batch index ``start`` — the
     mid-epoch resume path (resilience/preemption): batches ``[0, start)``
@@ -161,13 +177,21 @@ def prefetch_to_device(batches: Iterable[Dict[str, np.ndarray]], mesh,
                        for k in range(start, len(loader)))
             start = 0
         yield from _passthrough(iter(batches), mesh, stats, shard, tracer,
-                                step0, start)
+                                step0, start, on_ready)
     elif hasattr(batches, "materialize") and hasattr(batches, "__len__"):
         yield from _pooled(batches, mesh, depth, max(workers, 1), stats,
-                           shard, tracer, step0, start)
+                           shard, tracer, step0, start, on_ready)
     else:
         yield from _threaded(iter(batches), mesh, depth, stats, shard,
-                             tracer, step0, start)
+                             tracer, step0, start, on_ready)
+
+
+def _nbytes(tracer, batch) -> Optional[int]:
+    """Bytes of a host batch, for its ``h2d`` span; not counted when
+    nothing records it."""
+    if not tracer.enabled:
+        return None
+    return sum(v.nbytes for v in batch.values())
 
 
 def _timed(stats: Optional[PrefetchStats], field: str, fn, *args):
@@ -192,7 +216,8 @@ def _skip(batches: Iterator, start: int) -> None:
 
 def _passthrough(batches: Iterator[Dict[str, np.ndarray]], mesh,
                  stats: Optional[PrefetchStats], shard, tracer,
-                 step0: int, start: int = 0) -> Iterator[dict]:
+                 step0: int, start: int = 0,
+                 on_ready=_nothing) -> Iterator[dict]:
     """The unpipelined reference shape: one batch materialised, shipped,
     then consumed, strictly in sequence (singlegpu.py:104-107's loop).
     Everything runs on the consumer thread, so the spans are serial
@@ -200,6 +225,7 @@ def _passthrough(batches: Iterator[Dict[str, np.ndarray]], mesh,
     to expose.  A span whose body raises StopIteration is not recorded
     (tracer contract), so the exhaustion probe leaves no bogus span."""
     _skip(batches, start)
+    on_ready()
     k = step0
     while True:
         try:
@@ -207,7 +233,7 @@ def _passthrough(batches: Iterator[Dict[str, np.ndarray]], mesh,
                 batch = _timed(stats, "host_s", lambda: next(batches))
         except StopIteration:
             return
-        with tracer.span("h2d", step=k):
+        with tracer.span("h2d", step=k, nbytes=_nbytes(tracer, batch)):
             out = _timed(stats, "h2d_s", shard, batch, mesh)
         if stats is not None:
             stats.count_batch()
@@ -225,7 +251,7 @@ def _materialize_traced(tracer, stats, loader, k: int, step0: int):
 
 def _pooled(loader, mesh, depth: int, workers: int,
             stats: Optional[PrefetchStats], shard, tracer,
-            step0: int, start: int = 0) -> Iterator[dict]:
+            step0: int, start: int = 0, on_ready=_nothing) -> Iterator[dict]:
     n = len(loader)
     pool = ThreadPoolExecutor(max_workers=workers,
                               thread_name_prefix="ddp_tpu_prefetch")
@@ -238,6 +264,7 @@ def _pooled(loader, mesh, depth: int, workers: int,
                        for k in range(start,
                                       min(start + workers + depth, n)))
         next_k = start + len(futures)
+        on_ready()
         i = 0
         while futures:
             with tracer.span("data_wait", step=step0 + i):
@@ -246,7 +273,8 @@ def _pooled(loader, mesh, depth: int, workers: int,
                 futures.append(pool.submit(_materialize_traced, tracer,
                                            stats, loader, next_k, step0))
                 next_k += 1
-            with tracer.span("h2d", step=step0 + i):
+            with tracer.span("h2d", step=step0 + i,
+                             nbytes=_nbytes(tracer, batch)):
                 out = _timed(stats, "h2d_s", shard, batch, mesh)
             if stats is not None:
                 stats.count_batch()
@@ -256,12 +284,13 @@ def _pooled(loader, mesh, depth: int, workers: int,
         # Abandoned mid-epoch (consumer exception/break/preemption): drop
         # the queued work and JOIN the workers — an in-flight materialize
         # finishes (bounded: one batch per worker) and nothing else runs.
-        pool.shutdown(wait=True, cancel_futures=True)
+        with tracer.span("epoch_close", step=step0):
+            pool.shutdown(wait=True, cancel_futures=True)
 
 
 def _threaded(batches: Iterator[Dict[str, np.ndarray]], mesh, depth: int,
               stats: Optional[PrefetchStats], shard, tracer,
-              step0: int, start: int = 0) -> Iterator[dict]:
+              step0: int, start: int = 0, on_ready=_nothing) -> Iterator[dict]:
     _skip(batches, start)
     q: queue.Queue = queue.Queue(maxsize=depth)
     stop = threading.Event()
@@ -290,7 +319,8 @@ def _threaded(batches: Iterator[Dict[str, np.ndarray]], mesh, depth: int,
                                        lambda: next(batches))
                 except StopIteration:
                     break
-                with tracer.span("h2d", step=k, overlap=True):
+                with tracer.span("h2d", step=k, overlap=True,
+                                 nbytes=_nbytes(tracer, batch)):
                     item = _timed(stats, "h2d_s", shard, batch, mesh)
                 if not _put(item):
                     return
@@ -303,6 +333,7 @@ def _threaded(batches: Iterator[Dict[str, np.ndarray]], mesh, depth: int,
     t = threading.Thread(target=worker, daemon=True,
                          name="ddp_tpu_prefetch")
     t.start()
+    on_ready()
     i = 0
     try:
         while True:
@@ -325,10 +356,11 @@ def _threaded(batches: Iterator[Dict[str, np.ndarray]], mesh, depth: int,
                 stats.count_batch()
             yield item
     finally:
-        stop.set()
-        try:  # unblock a producer mid-put immediately
-            while True:
-                q.get_nowait()
-        except queue.Empty:
-            pass
-        t.join(timeout=10.0)
+        with tracer.span("epoch_close", step=step0):
+            stop.set()
+            try:  # unblock a producer mid-put immediately
+                while True:
+                    q.get_nowait()
+            except queue.Empty:
+                pass
+            t.join(timeout=10.0)

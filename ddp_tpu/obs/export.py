@@ -2,7 +2,10 @@
 export plus the terminal reports behind ``python -m ddp_tpu.obs``.
 
 A spill file (``--trace_spill``; obs/tracer.py) is append-only JSON lines
-``{"phase", "step", "start_s", "dur_s", "overlap", "host"}``.  Multi-host
+``{"phase", "step", "start_s", "dur_s", "overlap", "host"}``, plus the
+optional counts ``n`` (items) and ``nbytes`` where the site gave them
+(``dispatch``: samples, ``loss_flush``: losses, ``h2d`` and a resident
+epoch's ``epoch_setup``: bytes shipped).  Multi-host
 runs write one spill per host (rank suffixes); :func:`read_spill` merges
 any number of them into one timeline.
 
@@ -28,15 +31,16 @@ import json
 import statistics
 from typing import Dict, Iterable, List, Optional, Tuple
 
-# Canonical phase order: consumer-loop phases first in pipeline order,
+# Canonical phase order: consumer-loop phases first in pipeline order
+# (epoch_setup and epoch_close are the epoch's two ends on that thread),
 # then the boundary/background phases, then the serving engine's batch
 # pipeline (ddp_tpu/serve/ — queue_wait is per-request and overlap=True;
 # batch_form..d2h are the engine thread's serial stages, sharing "h2d"
 # with the training pipeline).  Unknown phases sort after these (the
 # tracer accepts free-form names).
-PHASE_ORDER = ("data_wait", "host_augment", "h2d", "dispatch",
-               "loss_flush", "drift_audit", "ckpt_write", "ckpt_upload",
-               "eval",
+PHASE_ORDER = ("epoch_setup", "data_wait", "host_augment", "h2d",
+               "dispatch", "epoch_close", "loss_flush", "drift_audit",
+               "ckpt_write", "ckpt_upload", "eval",
                "queue_wait", "batch_form", "pad", "forward", "d2h",
                # Fleet/router phases (serve/router.py, serve/fleet.py):
                # route/retry are per-request handler-thread spans
@@ -125,6 +129,9 @@ def to_trace_events(spans: List[dict]) -> dict:
             args["step"] = int(s["step"])
         if s.get("req") is not None:
             args["req"] = str(s["req"])
+        for key in ("n", "nbytes"):
+            if s.get(key) is not None:
+                args[key] = int(s[key])
         ev = {
             "name": s["phase"], "cat": "train", "ph": "X",
             "ts": round(float(s["start_s"]) * 1e6, 3),
@@ -300,25 +307,31 @@ def phase_summary(spans: List[dict]) -> Tuple[List[dict], float, float]:
     """Per-phase ledger + the wall identity.
 
     Returns ``(rows, wall_s, critical_s)``: one row per phase (count,
-    total/median/mean ms, overlap flag), the run's wall time (span of
+    total/median/mean ms, overlap flag, and the summed counts ``n`` and
+    ``nbytes``, None where no span of the phase carries one), the run's
+    wall time (span of
     the whole timeline), and the *critical* sum — total time of
     non-overlap spans only, the quantity comparable to wall (producer
     threads run concurrently and would double-count)."""
     if not spans:
         return [], 0.0, 0.0
-    by_phase: Dict[Tuple[str, bool], List[float]] = {}
+    by_phase: Dict[Tuple[str, bool], List[dict]] = {}
     for s in spans:
-        by_phase.setdefault((s["phase"], bool(s["overlap"])), []).append(
-            float(s["dur_s"]))
+        by_phase.setdefault((s["phase"], bool(s["overlap"])), []).append(s)
     rows = []
-    for (phase, overlap), durs in sorted(
+    for (phase, overlap), group in sorted(
             by_phase.items(), key=lambda kv: _phase_rank(kv[0][0])):
-        rows.append({
+        durs = [float(s["dur_s"]) for s in group]
+        row = {
             "phase": phase, "overlap": overlap, "count": len(durs),
             "total_ms": sum(durs) * 1e3,
             "median_ms": statistics.median(durs) * 1e3,
             "mean_ms": sum(durs) / len(durs) * 1e3,
-        })
+        }
+        for key in ("n", "nbytes"):
+            counts = [s[key] for s in group if s.get(key) is not None]
+            row[key] = sum(counts) if counts else None
+        rows.append(row)
     wall_s = (max(s["start_s"] + s["dur_s"] for s in spans)
               - min(s["start_s"] for s in spans))
     critical_s = sum(s["dur_s"] for s in spans if not s["overlap"])
@@ -425,14 +438,23 @@ def _format_host_report(spans: List[dict], *, host: int, top: int,
                      f"wall {wall_s:.3f} s ===")
     else:
         lines.append(f"wall {wall_s:.3f} s")
+    # The counts' two columns only where some span of the spill has one.
+    counted = any(r["n"] is not None or r["nbytes"] is not None
+                  for r in rows)
     lines.append(f"{'phase':<16} {'lane':<8} {'count':>7} {'total ms':>12} "
-                 f"{'median ms':>11} {'mean ms':>11} {'% wall':>7}")
+                 f"{'median ms':>11} {'mean ms':>11} {'% wall':>7}"
+                 + (f" {'items':>12} {'MB':>10}" if counted else ""))
     for r in rows:
         share = r["total_ms"] / (wall_s * 1e3) * 100.0 if wall_s else 0.0
-        lines.append(
+        line = (
             f"{r['phase']:<16} {'overlap' if r['overlap'] else 'serial':<8} "
             f"{r['count']:>7} {r['total_ms']:>12.2f} "
             f"{r['median_ms']:>11.3f} {r['mean_ms']:>11.3f} {share:>6.1f}%")
+        if counted:
+            items = "-" if r["n"] is None else str(r["n"])
+            mb = "-" if r["nbytes"] is None else f"{r['nbytes'] / 1e6:.2f}"
+            line += f" {items:>12} {mb:>10}"
+        lines.append(line)
     pct = critical_s / wall_s * 100.0 if wall_s else 0.0
     lines.append("")
     lines.append(f"phase sum (serial lanes): {critical_s * 1e3:.1f} ms = "

@@ -18,8 +18,12 @@ offline tooling consumes (``python -m ddp_tpu.obs``: phase breakdown,
 step histogram, slowest-K, Perfetto export — obs/export.py).
 
 Phases are free-form strings; the canonical training phases live in
-:data:`~ddp_tpu.obs.export.PHASE_ORDER` (data_wait, host_augment, h2d,
-dispatch, loss_flush, ckpt_write, eval).  ``overlap=True`` marks spans
+:data:`~ddp_tpu.obs.export.PHASE_ORDER` (epoch_setup, data_wait,
+host_augment, h2d, dispatch, epoch_close, loss_flush, ckpt_write, eval).
+A span may carry two counts, ``n`` (items: samples, losses) and
+``nbytes``, given to ``span()`` or, where they are known only inside the
+body, through ``count()`` on the open span; a site computes a count that
+costs anything only if ``tracer.enabled``.  ``overlap=True`` marks spans
 recorded on *producer* threads (prefetch workers, the async checkpoint
 writer) whose wall time hides behind the consumer loop — reports sum
 only non-overlap spans when comparing against wall time, or concurrent
@@ -33,6 +37,15 @@ off.  Spans are recorded only on *clean* exit: a span whose body raises
 (including the ``StopIteration`` probe at iterator exhaustion) never
 lands, which is also what makes "last completed span" the right stall
 diagnostic.
+
+Profiler mirror: while a ``jax.profiler`` session runs (``--profile_dir``,
+``/debug/profile``, the benchmark's traced epochs), every span a
+:class:`SpanTracer` times is also a host event ``ddp:<phase>`` of that
+trace, on the thread that ran it, with ``step`` and the counts as its
+arguments (one ``jax.profiler.TraceAnnotation`` entered and left with the
+span).  With no session the price is one ``is_enabled()`` call a span.
+``add_span`` records an interval that is already over, so it has no
+event (the threaded engine's ``data_wait``, ``pp_bubble``).
 
 Thread safety: producers (prefetch pool/thread, checkpoint writer) and
 the consumer loop record concurrently; the ring, last-span table and
@@ -70,6 +83,13 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def count(self, n: Optional[int] = None,
+              nbytes: Optional[int] = None) -> None:
+        pass
+
+    def end(self) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -79,13 +99,15 @@ class NullTracer:
     enabled = False
 
     def span(self, phase: str, step: Optional[int] = None,
-             overlap: bool = False,
-             req: Optional[str] = None) -> _NullSpan:
+             overlap: bool = False, req: Optional[str] = None,
+             n: Optional[int] = None,
+             nbytes: Optional[int] = None) -> _NullSpan:
         return _NULL_SPAN
 
     def add_span(self, phase: str, start_monotonic: float, dur_s: float,
                  step: Optional[int] = None, overlap: bool = False,
-                 req: Optional[str] = None) -> None:
+                 req: Optional[str] = None, n: Optional[int] = None,
+                 nbytes: Optional[int] = None) -> None:
         pass
 
     def now(self) -> float:
@@ -109,29 +131,63 @@ class NullTracer:
         pass
 
 
+def _given(**kw) -> dict:
+    """The arguments that were given: what a profiler event shows."""
+    return {k: v for k, v in kw.items() if v is not None}
+
+
 class _Span:
-    """One in-flight span; records itself on clean ``__exit__`` only."""
-    __slots__ = ("_tracer", "phase", "step", "overlap", "req", "_start")
+    """One in-flight span; records itself on clean ``__exit__`` only.
+
+    Most sites use it as a context manager.  A phase that opens in one
+    function and closes in another (``epoch_setup``: opened by the
+    trainer, closed by the prefetch engine when it is built) enters it by
+    hand and calls :meth:`end`."""
+    __slots__ = ("_tracer", "phase", "step", "overlap", "req", "n",
+                 "nbytes", "_start", "_event")
 
     def __init__(self, tracer: "SpanTracer", phase: str,
                  step: Optional[int], overlap: bool,
-                 req: Optional[str] = None):
+                 req: Optional[str] = None, n: Optional[int] = None,
+                 nbytes: Optional[int] = None):
         self._tracer = tracer
         self.phase = phase
         self.step = step
         self.overlap = overlap
         self.req = req
+        self.n = n
+        self.nbytes = nbytes
 
     def __enter__(self) -> "_Span":
+        # The profiler's event opens before the span's clock read and
+        # closes after it, so the event holds the span.
+        self._event = self._tracer._profiler_event(self)
         self._start = time.monotonic()
         return self
 
+    def count(self, n: Optional[int] = None,
+              nbytes: Optional[int] = None) -> None:
+        """Counts known only inside the body (the bytes of an index
+        matrix built under ``epoch_setup``)."""
+        if n is not None:
+            self.n = n
+        if nbytes is not None:
+            self.nbytes = nbytes
+        if self._event is not None:
+            self._event.set_metadata(**_given(n=n, nbytes=nbytes))
+
     def __exit__(self, exc_type, exc, tb) -> bool:
+        end = time.monotonic()
+        if self._event is not None:
+            self._event.__exit__(exc_type, exc, tb)
         if exc_type is None:  # an aborted body is not a completed phase
-            end = time.monotonic()
             self._tracer._record(self.phase, self.step, self._start,
-                                 end - self._start, self.overlap, self.req)
+                                 end - self._start, self.overlap, self.req,
+                                 self.n, self.nbytes)
         return False
+
+    def end(self) -> None:
+        self.__exit__(None, None, None)
 
 
 class SpanTracer:
@@ -139,7 +195,9 @@ class SpanTracer:
 
     ``host`` tags every record with this process's rank so multi-host
     spills merge into one timeline (one Perfetto process per host);
-    pass ``jax.process_index()`` — the tracer itself is jax-free.
+    pass ``jax.process_index()`` — the tracer asks jax for nothing but
+    the profiler's annotation class (module docstring), and works
+    without it.
     ``ring`` bounds in-memory retention (the spill file is the full
     record); ``t0`` anchors relative timestamps and defaults to
     construction time.
@@ -163,27 +221,48 @@ class SpanTracer:
         self._last: Dict[str, tuple] = {}
         self._f: Optional[IO[str]] = (open(spill_path, "w")
                                       if spill_path else None)
+        try:  # here, not at import: ``python -m ddp_tpu.obs`` needs no jax
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
 
     # -- recording ---------------------------------------------------------
 
     def span(self, phase: str, step: Optional[int] = None,
-             overlap: bool = False, req: Optional[str] = None) -> _Span:
-        return _Span(self, phase, step, overlap, req)
+             overlap: bool = False, req: Optional[str] = None,
+             n: Optional[int] = None,
+             nbytes: Optional[int] = None) -> _Span:
+        return _Span(self, phase, step, overlap, req, n, nbytes)
+
+    def _profiler_event(self, span: _Span):
+        """The span's ``ddp:<phase>`` event in the running profiler
+        session, entered; None when no session runs."""
+        cls = self._annotation
+        if cls is None or not cls.is_enabled():
+            return None
+        event = cls("ddp:" + span.phase,
+                    **_given(step=span.step, n=span.n, nbytes=span.nbytes))
+        event.__enter__()
+        return event
 
     def add_span(self, phase: str, start_monotonic: float, dur_s: float,
                  step: Optional[int] = None, overlap: bool = False,
-                 req: Optional[str] = None) -> None:
+                 req: Optional[str] = None, n: Optional[int] = None,
+                 nbytes: Optional[int] = None) -> None:
         """Record a span measured by the caller (``start_monotonic`` on
         the ``time.monotonic`` clock) — for sites that only know AFTER
         timing whether the interval was a real phase occurrence (e.g. the
         prefetch consumer's queue get, which may return the end-of-stream
         sentinel rather than a batch)."""
-        self._record(phase, step, start_monotonic, dur_s, overlap, req)
+        self._record(phase, step, start_monotonic, dur_s, overlap, req,
+                     n, nbytes)
 
     def _record(self, phase: str, step: Optional[int], start: float,
-                dur: float, overlap: bool,
-                req: Optional[str] = None) -> None:
-        rec = (phase, step, start - self._t0, dur, overlap, req)
+                dur: float, overlap: bool, req: Optional[str] = None,
+                n: Optional[int] = None,
+                nbytes: Optional[int] = None) -> None:
+        rec = (phase, step, start - self._t0, dur, overlap, req, n, nbytes)
         # Serialize OUTSIDE the lock: json.dumps is pure CPU on local
         # data, and holding the one shared lock through it would make
         # every producer thread contend on exactly the work being timed.
@@ -194,6 +273,10 @@ class SpanTracer:
         }
         if req is not None:  # request-scoped spans only — lines stay lean
             body["req"] = req
+        if n is not None:  # counts only where the site gave them
+            body["n"] = n
+        if nbytes is not None:
+            body["nbytes"] = nbytes
         line = (json.dumps(body) + "\n") if self._f is not None else None
         with self._lock:
             self._ring.append(rec)
@@ -226,9 +309,10 @@ class SpanTracer:
 
     @staticmethod
     def _as_dict(rec: tuple) -> dict:
-        phase, step, start, dur, overlap, req = rec
+        phase, step, start, dur, overlap, req, n, nbytes = rec
         return {"phase": phase, "step": step, "start_s": start,
-                "dur_s": dur, "overlap": overlap, "req": req}
+                "dur_s": dur, "overlap": overlap, "req": req,
+                "n": n, "nbytes": nbytes}
 
     def spans_since(self, t: float) -> List[dict]:
         """Completed spans whose start is at or after tracer-time ``t``

@@ -456,11 +456,15 @@ class Trainer:
         else:
             self.train_step = self._rebuild_step(self.lr_schedule)
 
-    def _epoch_losses_streaming(self, epoch: int, start: int = 0):
+    def _epoch_losses_streaming(self, epoch: int, start: int, setup):
         """Per-step dispatch over host-fed batches (the reference's loop,
         multigpu.py:104-107).  ``start`` is the mid-epoch resume offset
         (data_state): the prefetch engine fast-forwards to batch
-        ``start`` without materialising the skipped prefix."""
+        ``start`` without materialising the skipped prefix.  ``setup`` is
+        the epoch's open ``epoch_setup`` span: the engine's body runs at
+        the loop's first ``next()`` and ends the span when it is built
+        (pool made, first batches submitted), just before it first waits
+        for a batch."""
         epoch_losses = []
         from ..data.prefetch import prefetch_to_device
         if self.grad_accum > 1 or self.pp_plan is not None:
@@ -486,7 +490,7 @@ class Trainer:
                 self.mesh, depth=self.prefetch_depth,
                 workers=self.prefetch_workers, stats=self.prefetch_stats,
                 shard_fn=stacked_shard, tracer=self.tracer,
-                step0=self._host_step, start=start)
+                step0=self._host_step, start=start, on_ready=setup.end)
         else:
             # Worker pool augments + device_puts ahead of the loop (the
             # pin_memory/worker analogue, singlegpu.py:177); combined with
@@ -495,9 +499,11 @@ class Trainer:
             batches = prefetch_to_device(
                 self.train_loader, self.mesh, depth=self.prefetch_depth,
                 workers=self.prefetch_workers, stats=self.prefetch_stats,
-                tracer=self.tracer, step0=self._host_step, start=start)
+                tracer=self.tracer, step0=self._host_step, start=start,
+                on_ready=setup.end)
         step = self._host_step
         k = start  # epoch-local batch offset (the data_state coordinate)
+        traced = self.tracer.enabled
         t_prev = time.monotonic()
         for device_batch in batches:
             # Step-boundary preemption (resilience/preemption.py): checked
@@ -521,10 +527,15 @@ class Trainer:
                 continue
             # The dispatch span covers the jitted call only — enqueue
             # time plus whatever XLA makes it wait for (donated-buffer
-            # availability, compile on the first step); together with
-            # the prefetch engine's data_wait span this is the consumer
-            # loop's full wall, the "where did step N go" record.
-            with self.tracer.span("dispatch", step=step):
+            # availability, compile on the first step), and carries the
+            # call's samples.  A step of the consumer loop is the
+            # engine's data_wait and h2d (or, at depth 0, host_augment
+            # and h2d), then this; epoch_setup before the first step and
+            # epoch_close after the last make the epoch whole, the
+            # "where did step N go" record.
+            with self.tracer.span(
+                    "dispatch", step=step,
+                    n=device_batch["label"].size if traced else None):
                 self.state, loss = self.train_step(
                     self.state, device_batch, self.rng)
             epoch_losses.append(loss)
@@ -548,13 +559,18 @@ class Trainer:
                 self._watchdog.beat()
             if self._step_probe is not None:
                 self._step_probe(step)
-        return jnp.stack(epoch_losses) if epoch_losses else None
+        # The engine has shut down by now (its own epoch_close span: the
+        # loop's last next() runs its ``finally``).
+        with self.tracer.span("epoch_close", step=self._host_step):
+            return jnp.stack(epoch_losses) if epoch_losses else None
 
-    def _epoch_losses_resident(self):
-        """One (or two, with a ragged tail) jitted scan calls per epoch."""
+    def _epoch_losses_resident(self, setup):
+        """One (or two, with a ragged tail) jitted scan calls per epoch.
+        Every call's index matrix is built and shipped before the first
+        dispatch, under the epoch's open ``epoch_setup`` span, which
+        ends here with the matrices' bytes."""
         from .epoch import put_index_matrix
         full, tail = self.train_loader.epoch_index_matrix()
-        parts = []
         if self.grad_accum > 1:
             # Group the epoch's batches into [G, A, B] optimizer-step
             # stacks for the accumulation epoch scan — the same grouping
@@ -570,38 +586,36 @@ class Trainer:
                 calls.append(full[n_groups * a:][None])
             if tail is not None:
                 calls.append(tail[None, None, :])
-            s = self._host_step
-            for idx3 in calls:
-                idx = put_index_matrix(idx3, self.mesh)
-                # One dispatch per scan call: the span's step is the call's
-                # FIRST optimizer step (the whole-epoch granularity is the
-                # resident mode's dispatch pattern — per-step attribution
-                # lives inside XLA, reachable via --profile_dir).
-                with self.tracer.span("dispatch", step=s):
-                    self.state, losses = self.train_epoch(
-                        self.state, self.resident.images,
-                        self.resident.labels, idx, self.rng)
-                s += idx3.shape[0]
-                parts.append(losses)
-            return jnp.concatenate(parts) if parts else None
-        if full.shape[0]:
-            idx = put_index_matrix(full, self.mesh)
-            with self.tracer.span("dispatch", step=self._host_step):
+        else:
+            calls = [full] if full.shape[0] else []
+            if tail is not None:
+                calls.append(tail[None, :])
+        idxs = [put_index_matrix(c, self.mesh) for c in calls]
+        setup.count(nbytes=sum(c.nbytes for c in calls))
+        setup.end()
+        first = s = self._host_step
+        parts = []
+        for idx in idxs:
+            # One dispatch per scan call: the span's step is the call's
+            # FIRST optimizer step and its ``n`` the call's samples (the
+            # whole-epoch granularity is the resident mode's dispatch
+            # pattern — per-step attribution lives inside XLA, reachable
+            # via --profile_dir).
+            with self.tracer.span("dispatch", step=s, n=idx.size):
                 self.state, losses = self.train_epoch(
-                    self.state, self.resident.images, self.resident.labels,
-                    idx, self.rng)
+                    self.state, self.resident.images,
+                    self.resident.labels, idx, self.rng)
+            s += idx.shape[0]
             parts.append(losses)
-        if tail is not None:
-            idx = put_index_matrix(tail[None, :], self.mesh)
-            with self.tracer.span("dispatch",
-                                  step=self._host_step + full.shape[0]):
-                self.state, tail_loss = self.train_epoch(
-                    self.state, self.resident.images, self.resident.labels,
-                    idx, self.rng)
-            parts.append(tail_loss)
-        return jnp.concatenate(parts) if parts else None
+        with self.tracer.span("epoch_close", step=first):
+            return jnp.concatenate(parts) if parts else None
 
     def _run_epoch(self, epoch: int, start_offset: int = 0) -> None:
+        # epoch_setup: from here to the epoch's first data_wait (where
+        # batches stream) or first dispatch (where they are resident).
+        # Like epoch_close it carries the epoch's first global step.
+        setup = self.tracer.span("epoch_setup",
+                                 step=self._host_step).__enter__()
         b_sz = self.train_loader.per_replica_batch
         # Reference epoch header (multigpu.py:102) — without materialising
         # and discarding a probe batch to learn b_sz (multigpu.py:101).
@@ -613,8 +627,9 @@ class Trainer:
         self._epoch_origin[epoch] = (self._host_step, start_offset)
         self._host_epoch = epoch
         self.train_loader.set_epoch(epoch)
-        stacked = (self._epoch_losses_resident() if self.resident is not None
-                   else self._epoch_losses_streaming(epoch, start_offset))
+        stacked = (self._epoch_losses_resident(setup)
+                   if self.resident is not None else
+                   self._epoch_losses_streaming(epoch, start_offset, setup))
         n_losses = int(stacked.shape[0]) if stacked is not None else 0
         start_step = self._host_step
         self._host_step += n_losses
@@ -634,7 +649,9 @@ class Trainer:
             self._flush_losses(*prev)
 
     def _flush_losses(self, epoch: int, start_step: int, stacked) -> None:
-        with self.tracer.span("loss_flush", step=start_step):
+        with self.tracer.span(
+                "loss_flush", step=start_step,
+                n=int(stacked.shape[0]) if stacked is not None else 0):
             self._flush_losses_inner(epoch, start_step, stacked)
 
     def _flush_losses_inner(self, epoch: int, start_step: int,
@@ -976,25 +993,33 @@ class Trainer:
             # an unconditional flush would re-serialize every
             # epoch boundary for monitored runs).
             epoch_callback(epoch)
-        self._log_stragglers(epoch, t_epoch)
-        # analysis: divergence-ok(ctor-time config, identical on all ranks)
-        if self._preemption is not None:
-            # COLLECTIVE on multi-host (resilience/preemption.py): every
-            # rank calls it at every epoch boundary so the stop decision —
-            # and the emergency save's collective canonicalisation — run
-            # in lockstep.  The streaming loop also checks per step; this
-            # boundary check catches a notice that landed after the
-            # epoch's last dispatch, keeping the completed epoch's
-            # checkpoint as the emergency state.  Resident mode keeps the
-            # epoch-granular sync-id space (its dispatch unit); streaming
-            # uses the global-step space throughout so the two never mix
-            # sync counters.
-            stop = (self._preemption.should_stop(epoch, self.mesh)
-                    if self.resident is not None else
-                    self._preemption.should_stop_step(self._host_step,
-                                                      self.mesh))
-            if stop:
-                self._emergency_checkpoint(epoch)
+        # The rest of the boundary is epoch_close's, like the engine's
+        # shutdown and the stack of the losses before it.  The callback
+        # above is not: it names its own phases (cli.run's is a
+        # loss_flush and an eval).
+        stop = False
+        with self.tracer.span("epoch_close",
+                              step=self._epoch_origin[epoch][0]):
+            self._log_stragglers(epoch, t_epoch)
+            # analysis: divergence-ok(ctor-time config, identical on all ranks)
+            if self._preemption is not None:
+                # COLLECTIVE on multi-host (resilience/preemption.py):
+                # every rank calls it at every epoch boundary so the stop
+                # decision — and the emergency save's collective
+                # canonicalisation — run in lockstep.  The streaming loop
+                # also checks per step; this boundary check catches a
+                # notice that landed after the epoch's last dispatch,
+                # keeping the completed epoch's checkpoint as the
+                # emergency state.  Resident mode keeps the
+                # epoch-granular sync-id space (its dispatch unit);
+                # streaming uses the global-step space throughout so the
+                # two never mix sync counters.
+                stop = (self._preemption.should_stop(epoch, self.mesh)
+                        if self.resident is not None else
+                        self._preemption.should_stop_step(self._host_step,
+                                                          self.mesh))
+        if stop:
+            self._emergency_checkpoint(epoch)
 
     def _log_stragglers(self, epoch: int, since: float) -> None:
         """Per-epoch cross-host phase attribution (obs/aggregate.py).

@@ -101,6 +101,127 @@ def test_null_tracer_is_inert_and_default():
     assert not get_tracer().enabled
 
 
+def test_tracer_counts_recorded_returned_and_spilled_only_when_set(tmp_path):
+    """``n`` and ``nbytes`` ride a span into the ring, into what
+    ``spans_since`` returns, and into the spill line — there only where
+    the site gave them, so count-less lines stay as lean as before."""
+    spill = str(tmp_path / "spill.jsonl")
+    tr = SpanTracer(spill_path=spill)
+    with tr.span("dispatch", step=3, n=512):
+        pass
+    with tr.span("h2d", step=3, nbytes=1_575_936):
+        pass
+    with tr.span("data_wait", step=3):
+        pass
+    with tr.span("epoch_setup", step=4) as sp:
+        sp.count(nbytes=160)  # known only inside the body
+    tr.add_span("loss_flush", time.monotonic(), 0.0, step=0, n=17)
+    tr.close()
+    got = {s["phase"]: (s["n"], s["nbytes"]) for s in tr.spans_since(0.0)}
+    assert got == {"dispatch": (512, None), "h2d": (None, 1_575_936),
+                   "data_wait": (None, None), "epoch_setup": (None, 160),
+                   "loss_flush": (17, None)}
+    assert tr.last_spans()["h2d"]["nbytes"] == 1_575_936
+    lines = {l["phase"]: l for l in map(json.loads, open(spill))}
+    assert lines["dispatch"]["n"] == 512 and "nbytes" not in lines["dispatch"]
+    assert lines["h2d"]["nbytes"] == 1_575_936 and "n" not in lines["h2d"]
+    assert lines["epoch_setup"]["nbytes"] == 160
+    assert lines["loss_flush"]["n"] == 17
+    assert set(lines["data_wait"]) == {"phase", "step", "start_s", "dur_s",
+                                       "overlap", "host"}
+
+
+def test_null_tracer_takes_counts_and_returns_the_shared_noop():
+    """--obs_off: the counted call sites cost what the count-less ones
+    did — the one shared no-op span, nothing allocated, nothing read."""
+    null = NullTracer()
+    plain = null.span("dispatch", step=1)
+    counted = null.span("h2d", step=1, n=3, nbytes=4096)
+    assert counted is plain
+    with counted as sp:
+        assert sp is plain
+        sp.count(n=1, nbytes=2)
+    assert plain.__enter__() is plain  # a hand-entered span, ended by hand
+    plain.end()
+    null.add_span("loss_flush", 0.0, 0.0, step=0, n=17, nbytes=68)
+    assert null.spans_since(0.0) == []
+    assert not hasattr(plain, "__dict__")  # count() kept nothing on it
+
+
+def test_hand_entered_span_records_at_end():
+    """A phase that opens in one function and closes in another
+    (epoch_setup: opened by the trainer, ended by the prefetch engine)
+    is entered by hand and lands when ``end()`` is called."""
+    tr = SpanTracer()
+    sp = tr.span("epoch_setup", step=5).__enter__()
+    assert tr.spans_since(0.0) == []
+    with tr.span("host_augment", step=5, overlap=True):
+        pass
+    sp.end()
+    assert [(s["phase"], s["step"]) for s in tr.spans_since(0.0)] == [
+        ("host_augment", 5), ("epoch_setup", 5)]
+
+
+def _profiled_ddp_events(trace_dir):
+    """``{name: [stats dict, ...]}`` of the ``ddp:`` host events in the
+    newest profile under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("ddp:"):
+                    events.setdefault(ev.name, []).append(dict(ev.stats))
+    return events
+
+
+def test_spans_show_in_the_profiler_trace_one_dispatch_event_a_step(tmp_path):
+    """While a jax.profiler session runs, every span of a SpanTracer
+    built with no special argument is a host event ``ddp:<phase>`` of
+    that trace, with ``step`` and the counts as its arguments: a real
+    Trainer's two epochs leave one ``ddp:dispatch`` a step."""
+    import jax
+
+    from test_prefetch import tiny_trainer
+    tr = SpanTracer(ring=1 << 20)  # as benchmark/runners/train.py builds it
+    trainer, loader = tiny_trainer(tr, depth=2)
+    trainer.train(1)  # compile outside the profiled stretch
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    t0 = tr.now()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        trainer.train(2)
+    finally:
+        jax.profiler.stop_trace()
+    spans = tr.spans_since(t0)
+    events = _profiled_ddp_events(str(tmp_path))
+    steps = 2 * len(loader)
+    dispatches = [s for s in spans if s["phase"] == "dispatch"]
+    assert len(dispatches) == steps
+    assert sorted(e["step"] for e in events["ddp:dispatch"]) == sorted(
+        s["step"] for s in dispatches)
+    assert sorted(e["n"] for e in events["ddp:dispatch"]) == sorted(
+        s["n"] for s in dispatches)
+    # Every phase the tracer timed through span() has its events, as
+    # many as spans (add_span records an interval that is over: none).
+    for phase in {s["phase"] for s in spans}:
+        assert len(events["ddp:" + phase]) == sum(
+            s["phase"] == phase for s in spans), phase
+    assert all("nbytes" in e for e in events["ddp:h2d"])
+    # Outside a session the same tracer leaves nothing behind and the
+    # spans still land.
+    with tr.span("dispatch", step=10**6, n=1):
+        pass
+    assert tr.last_spans()["dispatch"]["step"] == 10**6
+
+
 # ---------------------------------------------------------------------------
 # export / report
 
@@ -186,6 +307,37 @@ def test_phase_summary_separates_serial_from_overlap():
     # Serial sum excludes the overlapped producer span.
     assert critical_s == pytest.approx(0.001 + 0.1 + 0.3 + 0.05 + 0.2)
     assert wall_s == pytest.approx(0.462)  # 0.0 .. 0.412+0.05
+
+
+def test_phase_summary_sums_counts_and_report_shows_them_where_present():
+    spans = [s for s in _sample_spans() if s["host"] == 0]
+    rows, _, _ = export.phase_summary(spans)
+    assert all(r["n"] is None and r["nbytes"] is None for r in rows)
+    assert "items" not in export.format_report(spans)  # old spills: as before
+    counted = [dict(s, n=512) if s["phase"] == "dispatch" else dict(s)
+               for s in spans]
+    counted += [{"phase": "h2d", "step": k, "start_s": 0.5 + k, "dur_s": 0.1,
+                 "overlap": False, "host": 0, "nbytes": 2_000_000}
+                for k in range(3)]
+    rows, _, _ = export.phase_summary(counted)
+    by = {r["phase"]: r for r in rows}
+    assert (by["dispatch"]["n"], by["dispatch"]["nbytes"]) == (1024, None)
+    assert (by["h2d"]["n"], by["h2d"]["nbytes"]) == (None, 6_000_000)
+    assert by["data_wait"]["n"] is None
+    report = export.format_report(counted)
+    assert "items" in report and "MB" in report
+    dispatch_line = next(l for l in report.splitlines()
+                         if l.startswith("dispatch"))
+    assert dispatch_line.split()[-2:] == ["1024", "-"]
+    h2d_line = next(l for l in report.splitlines() if l.startswith("h2d"))
+    assert h2d_line.split()[-2:] == ["-", "6.00"]
+    # The Perfetto export carries the counts in a slice's args.
+    xs = [e for e in export.to_trace_events(counted)["traceEvents"]
+          if e["ph"] == "X"]
+    assert {e["args"].get("n") for e in xs if e["name"] == "dispatch"} \
+        == {512}
+    assert all(e["args"]["nbytes"] == 2_000_000 for e in xs
+               if e["name"] == "h2d")
 
 
 def test_step_walls_and_slowest_steps():

@@ -309,3 +309,138 @@ def test_cli_prefetch_flags_end_to_end(tmp_path, capsys, monkeypatch):
     # The issue-named alias spelling maps onto the same destination.
     assert cli.build_parser("t").parse_args(
         ["1", "1", "--augment_device"]).device_augment
+
+
+# ---------------------------------------------------------------------------
+# the host's timeline: epoch_setup, the steps, epoch_close, and the counts
+
+
+def tiny_trainer(tracer, depth=2, resident=False, grad_accum=1):
+    """A two-replica deepnn Trainer over 52 samples (3 full steps of 16
+    and a ragged tail of 4) that reports into ``tracer``."""
+    from ddp_tpu.models import get_model
+    from ddp_tpu.optim import SGDConfig
+    from ddp_tpu.train import Trainer
+
+    ds, _ = synthetic(n_train=52, n_test=8, seed=4)
+    model = get_model("deepnn")
+    params, stats = model.init(jax.random.key(2))
+    loader = TrainLoader(ds, per_replica_batch=8, num_replicas=2, seed=2,
+                         augment=not resident)
+    trainer = Trainer(model, loader, params, stats, mesh=make_mesh(2),
+                      lr_schedule=lambda step: 0.02,
+                      sgd_config=SGDConfig(lr=0.02), save_every=10**9,
+                      snapshot_path=None, seed=2, resident=resident,
+                      device_augment=resident, grad_accum=grad_accum,
+                      prefetch_depth=depth, prefetch_workers=2,
+                      tracer=tracer)
+    return trainer, loader
+
+
+# What the consumer thread records for one step, by engine.
+_STEP_PHASES = {
+    "pooled": ["data_wait", "h2d", "dispatch"],
+    "depth0": ["host_augment", "h2d", "dispatch"],
+    "threaded": ["data_wait", "dispatch"],  # h2d on the producer thread
+    "resident": ["dispatch"],
+}
+
+
+@pytest.mark.parametrize("mode", sorted(_STEP_PHASES))
+def test_trainer_spans_tile_the_consumer_thread(mode):
+    """Per epoch the consumer thread records ``epoch_setup``, then the
+    steps' spans in the engine's order, then ``epoch_close`` (with the
+    deferred ``loss_flush`` of the epoch before among it); no two of its
+    spans overlap; ``dispatch.n`` sums to the epoch's samples and the
+    shipped bytes to the batches' (or the index matrices')."""
+    from ddp_tpu.obs.tracer import SpanTracer
+
+    tr = SpanTracer()
+    trainer, loader = tiny_trainer(
+        tr, depth=0 if mode == "depth0" else 2, resident=mode == "resident",
+        grad_accum=2 if mode == "threaded" else 1)
+    t0 = tr.now()
+    trainer.train(2)
+    spans = tr.spans_since(t0)
+    serial = sorted((s for s in spans if not s["overlap"]),
+                    key=lambda s: s["start_s"])
+    for a, b in zip(serial, serial[1:]):
+        assert a["start_s"] + a["dur_s"] <= b["start_s"] + 1e-9, (a, b)
+    # Split at epoch_setup: one group an epoch.
+    epochs = []
+    for s in serial:
+        if s["phase"] == "epoch_setup":
+            epochs.append([])
+        epochs[-1].append(s)  # IndexError: something before the first
+    assert len(epochs) == 2
+    samples = len(loader.samplers[0]) * 2
+    sample_bytes = sum(v[0].nbytes for v in loader.materialize(0).values())
+    for spans_e in epochs:
+        phases = [s["phase"] for s in spans_e]
+        n_step = phases.count("dispatch")
+        body = phases[1:1 + n_step * len(_STEP_PHASES[mode])]
+        assert body == _STEP_PHASES[mode] * n_step, phases
+        tail = phases[1 + len(body):]
+        assert "epoch_close" in tail
+        assert set(tail) <= {"epoch_close", "loss_flush"}, phases
+        first_step = spans_e[0]["step"]
+        dispatches = [s for s in spans_e if s["phase"] == "dispatch"]
+        assert dispatches[0]["step"] == first_step
+        assert {s["step"] for s in spans_e
+                if s["phase"] == "epoch_close"} == {first_step}
+        assert sum(s["n"] for s in dispatches) == samples
+        if mode == "resident":
+            full, tail_idx = loader.epoch_index_matrix()
+            assert spans_e[0]["nbytes"] == full.nbytes + tail_idx.nbytes
+        else:
+            assert spans_e[0]["nbytes"] is None
+    # Every h2d, on whichever thread, carries the bytes of its batch.
+    h2d = [s for s in spans if s["phase"] == "h2d"]
+    if mode == "resident":
+        assert h2d == []
+    else:
+        assert all(s["overlap"] == (mode == "threaded") for s in h2d)
+        assert sum(s["nbytes"] for s in h2d) == 2 * samples * sample_bytes
+    flushes = [s for s in spans if s["phase"] == "loss_flush"]
+    assert sum(s["n"] for s in flushes) == len(trainer.loss_history)
+
+
+def _engine_batches(engine):
+    ds, _ = synthetic(n_train=24, n_test=8, seed=1)
+    loader = TrainLoader(ds, per_replica_batch=4, num_replicas=2, seed=1)
+    loader.set_epoch(0)
+    if engine == "threaded":  # a plain iterable: no random access
+        return iter([loader.materialize(k) for k in range(len(loader))]), 2
+    return loader, (0 if engine == "depth0" else 2)
+
+
+@pytest.mark.parametrize("engine", ["pooled", "depth0", "threaded"])
+def test_engine_reports_ready_once_and_names_its_shutdown(engine):
+    """``on_ready`` fires once, inside the first ``next()``, before any
+    batch is waited for; the engine's shutdown (workers joined) is an
+    ``epoch_close`` span at ``step0``, also when the consumer abandons
+    the stream."""
+    from ddp_tpu.obs.tracer import SpanTracer
+
+    tr = SpanTracer()
+    ready = []
+    batches, depth = _engine_batches(engine)
+    stream = prefetch_to_device(
+        batches, make_mesh(2), depth=depth, workers=2, tracer=tr, step0=40,
+        on_ready=lambda: ready.append(
+            [s["phase"] for s in tr.spans_since(0.0) if not s["overlap"]]))
+    assert ready == []  # a generator: nothing runs before the first next()
+    first = next(stream)
+    assert ready == [[]]  # once, and no consumer-side span before it
+    nbytes = sum(np.asarray(v).nbytes for v in first.values())
+    stream.close()  # abandoned after one batch
+    spans = tr.spans_since(0.0)
+    assert [s["nbytes"] for s in spans
+            if s["phase"] == "h2d" and s["step"] == 40] == [nbytes]
+    closes = [s for s in spans if s["phase"] == "epoch_close"]
+    if engine == "depth0":
+        assert closes == []  # nothing to shut down
+    else:
+        assert [(s["step"], s["overlap"]) for s in closes] == [(40, False)]
+        assert spans[-1]["phase"] == "epoch_close"
+    assert len(ready) == 1
