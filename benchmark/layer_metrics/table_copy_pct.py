@@ -1,7 +1,10 @@
 """Share of the device-busy time spent in operations that write a whole
 copy of the resident table (``trace_reduce.table_seconds``): the
 relayout for the row gather, which belongs before the epoch and not in
-it.  Device trace; nothing to read where no operation writes one."""
+it.  Device trace.  A table that no operation copies reads 0.0: that is
+the goal, and a reading.  None only where the input is missing: no trace
+(a rehearsal), no resident table (batches stream), or a trace in which
+nothing ran."""
 from benchmark import trace_reduce
 
 
@@ -9,5 +12,4 @@ def read(ctx):
     tr, table = ctx.get("trace"), ctx.get("table")
     if not tr or not table or tr["busy_s"] <= 0:
         return None
-    table_s = trace_reduce.table_seconds(tr, **table)
-    return 100.0 * table_s / tr["busy_s"] if table_s > 0 else None
+    return 100.0 * trace_reduce.table_seconds(tr, **table) / tr["busy_s"]

@@ -280,7 +280,24 @@ def run(resolved: dict, args, process_age_s) -> dict:
         if v is not None and math.isfinite(v):
             result["metrics"][m["name"]] = {"value": float(v),
                                             "unit": m["unit"]}
+    if args.trace and on_chip:
+        # The driver holds a traced line to every metric listed for the
+        # cell; say which are missing before it does.
+        note = not_read_note(cell["name"], wanted, result["metrics"])
+        if note:
+            print(note, file=sys.stderr)
     return result
+
+
+def not_read_note(cell_name: str, wanted: list, metrics: dict):
+    """One line naming the metrics listed for the cell that the result
+    line lacks (a reader returned None or no finite number), or None
+    where the line is whole."""
+    missing = [m["name"] for m in wanted if m["name"] not in metrics]
+    if not missing:
+        return None
+    return (f"benchmark: listed for {cell_name}, not read: "
+            + ", ".join(missing))
 
 
 def _peak_bytes(stats: dict) -> int:

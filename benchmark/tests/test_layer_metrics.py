@@ -44,22 +44,63 @@ def ctx():
     }
 
 
+def read_metric(name, c):
+    return importlib.import_module("benchmark.layer_metrics." + name).read(c)
+
+
+@pytest.fixture(scope="module")
+def copy_free(ctx):
+    """The same trace as a program that stopped copying its table would
+    leave it: the three operations that write a whole copy taken out of
+    ``ops`` (the relayout fix of ledger PR 25; the busy union stays)."""
+    ops = ctx["trace"]["ops"]
+    kept = {name: secs for name, secs in ops.items()
+            if not tr.table_seconds({"ops": {name: secs}}, **ctx["table"])}
+    assert len(ops) - len(kept) == 3
+    return dict(ctx, trace=dict(ctx["trace"], ops=kept))
+
+
+@pytest.mark.parametrize("which", ["ctx", "copy_free"])
 @pytest.mark.parametrize("name", [m["name"] for m in SPEC["per_layer"]])
-def test_reader_reads_the_recorded_trace(ctx, name):
-    read = importlib.import_module("benchmark.layer_metrics." + name).read
-    value = read(ctx)
+def test_reader_reads_the_recorded_trace(request, which, name):
+    c = request.getfixturevalue(which)
+    value = read_metric(name, c)
     assert value is None or math.isfinite(value)
-    # Without a trace (a rehearsal) a device reader reads nothing.
     declared = {m["name"]: m for m in SPEC["per_layer"]}[name]
     if declared["source"] == "device_trace":
+        # A number with the table's copies and without them, but for the
+        # collectives: one chip holds none.
         assert value is not None or name.startswith("collective_")
-        assert read(dict(ctx, trace=None)) is None
+        # Without a trace (a rehearsal) a device reader reads nothing.
+        assert read_metric(name, dict(c, trace=None)) is None
+
+
+def test_a_table_never_copied_reads_zero_not_nothing(ctx, copy_free):
+    assert read_metric("table_copy_pct", ctx) > 0
+    assert read_metric("table_copy_pct", copy_free) == 0.0
+    assert (read_metric("step_device_ex_table_ms", copy_free)
+            == read_metric("step_device_ms", copy_free))
+    assert math.isfinite(read_metric("nonconv_device_pct", copy_free))
+    # None is for a missing input: no table, no trace, nothing ran.
+    assert read_metric("table_copy_pct", dict(copy_free, table=None)) is None
+    assert read_metric("table_copy_pct", dict(copy_free, trace=None)) is None
+    assert read_metric("table_copy_pct", dict(copy_free, trace=dict(
+        copy_free["trace"], busy_s=0.0))) is None
+
+
+def test_the_runner_names_what_a_traced_line_lacks():
+    from benchmark.runners.train import not_read_note
+    wanted = [{"name": "step_device_ms"}, {"name": "table_copy_pct"}]
+    got = {"step_device_ms": {"value": 78.0, "unit": "ms"}}
+    assert not_read_note("a_cell", wanted, got) == (
+        "benchmark: listed for a_cell, not read: table_copy_pct")
+    got["table_copy_pct"] = {"value": 0.0, "unit": "%"}
+    assert not_read_note("a_cell", wanted, got) is None
 
 
 def test_table_and_window_arithmetic(ctx):
     def read(name, c=ctx):
-        return importlib.import_module(
-            "benchmark.layer_metrics." + name).read(c)
+        return read_metric(name, c)
     busy = ctx["trace"]["busy_s"]
     table = (582531 + 1398988 + 1372524) * 1e-9   # test_trace_reduce.py
     assert read("step_device_ms") == pytest.approx(1e3 * busy)
