@@ -27,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops.gather import gather_rows
+from ..ops.gather import RowTable, gather_rows
 
 PAD = 4
 SIZE = 32
@@ -42,13 +42,15 @@ def random_crop_flip(rng: jax.Array, imgs: jax.Array) -> jax.Array:
     return _crop_flip_onehot(rng, imgs)
 
 
-def gather_crop_flip(rng: jax.Array, table: jax.Array,
+def gather_crop_flip(rng: jax.Array, table: RowTable,
                      idx_row: jax.Array) -> jax.Array:
     """Dataset-gather + RandomCrop(32, pad 4) + HFlip for the
     device-resident path (train/epoch.py).
 
-    ``table`` is the whole resident dataset ``[M,32,32,3]``; the batch is
-    pulled by the Pallas DMA row gather (ops/gather.py) and augmented by
+    ``table`` is the whole resident dataset as a
+    :class:`~ddp_tpu.ops.gather.RowTable` (``[M,24,128]`` on the device,
+    rows of ``[32,32,3]``); the batch is pulled by the Pallas DMA row
+    gather (ops/gather.py), reshaped to ``[N,32,32,3]`` and augmented by
     the one-hot matmuls below, in place of a fused clamped-gather
     formulation."""
     return _crop_flip_onehot(rng, gather_rows(table, idx_row))

@@ -8,6 +8,13 @@ by index on device (train/epoch.py).  Per-epoch host->device traffic drops
 from ~150 MB of images to a ~200 KB int32 index matrix, and the input
 pipeline stops existing as a bottleneck (SURVEY.md §7 hard-part #4).
 
+The images go up in the row gather's layout (ops/gather.py
+:class:`~ddp_tpu.ops.gather.RowTable`): ``u8[N, D/128, 128]`` where a
+row's element count ``D`` is a multiple of 128 (CIFAR's 3,072 is), the
+rows as they are where it is not.  The reshape is a free view on the host
+and a copy of every byte on the device, so it happens here, once, and no
+train or evaluation program ever has the whole table as a result.
+
 Augmentation correspondingly moves on device (data/device_augment.py) —
 the same RandomCrop+HFlip distribution as the host path (torchvision
 transforms, singlegpu.py:154-160).
@@ -25,6 +32,7 @@ import numpy as np
 
 from jax.sharding import Mesh
 
+from ..ops.gather import RowTable
 from ..parallel.mesh import replicated_sharding
 from .cifar10 import Dataset
 
@@ -48,7 +56,10 @@ def _device_bytes_limit(device) -> Optional[int]:
 
 
 class ResidentData:
-    """``dataset.images``/``labels`` as replicated device arrays.
+    """``dataset.images``/``labels`` as replicated device arrays:
+    ``images`` a :class:`~ddp_tpu.ops.gather.RowTable` (the device array
+    is ``images.data``, a sample's shape ``images.row_shape``), ``labels``
+    int32 ``[N]``.
 
     uint8 images on device; the ToTensor u8/255 scaling happens inside the
     train step (train/step.py ``_as_input``), so HBM holds the dataset at
@@ -63,7 +74,7 @@ class ResidentData:
 
     def __init__(self, dataset: Dataset, mesh: Mesh):
         rep = replicated_sharding(mesh)
-        images = np.ascontiguousarray(dataset.images)
+        images = RowTable.from_rows(np.ascontiguousarray(dataset.images))
         labels = np.ascontiguousarray(dataset.labels, dtype=np.int32)
         # Probe an ADDRESSABLE device: under multi-host, mesh device 0
         # belongs to process 0 only, and a non-addressable device's
@@ -84,7 +95,7 @@ class ResidentData:
             # disables the guard everywhere.
             from ..parallel.mesh import process_min_mib
             limit = process_min_mib(mesh, limit)
-        needed = images.nbytes + labels.nbytes
+        needed = images.data.nbytes + labels.nbytes
         if limit is not None and needed > HBM_BUDGET_FRACTION * limit:
             raise ValueError(
                 f"resident mode replicates the whole dataset into every "
@@ -97,12 +108,10 @@ class ResidentData:
                 f"(optionally with --device_augment), or shrink the "
                 f"dataset.")
         if jax.process_count() == 1:
-            self.images = jax.device_put(images, rep)
-            self.labels = jax.device_put(labels, rep)
+            self.images, self.labels = jax.device_put((images, labels), rep)
         else:
             # Explicit global shapes (= local: fully replicated), so the
             # upload works on asymmetric host->device topologies too.
-            self.images = jax.make_array_from_process_local_data(
-                rep, images, images.shape)
-            self.labels = jax.make_array_from_process_local_data(
-                rep, labels, labels.shape)
+            self.images, self.labels = jax.tree_util.tree_map(
+                lambda a: jax.make_array_from_process_local_data(
+                    rep, a, a.shape), (images, labels))

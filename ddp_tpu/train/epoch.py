@@ -50,8 +50,10 @@ def make_train_epoch(model, sgd_config: sgd_lib.SGDConfig,
 
     Returns ``epoch_fn(state, images, labels, idx, rng) -> (state, losses)``
     where ``images``/``labels`` are the device-resident dataset (replicated,
-    data/resident.py), ``idx`` is an int32 ``[steps, global_batch]`` matrix
-    of sample indices sharded on its batch (second) axis, and ``losses`` is
+    data/resident.py: ``images`` a :class:`~ddp_tpu.ops.gather.RowTable`,
+    never reshaped or copied in here), ``idx`` is an int32
+    ``[steps, global_batch]`` matrix of sample indices sharded on its
+    batch (second) axis, and ``losses`` is
     the per-step global-mean loss vector ``[steps]`` — the loss stream the
     reference never logs (SURVEY.md §5).
 

@@ -170,8 +170,9 @@ def _micro_from_batch(device_augment: bool):
 
 def micro_from_table(images, labels, device_augment: bool):
     """``get_micro`` for device-resident paths: the scanned value is an
-    index row into the HBM-resident dataset (Pallas DMA gather,
-    ops/gather.py; fused gather+crop+flip under device augmentation)."""
+    index row into the HBM-resident dataset (``images`` a
+    :class:`~ddp_tpu.ops.gather.RowTable`; Pallas DMA gather,
+    ops/gather.py; gather+crop+flip under device augmentation)."""
 
     def get_micro(aug_rng, idx_row):
         if device_augment:

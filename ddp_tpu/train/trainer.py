@@ -356,7 +356,10 @@ class Trainer:
             from .epoch import make_train_epoch, make_train_epoch_accum
             from .zero import (make_train_epoch_zero,
                                make_train_epoch_zero_accum)
-            self.resident = ResidentData(train_loader.dataset, mesh)
+            with self.tracer.span(
+                    "resident_upload",
+                    nbytes=train_loader.dataset.images.nbytes):
+                self.resident = ResidentData(train_loader.dataset, mesh)
             build = {(False, False): make_train_epoch,
                      (False, True): make_train_epoch_accum,
                      (True, False): make_train_epoch_zero,

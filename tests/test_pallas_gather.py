@@ -1,17 +1,27 @@
-"""The Pallas row-gather kernel (ops/gather.py) in interpret mode.
+"""The row gather (ops/gather.py): the table's layout, the Pallas kernel in
+interpret mode, and the compiled program's text.
 
-The CPU test mesh exercises the XLA fallback everywhere else; this pins the
-kernel itself — same values as ``table[idx]`` — so the TPU fast path is not
-tested only by construction.
+The CPU test mesh holds the table in the layout the chip holds and gathers
+with the XLA gather; the ``pallas`` cases run the kernel itself in
+interpret mode — same values as ``rows[idx]`` — so the TPU fast path is not
+tested only by construction.  The last tests compile the scanned gather for
+a described v5e chip (no chip attached) and read its text.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ddp_tpu.ops import gather as gather_mod
+from ddp_tpu.ops.gather import RowTable, gather_rows, whole_table_writes
+from ddp_tpu.parallel.mesh import DATA_AXIS, make_mesh
 
 
-def test_pallas_row_gather_interpret(monkeypatch):
+@pytest.fixture
+def pallas_interpret(monkeypatch):
+    """Take the TPU branch of ``gather_rows`` on the CPU backend: the
+    kernel runs in Pallas' interpret mode."""
     from jax.experimental import pallas as pl
 
     orig = pl.pallas_call
@@ -21,17 +31,189 @@ def test_pallas_row_gather_interpret(monkeypatch):
         return orig(*args, **kw)
 
     monkeypatch.setattr(pl, "pallas_call", interp)
-    rng = np.random.default_rng(0)
-    table = rng.integers(0, 256, (40, 256), dtype=np.uint8)
-    idx = rng.integers(0, 40, 9).astype(np.int32)
-    out = gather_mod._pallas_row_gather(jnp.asarray(table), jnp.asarray(idx))
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+    monkeypatch.setattr(gather_mod, "_use_pallas", lambda: True)
 
 
-def test_gather_rows_fallback_matches():
-    """On the CPU backend gather_rows is the XLA gather; shape-generic."""
-    rng = np.random.default_rng(1)
-    table = rng.random((30, 32, 32, 3)).astype(np.float32)
-    idx = rng.integers(0, 30, 7).astype(np.int32)
-    out = gather_mod.gather_rows(jnp.asarray(table), jnp.asarray(idx))
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+def _rows(shape, dtype=np.uint8, seed=0):
+    rng = np.random.default_rng(seed)
+    if np.issubdtype(dtype, np.integer):
+        return rng.integers(0, 256, shape, dtype=dtype)
+    return rng.random(shape).astype(dtype)
+
+
+@pytest.mark.parametrize("row_shape,stored", [
+    ((32, 32, 3), (24, 128)),   # CIFAR: 3,072 = 24 x 128
+    ((256,), (2, 128)),
+    ((128,), (1, 128)),
+    ((5, 5, 3), (5, 5, 3)),     # 75 elements: no lane layout, kept as is
+    ((100,), (100,)),
+])
+def test_row_table_layout(row_shape, stored):
+    """``from_rows`` stores ``[M, D/128, 128]`` where D % 128 == 0 and the
+    rows as they are where it is not; either way it remembers the row
+    shape, the host reshape is a view, and the table is ONE pytree leaf
+    that ``jit`` and ``device_put`` take like a bare array."""
+    rows = _rows((12,) + row_shape)
+    table = RowTable.from_rows(rows)
+    assert table.row_shape == row_shape
+    assert table.data.shape == (12,) + stored
+    assert np.shares_memory(table.data, rows)
+    np.testing.assert_array_equal(table.data.reshape(rows.shape), rows)
+    leaves, treedef = jax.tree_util.tree_flatten(table)
+    assert len(leaves) == 1 and leaves[0] is table.data
+    back = jax.tree_util.tree_unflatten(treedef, leaves)
+    assert back.row_shape == row_shape
+    on_dev = jax.device_put(table, jax.devices()[0])
+    assert isinstance(on_dev, RowTable) and on_dev.row_shape == row_shape
+    assert isinstance(on_dev.data, jax.Array)
+    out = jax.jit(lambda t: t)(on_dev)
+    assert isinstance(out, RowTable) and out.data.shape == table.data.shape
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("row_shape,dtype", [
+    ((32, 32, 3), np.uint8), ((256,), np.uint8), ((5, 5, 3), np.uint8),
+    ((32, 32, 3), np.float32),
+])
+def test_gather_rows_equals_indexing(kernel, row_shape, dtype, request):
+    """``gather_rows`` on the stored layout == ``rows[idx]`` bit for bit,
+    out-of-range indices clamped as XLA's gather clamps them."""
+    if kernel == "pallas":
+        request.getfixturevalue("pallas_interpret")
+    rows = _rows((40,) + row_shape, dtype, seed=1)
+    idx = np.random.default_rng(2).integers(0, 40, 9).astype(np.int32)
+    idx[:3] = -1, 40, 1000
+    out = jax.jit(gather_rows)(RowTable.from_rows(jnp.asarray(rows)), idx)
+    assert out.shape == (9,) + row_shape and out.dtype == rows.dtype
+    np.testing.assert_array_equal(np.asarray(out),
+                                  rows[np.clip(idx, 0, 39)])
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("row_shape", [(32, 32, 3), (5, 5, 3)])
+def test_gather_rows_under_shard_map(kernel, row_shape, request):
+    """The resident epoch's arrangement: table replicated (``P()`` covers
+    the whole RowTable), indices sharded on ``data``, inside a scan."""
+    if kernel == "pallas":
+        request.getfixturevalue("pallas_interpret")
+    mesh = make_mesh(2)
+    rows = _rows((40,) + row_shape, seed=3)
+    idx = np.random.default_rng(4).integers(-2, 44, (3, 8)).astype(np.int32)
+    table = jax.device_put(RowTable.from_rows(rows),
+                           NamedSharding(mesh, P()))
+
+    def body(table, idx_matrix):
+        return jax.lax.scan(
+            lambda _, idx_row: (None, gather_rows(table, idx_row)),
+            None, idx_matrix)[1]
+
+    out = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P(None, DATA_AXIS)),
+        out_specs=P(None, DATA_AXIS),
+        # Interpret mode evaluates the index_map outside the vma rules
+        # (the compiled kernel's declared vma: the chip's self-check).
+        check_vma=kernel != "pallas"))(
+            table, jax.device_put(idx, NamedSharding(mesh,
+                                                     P(None, DATA_AXIS))))
+    np.testing.assert_array_equal(np.asarray(out),
+                                  rows[np.clip(idx, 0, 39)])
+
+
+def test_pallas_kernel_takes_the_table_as_stored(pallas_interpret):
+    """No reshape of the table on the Pallas path: the only operations of
+    the traced gather with a table-sized result are none at all."""
+    table = RowTable.from_rows(_rows((40, 32, 32, 3)))
+    jaxpr = jax.make_jaxpr(gather_rows)(table, np.arange(8, dtype=np.int32))
+    table_sized = [e for e in jaxpr.jaxpr.eqns
+                   for v in e.outvars if v.aval.shape[:1] == (40,)]
+    assert table_sized == [], table_sized
+
+
+_HLO = """\
+HloModule jit_epoch, is_scheduled=true
+%body (arg: (s32[], u8[604388,24,128])) -> (s32[], u8[604388,24,128]) {
+  %arg = (s32[]{:T(128)}, u8[604388,24,128]{2,1,0:T(8,128)(4,1)}) parameter(0)
+  %gte.1 = u8[604388,24,128]{2,1,0:T(8,128)(4,1)} get-tuple-element(%arg), index=1
+  %gather.4 = u8[512,24,128]{2,1,0:T(8,128)(4,1)S(1)} custom-call(%idx, %gte.1), custom_call_target="tpu_custom_call"
+  %labels.2 = s32[604388]{0:T(1024)} copy(%l)
+  ROOT %tuple.11 = (s32[]{:T(128)}, u8[604388,24,128]{2,1,0:T(8,128)(4,1)}) tuple(%add.2, %gte.1)
+}
+ENTRY %main (t: u8[604388,24,128]) -> s32[] {
+  %t = u8[604388,24,128]{2,1,0:T(8,128)(4,1)} parameter(0), sharding={replicated}
+  %while = (s32[]{:T(128)}, u8[604388,24,128]{2,1,0:T(8,128)(4,1)}) while(%tuple.9), condition=%cond, body=%body
+%WRITE%
+}
+"""
+
+
+@pytest.mark.parametrize("line,flagged", [
+    ("", False),  # carriers only: parameter, get-tuple-element, tuple, while
+    ("  %copy.258 = u8[604388,24,128]{2,1,0:T(8,128)(4,1)} copy(%bitcast.3)",
+     True),
+    ("  %fusion.7 = u8[604388,3072]{1,0:T(8,128)(4,1)} fusion(%t), "
+     "kind=kLoop, calls=%fused", True),
+    ("  ROOT %r = (s32[], u8[604388,32,32,3]{3,2,1,0}) fusion(%t), "
+     "kind=kLoop, calls=%f", True),
+    ("  %bc = u8[604388,3072]{1,0} bitcast(%t)", False),
+    ("  %half = u8[604388,12,128]{2,1,0} copy(%x)", False),  # under a row
+    ("  %other = u8[604387,24,128]{2,1,0} copy(%x)", False),  # other rows
+])
+def test_whole_table_writes_rule(line, flagged):
+    """``trace_reduce.table_seconds``' rule on a compiled module's text:
+    a result with the table's rows first and a row's elements behind it,
+    written by an instruction that does more than carry it."""
+    found = whole_table_writes(_HLO.replace("%WRITE%", line), 604388, 3072)
+    assert (found == [line.strip()]) if flagged else (found == [])
+
+
+# A described chip: the TPU's compiler runs here with no chip attached
+# (nothing executes).  Described inside the fixture and in this file only:
+# one process at a time may hold the TPU's library.
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # What is compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep it out.
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("rows,batch", [(604388, 512), (50000, 3072)])
+def test_scanned_gather_compiles_for_v5e_without_copying_the_table(
+        one_chip, monkeypatch, rows, batch):
+    """The benchmark's two table sizes, at real size, through the TPU's
+    compiler: the Mosaic kernel is in the program, no instruction writes
+    the whole table, the program needs no temporary at all, and the
+    table's tiled layout pads nothing (the HBM guard counts ``nbytes``)."""
+    monkeypatch.setattr(gather_mod, "_use_pallas", lambda: True)
+
+    def scanned(table, idx_matrix):
+        def body(_, idx_row):
+            return None, gather_rows(table, idx_row).sum(
+                axis=(1, 2, 3), dtype=jnp.int32)
+        return jax.lax.scan(body, None, idx_matrix)[1]
+
+    table = RowTable(jax.ShapeDtypeStruct((rows, 24, 128), jnp.uint8,
+                                          sharding=one_chip), (32, 32, 3))
+    idx = jax.ShapeDtypeStruct((8, batch), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(scanned).lower(table, idx).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert whole_table_writes(text, rows, 3072) == []
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == rows * 3072 + 8 * batch * 4
+    assert mem.temp_size_in_bytes < rows * 3072 // 100

@@ -14,12 +14,15 @@ devices: compiling the scanned VGG epoch for an 8-device CPU mesh takes
 tens of minutes (CPU-backend artifact; the real-TPU compile is ~15 s).
 """
 import functools
+import math
+import re
 
 import jax
 import numpy as np
 import pytest
 
 from ddp_tpu.data import EvalLoader, ResidentData, TrainLoader, synthetic
+from ddp_tpu.data.cifar10 import Dataset
 from ddp_tpu.models import get_model
 from ddp_tpu.optim import SGDConfig, triangular_lr
 from ddp_tpu.parallel import make_mesh
@@ -161,6 +164,112 @@ def test_evaluate_resident_matches_streaming():
     acc_res = evaluate_resident(model, params, stats,
                                 ResidentData(test_ds, mesh), loader, mesh)
     assert abs(acc_stream - acc_res) < 1e-4, (acc_stream, acc_res)
+
+
+@pytest.mark.parametrize("row_shape,stored", [
+    ((32, 32, 3), (24, 128)),  # 3,072 elements: the gather's lane layout
+    ((5, 5, 3), (5, 5, 3)),    # 75: not a multiple of 128, uploaded as is
+])
+def test_resident_data_is_stored_in_the_gathers_layout(row_shape, stored):
+    """``ResidentData`` uploads ``[N, D/128, 128]`` where D % 128 == 0 and
+    the rows as they are where it is not, replicated, with the row shape
+    beside the array; ``gather_rows`` gives ``dataset.images[idx]`` bit for
+    bit from either."""
+    from ddp_tpu.ops.gather import RowTable, gather_rows
+
+    rng = np.random.default_rng(7)
+    ds = Dataset(rng.integers(0, 256, (44,) + row_shape, dtype=np.uint8),
+                 rng.integers(0, 10, 44).astype(np.int64))
+    mesh = make_mesh(2)
+    res = ResidentData(ds, mesh)
+    assert isinstance(res.images, RowTable)
+    assert res.images.row_shape == row_shape
+    assert res.images.data.shape == (44,) + stored
+    assert res.images.data.dtype == np.uint8
+    assert res.images.data.sharding.is_fully_replicated
+    assert res.labels.shape == (44,) and res.labels.dtype == np.int32
+    idx = rng.integers(0, 44, 16).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(gather_rows)(res.images, idx)), ds.images[idx])
+
+
+_TENSOR = re.compile(r"tensor<(\d+(?:x\d+)+)x\w+>")
+
+
+def _table_results(mlir_text, rows, row_elems):
+    """The operations of a lowered module whose result is a whole copy of
+    a ``rows``-row table (``ops.gather.whole_table_writes``' rule, on
+    StableHLO text: result types follow the last ``->``, or the last
+    `` : `` of an operation that prints one type; an operation that opens
+    a region prints them on the line that closes it)."""
+    found = []
+    for line in mlir_text.splitlines():
+        opens, closes = line.endswith("{"), line.lstrip().startswith("}")
+        if opens or not (" = " in line or closes):
+            continue
+        types = (line.rsplit("->", 1) if "->" in line
+                 else line.rsplit(" : ", 1))[-1]
+        for dims in _TENSOR.findall(types):
+            dims = [int(d) for d in dims.split("x")]
+            if dims[0] == rows and math.prod(dims[1:]) >= row_elems:
+                found.append(line.strip()[:160])
+    return found
+
+
+def test_table_results_rule():
+    text = ("%1 = stablehlo.reshape %0 : (tensor<52x32x32x3xui8>) -> "
+            "tensor<52x3072xui8>\n"
+            "%2 = stablehlo.custom_call @tpu_custom_call(%i, %arg1) : "
+            "(tensor<8xi32>, tensor<52x24x128xui8>) -> tensor<8x24x128xui8>\n"
+            "%3 = stablehlo.add %a, %b : tensor<52x24x128xui8>\n"
+            "%4 = stablehlo.gather(%l, %i) : (tensor<52xi32>) -> "
+            "tensor<8xi32>\n"
+            "%5:2 = sdy.manual_computation(%arg0) manual_axes={\"data\"} "
+            "(%arg9: tensor<52x24x128xui8>) {\n"
+            "} : (tensor<52x24x128xui8>) -> (tensor<4xf32>, "
+            "tensor<52x3072xui8>)")
+    found = _table_results(text, 52, 3072)
+    assert [f[:2] for f in found] == ["%1", "%3", "} "]
+
+
+@pytest.mark.parametrize("program", ["train", "train_augment", "eval"])
+def test_resident_programs_never_write_the_table(program, monkeypatch):
+    """Lowered for the TPU (the Pallas branch, no chip needed: nothing is
+    compiled), a resident train epoch and the evaluation scan hold the
+    Mosaic kernel and NO operation whose result is the whole table: the
+    relayout that a table stored as ``[N,32,32,3]`` paid on every step
+    (PERF.md section 6, PR 27) cannot come back unseen."""
+    from ddp_tpu.ops import gather as gather_mod
+    from ddp_tpu.train.epoch import make_eval_epoch, put_index_matrix
+
+    monkeypatch.setattr(gather_mod, "_use_pallas", lambda: True)
+    rows = 52  # no batch, width or step count of these programs is 52
+    ds, _ = synthetic(n_train=rows, n_test=8, seed=4)
+    mesh = make_mesh(2)
+    model = get_model("deepnn")
+    params, stats = model.init(jax.random.key(2))
+    if program == "eval":
+        loader = EvalLoader(ds, 8, 2)
+        res = ResidentData(ds, mesh)
+        idx, mask = loader.epoch_index_matrix()
+        fn = make_eval_epoch(model, mesh)
+        args = (params, stats, res.images, res.labels,
+                put_index_matrix(idx, mesh), put_index_matrix(mask, mesh))
+    else:
+        loader = TrainLoader(ds, 8, 2, seed=2, augment=False)
+        tr = Trainer(model, loader, params, stats, mesh=mesh,
+                     lr_schedule=lambda step: 0.02,
+                     sgd_config=SGDConfig(lr=0.02), save_every=10**9,
+                     snapshot_path=None, seed=2, resident=True,
+                     device_augment=program == "train_augment")
+        full, _tail = loader.epoch_index_matrix()
+        fn = tr.train_epoch
+        args = (tr.state, tr.resident.images, tr.resident.labels,
+                put_index_matrix(full, mesh), tr.rng)
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert f"tensor<{rows}x24x128xui8>" in text  # the table is an operand
+    assert _table_results(text, rows, 32 * 32 * 3) == []
 
 
 def test_resident_cli_end_to_end(tmp_path, capsys, monkeypatch):

@@ -790,7 +790,8 @@ def _trace_accum_epoch(monkeypatch, module_name, builder):
                               steps_per_epoch=34)
     fn = builder(mod)(model, SGDConfig(), sched, mesh)
     G, A, B = 17, 2, 8  # G*A = 34 > 32, A = 2 <= 32
-    images = jnp.zeros((16, 32, 32, 3), jnp.float32)
+    from ddp_tpu.ops.gather import RowTable
+    images = RowTable.from_rows(jnp.zeros((16, 32, 32, 3), jnp.float32))
     labels = jnp.zeros((16,), jnp.int32)
     idx = put_index_matrix(np.zeros((G, A, B), np.int32), mesh)
     if module_name.endswith("zero"):
