@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import functools
+import json
 import os
 import re
 import sys
@@ -46,8 +47,17 @@ def build_parser(description: str) -> argparse.ArgumentParser:
                    help="Input batch size on each device (default: 512)")
     # Framework extensions (all default to reference behavior).
     p.add_argument("--model", default="vgg",
-                   choices=["vgg", "deepnn", "resnet18"],
+                   choices=["vgg", "deepnn", "resnet18", "tinylm", "nemotron_h"],
                    help="Model to train (reference trains VGG)")
+    p.add_argument("--model_config", default=None, metavar="FILE",
+                   help="Configuration file (JSON) of a model that is "
+                        "built from one: --model nemotron_h reads its "
+                        "layer pattern, widths and the share held here "
+                        "from it, e.g. benchmark/configs/"
+                        "nemotron3_nano_30b_a3b_ep16.json.  With "
+                        "--synthetic such a model trains on the seeded "
+                        "token generator (data/tokens.py) at the file's "
+                        "seq_len")
     p.add_argument("--data_root", default=cifar10.DEFAULT_ROOT,
                    help="CIFAR-10 root (reference: data/cifar10)")
     p.add_argument("--synthetic", action="store_true",
@@ -637,16 +647,41 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     # axis replicates the batch (parallel/mesh.py:data_axis_size).
     from .parallel.mesh import data_axis_size
     n_replicas = data_axis_size(mesh)
+    model_config = None
+    if args.model_config:
+        with open(args.model_config) as f:
+            model_config = json.load(f)
+    model = get_model(args.model, model_config)
     # The native kernel serves host augmentation only; a run that
-    # augments on device never builds it.
-    if args.device_augment or args.resident:
+    # augments on device, or trains on token ids, never builds it.
+    if args.device_augment or args.resident or model.tokens:
         native_augment = "n/a"
     else:
         from .data import native
         native_augment = "on" if native.get_lib() is not None else "off"
     print(device_line(mesh, native_augment=native_augment), flush=True)
 
-    if args.synthetic:
+    if model.tokens:
+        if not args.synthetic:
+            raise SystemExit(
+                f"--model {args.model} takes token ids and trains on the "
+                "seeded token generator: pass --synthetic (no token corpus "
+                "reader exists yet)")
+        if args.resident or args.device_augment or args.eval_every:
+            raise SystemExit(
+                f"--model {args.model}: --resident and --device_augment "
+                "are the image pipeline's (a table of image rows in HBM, "
+                "crops and flips; token rows stream through the host "
+                "loader), and --eval_every scores accuracy over classes")
+        from .data.tokens import synthetic_tokens
+        vocab, seq_len = model.tokens
+        if not seq_len:
+            raise SystemExit(f"{args.model_config} gives no seq_len: the "
+                             "length of the synthetic sequences")
+        train_ds = synthetic_tokens(args.synthetic_size, seq_len, vocab,
+                                    seed=args.seed)
+        test_ds = synthetic_tokens(1, seq_len, vocab, seed=args.seed + 1)
+    elif args.synthetic:
         train_ds, test_ds = cifar10.synthetic(
             n_train=args.synthetic_size,
             n_test=max(args.synthetic_size // 4, 64),
@@ -663,7 +698,6 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
                 "Pass --synthetic, or drop the flag.")
         train_ds, test_ds = cifar10.load(args.data_root)
 
-    model = get_model(args.model)
     if args.init_from_torch:
         params, batch_stats = _load_torch_init(args.model,
                                                args.init_from_torch)
@@ -727,7 +761,7 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
     device_augment = args.device_augment or args.resident
     train_loader = TrainLoader(train_ds, args.batch_size, n_replicas,
                                seed=args.seed, local_replicas=local_replicas,
-                               augment=not device_augment)
+                               augment=not (device_augment or model.tokens))
     # Triangular schedule (reference singlegpu.py:142-149) with
     # steps_per_epoch derived from the real shard size and the triangle span
     # tied to the CLI epoch count — the two sanctioned fixes to the
@@ -1216,6 +1250,14 @@ def _run_guarded(args, preemption, metrics, model, train_loader, params,
         # weights are unchanged — reuse that accuracy instead of a second
         # identical full-test-set collective (minutes at scale).  Every
         # process took the same branch, so multi-host stays in lockstep.
+        if model.tokens:
+            # No accuracy over classes for a token model, and no held-out
+            # corpus: its record is the mean next-token loss a step.
+            last = trainer.loss_history[-len(train_loader):]
+            print(f"evaluation skipped: {args.model} is a token model "
+                  "(accuracy over classes does not apply); mean next-token "
+                  f"loss of the last epoch's steps: {np.mean(last):.4f}")
+            return float("nan")
         if last_periodic_eval and \
                 last_periodic_eval[0][0] == args.total_epochs - 1:
             accuracy = last_periodic_eval[0][1]

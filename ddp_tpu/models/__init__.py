@@ -3,6 +3,7 @@
 model-agnostic."""
 from __future__ import annotations
 
+import importlib
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -12,23 +13,42 @@ class ModelDef(NamedTuple):
     name: str
     init: Callable[..., Tuple[Dict, Dict]]
     apply: Callable[..., Tuple[jax.Array, Dict]]
+    # ``(vocabulary, sequence length)`` of a model whose input is token
+    # ids ``i32[B,T]`` and whose loss is a position's; None for a model
+    # that takes images.  What the Trainer and the CLI branch on: a
+    # property of the model, not a list of names.
+    tokens: Optional[Tuple[int, int]] = None
 
 
-def get_model(name: str) -> ModelDef:
-    if name == "vgg":
-        from . import vgg
-        return ModelDef("vgg", vgg.init, vgg.apply)
-    if name == "deepnn":
-        from . import deepnn
-        return ModelDef("deepnn", deepnn.init, deepnn.apply)
-    if name == "resnet18":
-        from . import resnet
-        return ModelDef("resnet18", resnet.init, resnet.apply)
-    if name == "transformer":
-        from . import transformer
-        return ModelDef("transformer", transformer.init, transformer.apply)
-    if name == "tinylm":
-        from . import transformer
-        return ModelDef("tinylm", transformer.lm_init, transformer.lm_apply)
-    raise ValueError(f"unknown model {name!r}; available: vgg, deepnn, "
-                     "resnet18, transformer, tinylm")
+# name -> (module, init, apply, tokens): models that are modules of
+# constants; ``tokens`` names the module's (vocabulary, length) constants.
+_MODULE_MODELS = {
+    "vgg": ("vgg", "init", "apply", None),
+    "deepnn": ("deepnn", "init", "apply", None),
+    "resnet18": ("resnet", "init", "apply", None),
+    "transformer": ("transformer", "init", "apply", None),
+    "tinylm": ("transformer", "lm_init", "lm_apply", ("VOCAB", "T_MAX")),
+}
+# name -> module with ``build(config) -> (init, apply, tokens)``: models
+# built from a configuration file (``--model_config``).
+_CONFIG_MODELS = {"nemotron_h": "nemotron_h"}
+MODEL_NAMES = tuple(_MODULE_MODELS) + tuple(_CONFIG_MODELS)
+
+
+def get_model(name: str, config: Optional[dict] = None) -> ModelDef:
+    """The model of that name.  ``config`` is read by the models that are
+    built from a configuration and ignored by the others."""
+    if name in _MODULE_MODELS:
+        module, init, apply, tokens = _MODULE_MODELS[name]
+        mod = importlib.import_module(f"{__name__}.{module}")
+        return ModelDef(name, getattr(mod, init), getattr(mod, apply),
+                        tokens and tuple(getattr(mod, c) for c in tokens))
+    if name in _CONFIG_MODELS:
+        if config is None:
+            raise ValueError(
+                f"model {name!r} is built from a configuration file: pass "
+                "--model_config <file> (get_model(name, config))")
+        mod = importlib.import_module(f"{__name__}.{_CONFIG_MODELS[name]}")
+        return ModelDef(name, *mod.build(config))
+    raise ValueError(f"unknown model {name!r}; available: "
+                     + ", ".join(MODEL_NAMES))

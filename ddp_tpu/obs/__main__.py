@@ -21,6 +21,12 @@ renders the human autopsy — reason, exit status, error, the health
 snapshot at death, the resilience-event timeline, and the last completed
 spans.  A missing or torn bundle exits 2 with a one-line diagnosis.
 
+``--prom RUN.prom`` is a third mode (no spill needed either): it parses
+a run's end-of-run registry exposition (``<metrics>.prom``) and prints an
+expert model's routing counters a layer — assignments to each expert held
+here, the busiest over the mean, and the assignments dropped
+(obs/routing.py).
+
 Multi-host runs spill one file per host (``--trace_spill`` path plus
 ``.hostN`` suffixes); pass them all — the terminal report prints one
 section per host (hosts' clocks are independent and each host's serial
@@ -36,6 +42,7 @@ Usage:
         [--perfetto trace.json] [--top 10] [--bins 12]
         [--requests] [--ledger CALIB.json [--ledger_scale N]]
     python -m ddp_tpu.obs --postmortem postmortem.json [--json]
+    python -m ddp_tpu.obs --prom metrics.jsonl.prom
 """
 from __future__ import annotations
 
@@ -81,6 +88,10 @@ def main(argv: Optional[list] = None) -> int:
                         "(obs/blackbox.py) instead of a spill report; "
                         "missing/torn bundles exit 2 with a one-line "
                         "diagnosis")
+    p.add_argument("--prom", default=None, metavar="RUN.prom",
+                   help="Print the routing counters of an expert model "
+                        "(ddp_moe_*: obs/routing.py) from a run's "
+                        "registry exposition instead of a spill report")
     p.add_argument("--perfetto", default=None, metavar="OUT.json",
                    help="Also export a schema-validated Chrome/Perfetto "
                         "trace_event JSON (open in ui.perfetto.dev)")
@@ -129,8 +140,20 @@ def main(argv: Optional[list] = None) -> int:
             return 2
         print(json.dumps(doc) if args.as_json else format_postmortem(doc))
         return 0
+    if args.prom is not None:
+        from .registry import parse_exposition
+        from .routing import format_routing
+        try:
+            with open(args.prom) as f:
+                families = parse_exposition(f.read())
+        except (OSError, ValueError) as e:
+            print(f"cannot read exposition {args.prom}: {e}",
+                  file=sys.stderr)
+            return 2
+        print(format_routing(families))
+        return 0
     if not args.spill:
-        p.error("a spill file is required (or use --postmortem)")
+        p.error("a spill file is required (or use --postmortem / --prom)")
     try:
         spans = read_spill(args.spill)
     except OSError as e:

@@ -1,0 +1,109 @@
+"""Routing counters of an expert model, host side.
+
+The device side counts inside the step: per expert layer the model's
+state pytree carries ``assignments`` (to each expert held here) and
+``dropped`` (assignments that found no room), integer and cumulative
+(models/nemotron_h.py; train/step.py sums integer state over replicas).
+The Trainer hands a copy of those leaves to :meth:`RoutingCounters.update`
+where it flushes an epoch's losses, so no step pays a device read.
+
+Exported through the run's registry (obs/registry.py):
+
+    ddp_moe_assignments_total{layer,expert}   counter
+    ddp_moe_dropped_total{layer}              counter
+    ddp_moe_load_max_over_mean{layer}         gauge: the busiest held
+        expert's assignments over the mean, since the run began
+
+``expert`` is the index among the experts held here (the router's id is
+``experts_held[0]`` more).  ``python -m ddp_tpu.obs --prom FILE`` prints
+them from a run's ``.prom`` file.
+"""
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import numpy as np
+
+FAMILIES = ("ddp_moe_assignments_total", "ddp_moe_dropped_total",
+            "ddp_moe_load_max_over_mean")
+
+
+def _u32(x) -> np.ndarray:
+    return np.asarray(x).astype(np.uint32)
+
+
+class RoutingCounters:
+    """Cumulative device counts in, increments out.  The device's
+    counters are 32 bits wide and wrap; the difference of two readings
+    modulo 2**32 is right as long as one epoch adds less than that."""
+
+    def __init__(self, registry=None, baseline: Optional[dict] = None):
+        self.totals: Dict[str, dict] = {}
+        self._last = baseline or {}
+        self._assign = self._dropped = self._load = None
+        if registry is not None:
+            self._assign = registry.counter(
+                FAMILIES[0], "Assignments routed to an expert held here",
+                ("layer", "expert"))
+            self._dropped = registry.counter(
+                FAMILIES[1], "Assignments to a held expert that found no "
+                "room (must read 0)", ("layer",))
+            self._load = registry.gauge(
+                FAMILIES[2], "Busiest held expert's assignments over the "
+                "mean, since the run began", ("layer",))
+
+    def update(self, counters: dict) -> None:
+        """``counters``: layer -> {"assignments": int[E], "dropped": int},
+        as read from the device."""
+        for layer, now in counters.items():
+            last = self._last.get(layer, {})
+            d_assign = (_u32(now["assignments"])
+                        - _u32(last.get("assignments", 0))).astype(np.int64)
+            d_drop = int((_u32(now["dropped"])
+                          - _u32(last.get("dropped", 0))).astype(np.int64))
+            self._last[layer] = now
+            tot = self.totals.setdefault(
+                layer, {"assignments": np.zeros_like(d_assign),
+                        "dropped": 0})
+            tot["assignments"] = tot["assignments"] + d_assign
+            tot["dropped"] += d_drop
+            if self._assign is not None:
+                for j, n in enumerate(d_assign):
+                    self._assign.labels(layer=layer, expert=str(j)).inc(
+                        float(n))
+                self._dropped.labels(layer=layer).inc(float(d_drop))
+                self._load.labels(layer=layer).set(
+                    load_max_over_mean(tot["assignments"]))
+
+
+def load_max_over_mean(assignments) -> float:
+    a = np.asarray(assignments, np.float64)
+    return float(a.max() / a.mean()) if a.size and a.sum() > 0 else 0.0
+
+
+def format_routing(families: dict) -> str:
+    """The routing counters of a parsed exposition
+    (``registry.parse_exposition``) as a table, a layer a line."""
+    def samples(name):
+        return {dict(labels).get("layer"): {} for (_n, labels) in
+                families.get(name, {}).get("samples", {})}
+
+    layers = sorted(samples(FAMILIES[0]))
+    if not layers:
+        return "no routing counters (ddp_moe_*) in this exposition"
+    lines = [f"{'layer':<10} {'assigned':>10} {'dropped':>8} "
+             f"{'max/mean':>8}  assignments by expert held"]
+    for layer in layers:
+        by_expert = sorted(
+            (int(dict(labels)["expert"]), int(v)) for (_n, labels), v in
+            families[FAMILIES[0]]["samples"].items()
+            if dict(labels)["layer"] == layer)
+        counts = [v for _j, v in by_expert]
+        dropped = sum(int(v) for (_n, labels), v in
+                      families.get(FAMILIES[1], {}).get("samples",
+                                                        {}).items()
+                      if dict(labels)["layer"] == layer)
+        lines.append(f"{layer:<10} {sum(counts):>10} {dropped:>8} "
+                     f"{load_max_over_mean(counts):>8.2f}  "
+                     + " ".join(map(str, counts)))
+    return "\n".join(lines)

@@ -35,6 +35,7 @@ normalisation is unaffected (it uses batch stats).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -53,7 +54,8 @@ def _as_input(x: jax.Array, compute_dtype=None) -> jax.Array:
     """Accept uint8 batches and apply ToTensor scaling (u8/255,
     singlegpu.py:158) on DEVICE: the loaders ship uint8 so each batch
     crosses the host->device link at 1/4 the bytes of fp32 — the transfer,
-    not the chips, is the bottleneck on thin links."""
+    not the chips, is the bottleneck on thin links.  Anything else goes
+    through as it is: float images, and a token model's integer ids."""
     if x.dtype == jnp.uint8:
         return x.astype(compute_dtype or jnp.float32) / 255.0
     return x
@@ -96,7 +98,12 @@ def make_loss_and_grads(model, compute_dtype=None, sync_bn: bool = False):
                     params, batch_stats,
                     _as_input(images, compute_dtype), train=True,
                     rng=rng, compute_dtype=compute_dtype)
-            ce_sum, count = cross_entropy_sum_count(logits, labels)
+            # A token model's loss (logits [B,T,V] against per-position
+            # labels, ignored positions left out of sum and count) is
+            # named for the device trace; a classifier's keeps its names.
+            with (jax.named_scope("lm_head") if logits.ndim == 3
+                  else contextlib.nullcontext()):
+                ce_sum, count = cross_entropy_sum_count(logits, labels)
             # Global mean: psum(sum)/psum(count).  Equal per-shard counts
             # (DistributedSampler padding guarantee, multigpu.py:153) make
             # this identical to DDP's mean-of-rank-means.
@@ -113,11 +120,20 @@ def make_loss_and_grads(model, compute_dtype=None, sync_bn: bool = False):
         # loss IS DDP's bucketed all-reduce(mean) (multigpu.py:96); an
         # explicit pmean there would double-count by the mesh size
         # (tests/test_train_step.py pins this numerically).
-        new_stats = jax.tree_util.tree_map(
-            lambda s: lax.pmean(s, DATA_AXIS), new_stats)
+        new_stats = jax.tree_util.tree_map(_reduce_state_leaf, new_stats,
+                                           batch_stats)
         return loss, new_stats, grads
 
     return loss_and_grads
+
+
+def _reduce_state_leaf(new: jax.Array, old: jax.Array) -> jax.Array:
+    """A model-state leaf over the replicas: running statistics (float)
+    are averaged; counters (integer: a token model's routing counts) grow
+    by the SUM of what the replicas added this step."""
+    if jnp.issubdtype(new.dtype, jnp.integer):
+        return old + lax.psum(new - old, DATA_AXIS)
+    return lax.pmean(new, DATA_AXIS)
 
 
 def make_loss_and_grads_tp(model, data_size: int, compute_dtype=None,
@@ -255,8 +271,9 @@ def make_group_update(sgd_config: sgd_lib.SGDConfig,
 
     def update(state: TrainState, grads, new_stats) -> TrainState:
         lr_t = lr_schedule(state.step)
-        params, opt_state = sgd_lib.apply_updates(
-            state.params, grads, state.opt_state, lr_t, sgd_config)
+        with jax.named_scope("update"):
+            params, opt_state = sgd_lib.apply_updates(
+                state.params, grads, state.opt_state, lr_t, sgd_config)
         return TrainState(params, new_stats, opt_state, state.step + 1)
 
     return update

@@ -72,6 +72,31 @@ def _stack_groups(batches, accum: int):
         yield flush()
 
 
+def _samples(label, stacked: bool) -> int:
+    """Samples in a device batch, from its labels: rows (``[B]`` or, a
+    token model's, ``[B,T]``), times the depth of a stack of micro-batches
+    (``[A,B,...]``)."""
+    return label.shape[0] * label.shape[1] if stacked else label.shape[0]
+
+
+def _counters(model_state):
+    """The part of a model's state that counts: its integer leaves (a
+    token model's routing counters, ``{layer: {name: leaf}}``) with the
+    dicts that hold them; empty for a classifier's running statistics."""
+    if not isinstance(model_state, dict):
+        return {}
+    out = {}
+    for key, sub in model_state.items():
+        if isinstance(sub, dict):
+            sub = _counters(sub)
+            if sub:
+                out[key] = sub
+        elif jnp.issubdtype(getattr(sub, "dtype", jnp.float32),
+                            jnp.integer):
+            out[key] = sub
+    return out
+
+
 class Trainer:
     def __init__(self, model, train_loader, params, batch_stats, *,
                  mesh, lr_schedule: Callable,
@@ -204,6 +229,24 @@ class Trainer:
                 raise ValueError(
                     "pipeline parallelism (stage axis s>1) is incompatible "
                     "with:\n" + "\n".join(f"  - {f}" for f in incompatible))
+        if getattr(model, "tokens", None):
+            not_wired = [why for why, on in (
+                ("--resident: the table in HBM and its row gather hold "
+                 "image rows (u8[N,24,128]); token rows stream through "
+                 "the host loader", resident),
+                ("--shard_update: the ZeRO update has its own loss core "
+                 "(train/zero.py), which does not sum a token model's "
+                 "routing counters", shard_update),
+                ("a tensor- or pipeline-parallel plan: their loss cores "
+                 "(train/step.py's _tp, parallel/pp/) take one label a "
+                 "sample",
+                 tp_plan is not None or pp_plan is not None),
+            ) if on]
+            if not_wired:
+                raise ValueError(
+                    f"model {model.name!r} (token ids in, a loss a "
+                    "position) is not wired for:\n"
+                    + "\n".join(f"  - {w}" for w in not_wired))
         self.start_epoch = 0
         self.state = init_train_state(params, batch_stats)
         if resume and snapshot_path:
@@ -260,6 +303,14 @@ class Trainer:
                     print(f"Mid-epoch resume: fast-forwarding epoch "
                           f"{self.start_epoch} to batch offset "
                           f"{self._resume_offset}")
+        # A token model's routing counters (integer leaves of its state:
+        # obs/routing.py): read here once as the baseline, then a copy an
+        # epoch where its losses are flushed.
+        from ..obs.routing import RoutingCounters
+        self._pending_counters: dict = {}
+        self.routing = RoutingCounters(
+            registry, baseline=jax.device_get(_counters(
+                self.state.batch_stats)))
         # Host-side mirror of state.step: reading the device scalar would
         # block on the in-flight epoch (the exact stall the deferred loss
         # read removes), and the step count per epoch is host-known.
@@ -507,6 +558,7 @@ class Trainer:
         step = self._host_step
         k = start  # epoch-local batch offset (the data_state coordinate)
         traced = self.tracer.enabled
+        stacked = self.grad_accum > 1 or self.pp_plan is not None
         t_prev = time.monotonic()
         for device_batch in batches:
             # Step-boundary preemption (resilience/preemption.py): checked
@@ -538,7 +590,8 @@ class Trainer:
             # "where did step N go" record.
             with self.tracer.span(
                     "dispatch", step=step,
-                    n=device_batch["label"].size if traced else None):
+                    n=_samples(device_batch["label"], stacked) if traced
+                    else None):
                 self.state, loss = self.train_step(
                     self.state, device_batch, self.rng)
             epoch_losses.append(loss)
@@ -646,6 +699,12 @@ class Trainer:
         # the next epoch's host prep then overlap device compute.  This
         # epoch's array is read at the next epoch's dispatch (or by
         # train()'s final flush).
+        # The routing counters as this epoch's last step left them ride
+        # with its losses: copies, because the next step donates the state.
+        # (Kept beside the losses, by the epoch's first step: the flush's
+        # signature is a seam that fault drills wrap.)
+        self._pending_counters[start_step] = jax.tree_util.tree_map(
+            jnp.copy, _counters(self.state.batch_stats))
         prev, self._pending_losses = (self._pending_losses,
                                       (epoch, start_step, stacked))
         if prev is not None:
@@ -661,8 +720,12 @@ class Trainer:
                             stacked) -> None:
         # One stacked D2H transfer for the whole epoch's losses — per-scalar
         # reads pay a link round trip each on remote-device setups.
-        arr = (np.asarray(jax.device_get(stacked))
-               if stacked is not None else np.zeros(0, np.float32))
+        arr, counters = jax.device_get(
+            (stacked, self._pending_counters.pop(start_step, None)))
+        arr = (np.asarray(arr) if stacked is not None
+               else np.zeros(0, np.float32))
+        if counters:
+            self.routing.update(counters)
         losses = arr.tolist()
         if self._watchdog is not None:
             self._watchdog.beat()
@@ -906,6 +969,7 @@ class Trainer:
         from ..resilience.lineage import latest_verifiable
         self._join_pending_save()  # let any in-flight (good) write land
         self._pending_losses = None  # the poisoned trajectory's records
+        self._pending_counters.clear()
         self._preempt_pending = None
         loaded = (latest_verifiable(self.snapshot_path,
                                     loader=self._ckpt_loader(),
