@@ -6,6 +6,8 @@ full width of the one full-width model the repo supports (VGG, batch 512
 per chip; weights random from a seed, depth of the run cut to 8 steps):
 
     gather     python -m ddp_tpu.ops.gather       Pallas row gather == table[idx]
+    attention  python -m ddp_tpu.ops.attention    the token model's attention kernel
+                                                  against the XLA loop and float32
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
     train_again  the same command once more       adds no compile-cache entries
     serve      python -m ddp_tpu.serve            /predict x3, SIGTERM drain, exit 0
@@ -366,6 +368,22 @@ class Smoke:
             fail(f"no 'gather: ok kernel={kernel}' line", cmd, out)
         return m.group(0)
 
+    def attention(self) -> str:
+        """The kernel at the token cell's shape: the child raises where it
+        is further from float32 than the XLA loop or the mixer does not
+        take it; its table (distances, milliseconds, block sweep, paths
+        traced) is shown."""
+        cmd = [PY, "-m", "ddp_tpu.ops.attention"]
+        out = self.run(cmd)
+        self.check_device(cmd, out)
+        for line in re.findall(r"^attention: .*$", out, re.M)[:-1]:
+            print(f"[smoke]   {line}", flush=True)
+        kernel = "pallas" if self.platform == "tpu" else "interpret"
+        m = re.search(rf"^attention: ok kernel={kernel} .*$", out, re.M)
+        if not m:
+            fail(f"no 'attention: ok kernel={kernel}' line", cmd, out)
+        return m.group(0)
+
     def lm(self) -> str:
         cmd = [PY, "-m", "ddp_tpu.train.lm", "--steps", "6",
                "--snapshot_path", self.path("lm/ckpt.npz")]
@@ -381,8 +399,8 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "train", "train_again", "serve", "bf16", "resident",
-          "shard_update", "resume", "lm", "generate")
+PHASES = ("gather", "attention", "train", "train_again", "serve", "bf16",
+          "resident", "shard_update", "resume", "lm", "generate")
 
 
 def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
@@ -396,6 +414,7 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
     cache_before = s.cache_entries()
     table = {
         "gather": s.gather,
+        "attention": s.attention,
         "train": lambda: s.train_dp("train"),
         "train_again": s.train_again,
         "serve": s.serve_predict,

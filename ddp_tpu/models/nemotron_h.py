@@ -13,8 +13,9 @@ character of ``hybrid_override_pattern``:
 
 - ``M``, a Mamba-2 mixer in the chunked form: products inside chunks of
   ``chunk_size`` tokens, a ``lax.scan`` over the chunks' states.
-- ``*``, grouped-query causal attention without positional encoding,
-  computed a block of queries at a time against the keys before it.
+- ``*``, grouped-query causal attention without positional encoding:
+  the blocked kernel of ops/attention.py where it applies (a TPU, whole
+  blocks), else a block of queries at a time against the keys before it.
 - ``E``, a sigmoid router over ALL ``router_experts`` experts, the
   ``num_experts_per_tok`` largest ``s + b`` chosen; this chip computes
   the shared expert and the weighted results of the chosen experts it
@@ -43,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import attention
 from ..ops.layers import linear
 
 F32 = jnp.float32
@@ -258,14 +260,21 @@ def attention_mixer(p, x, dm: dict, cd):
         v = linear(x, p["v"].astype(cd)).reshape(
             bsz, t, hkv, hd).transpose(0, 2, 1, 3)
     with jax.named_scope("attn_core"):
-        # One (sequence, key-value head) pair at a time, as a loop: a
-        # batched product over a batch of 2 x 2 is what XLA:TPU lowers to
-        # a dilated convolution (1.4% of the roofline on the chip: PERF.md,
+        scale = 1.0 / math.sqrt(hd)
+        qkv = (q.reshape(bsz * hkv, hq // hkv, t, hd),
+               k.reshape(bsz * hkv, t, hd), v.reshape(bsz * hkv, t, hd))
+        # The blocked kernel where the shapes and the backend allow it
+        # (ops/attention.py: a tile's scores stay in VMEM); else the XLA
+        # loop, one (sequence, key-value head) pair at a time: a batched
+        # product over a batch of 2 x 2 is what XLA:TPU lowers to a
+        # dilated convolution (1.4% of the roofline on the chip: PERF.md,
         # findings of PR 28).
-        o = lax.map(
-            lambda qkv: _attend_head(*qkv, scale=1.0 / math.sqrt(hd), cd=cd),
-            (q.reshape(bsz * hkv, hq // hkv, t, hd),
-             k.reshape(bsz * hkv, t, hd), v.reshape(bsz * hkv, t, hd)))
+        if attention.kernel_applies(t, hd, jnp.dtype(cd).itemsize):
+            attention.TRACED["kernel"] += 1
+            o = attention.causal_gqa(*qkv, scale)
+        else:
+            attention.TRACED["xla"] += 1
+            o = lax.map(lambda a: _attend_head(*a, scale=scale, cd=cd), qkv)
         o = o.reshape(bsz, hkv, hq // hkv, t, hd).transpose(
             0, 3, 1, 2, 4).reshape(bsz, t, hq * hd)
     with jax.named_scope("attn_proj"):

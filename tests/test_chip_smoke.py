@@ -12,16 +12,21 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
-    """The real commands as children — gather check, train + checkpoint +
-    eval, the same train again adding nothing to the (suite's) compile
-    cache, serve + /predict + SIGTERM drain, resume at epoch 1 — with
-    every check of the smoke applied.  The expected platform is this test's
-    argument; ``python chip_smoke.py`` itself always expects tpu."""
+    """The real commands as children — gather check, the attention kernel's
+    check (off the chip: through the interpreter), train + checkpoint +
+    eval, the same train again adding nothing to the compile cache,
+    serve + /predict + SIGTERM drain, resume at epoch 1 — with every check
+    of the smoke applied.  The expected platform is this test's argument;
+    ``python chip_smoke.py`` itself always expects tpu."""
     monkeypatch.setenv("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=1")
+    # A cache of this run's own: the suite's is written by every other
+    # worker at once, and "the second child adds no entries" counts files.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     result = chip_smoke.run_smoke(
         "cpu", model="deepnn", batch=8, out=str(tmp_path / "out"),
-        phases=("gather", "train", "train_again", "serve", "resume"),
+        phases=("gather", "attention", "train", "train_again", "serve",
+                "resume"),
         loss_band=(2.0, 2.7))
     assert result == {"ok": True, "device": {"platform": "cpu",
                                              "kind": "cpu", "count": 1}}

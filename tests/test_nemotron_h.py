@@ -25,6 +25,7 @@ from benchmark import flops_seq  # noqa: E402
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from ddp_tpu.models import MODEL_NAMES, get_model  # noqa: E402
 from ddp_tpu.models import nemotron_h as sysm  # noqa: E402
+from ddp_tpu.ops import attention  # noqa: E402
 
 CONFIG_FILE = os.path.join(ROOT, "benchmark", "configs",
                            "nemotron3_nano_30b_a3b_ep16.json")
@@ -95,22 +96,36 @@ def reference_loss_and_grads(config, params, state, ids, targets):
 # -- (a) the system against the reference ----------------------------------------
 
 @pytest.mark.parametrize("cd", [None, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("pattern", ["M", "*", "E", "MEMEM*EME"])
-def test_matches_reference(pattern, cd, monkeypatch):
+@pytest.mark.parametrize("pattern,path", [
+    ("M", "xla"), ("*", "xla"), ("E", "xla"), ("MEMEM*EME", "xla"),
+    ("*", "kernel"), ("MEMEM*EME", "kernel")])
+def test_matches_reference(pattern, path, cd, monkeypatch):
     # Several query blocks, the last one ragged.
     monkeypatch.setattr(sysm, "ATTN_QUERY_BLOCK", 32)
     monkeypatch.setattr(ref, "QUERY_BLOCK", 48)
     # Several row tiles an expert, the last one of each part padding.
     monkeypatch.setattr(sysm, "MOE_ROW_TILE", 16)
-    config = tiny(pattern)
+    config, t = tiny(pattern), T
+    monkeypatch.setattr(attention, "TRACED", {"kernel": 0, "xla": 0})
+    if path == "kernel":
+        # The chip's path, through the interpreter: heads of whole lanes,
+        # two query blocks of two key tiles.
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+        monkeypatch.setattr(attention, "FWD_BLOCKS", (128, 128))
+        monkeypatch.setattr(attention, "BWD_BLOCKS", (128, 128))
+        monkeypatch.setattr(attention, "causal_gqa", functools.partial(
+            attention.causal_gqa, interpret=True))
+        config, t = tiny(pattern, head_dim=128), 256
     params, state = seeded(config)
-    ids, targets = batch()
+    ids, targets = batch(t=t)
     loss, grads, logits = system_loss_and_grads(config, params, state, ids,
                                                 targets, cd)
+    taken = {k for k, n in attention.TRACED.items() if n}
+    assert taken == ({path} if "*" in pattern else set())
     r_loss, r_grads, r_logits = reference_loss_and_grads(
         config, params, state, ids, targets)
     tol = 2e-4 if cd is None else 4e-2
-    assert logits.dtype == jnp.float32 and logits.shape == (2, T, 256)
+    assert logits.dtype == jnp.float32 and logits.shape == (2, t, 256)
     assert rel(logits, r_logits) < tol
     assert abs(float(loss) - float(r_loss)) < tol
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
@@ -376,12 +391,17 @@ def _trainer(config, tracer=None, registry=None, **kw):
                    registry=registry, **kw)
 
 
-def test_three_epochs_through_the_trainer():
+def test_three_epochs_through_the_trainer(monkeypatch):
     from ddp_tpu.obs.registry import MetricsRegistry, parse_exposition
     from ddp_tpu.obs.tracer import SpanTracer
     tracer, registry = SpanTracer(ring=1 << 16), MetricsRegistry()
+    monkeypatch.setattr(attention, "TRACED", {"kernel": 0, "xla": 0})
     trainer = _trainer(tiny(), tracer=tracer, registry=registry)
     trainer.train(3)
+    # Heads of 16 on the CPU: the XLA loop, chosen once when the step is
+    # traced (forward, and again under each checkpoint).
+    print("attention paths traced:", attention.TRACED)
+    assert attention.TRACED["kernel"] == 0 < attention.TRACED["xla"]
     losses = np.asarray(trainer.loss_history)
     assert losses.shape == (24,) and np.isfinite(losses).all()
     assert losses[-8:].mean() < losses[:8].mean()

@@ -217,3 +217,37 @@ def test_scanned_gather_compiles_for_v5e_without_copying_the_table(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == rows * 3072 + 8 * batch * 4
     assert mem.temp_size_in_bytes < rows * 3072 // 100
+
+
+def test_attention_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, monkeypatch):
+    """The token cell's attention (ops/attention.py; here because the
+    described chip's fixture lives in this file only): 4 pairs x 16 heads
+    x 8,192 x 128 in bf16 at the module's block constants, forward and
+    backward, through Mosaic: both kernels are in the program, within the
+    VMEM limit the module sets, and nothing the size of a head's scores
+    is left for HBM."""
+    import math
+
+    from ddp_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    p, r, t, hd = 4, 16, 8192, 128
+    assert attention.kernel_applies(t, hd, 2)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def out_and_grads(q, k, v, do):
+        o, pull = jax.vjp(lambda q, k, v: attention.causal_gqa(
+            q, k, v, 1.0 / math.sqrt(hd)), q, k, v)
+        return (o,) + pull(do)
+
+    compiled = jax.jit(out_and_grads).lower(
+        spec(p, r, t, hd), spec(p, t, hd), spec(p, t, hd),
+        spec(p, r, t, hd)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "causal_gqa_fwd" in text and "causal_gqa_bwd" in text
+    # One head's float32 scores are 268 MB; the program's temporaries are
+    # the log-sum-exp, ``di`` and padding: a few MB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
