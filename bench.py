@@ -583,10 +583,10 @@ def _bench_step(args, *, bf16: bool, extras: bool = True) -> list:
                             tp_plan=plan)
     elif args.shard_update:
         from ddp_tpu.train.step import TrainState
-        from ddp_tpu.train.zero import init_opt_shard, make_train_step_zero
-        step_fn = make_train_step_zero(model, SGDConfig(), schedule, mesh,
-                                       compute_dtype=compute_dtype,
-                                       plan=plan)
+        from ddp_tpu.train.zero import init_opt_shard
+        step_fn = make_train_step(model, SGDConfig(), schedule, mesh,
+                                  compute_dtype=compute_dtype, plan=plan,
+                                  shard_update=True)
         state = TrainState(params, stats,
                            init_opt_shard(params, mesh, plan=plan),
                            jnp.zeros((), jnp.int32))
@@ -1706,7 +1706,7 @@ def _search_plan_child(model_name: str, total: int, calib_path: str,
     one-device CPU child (meshes are abstract and pricing needs no
     device), leaving the chips to the measuring children.  The doc is a
     function of its inputs alone: the candidates are the plain
-    ``make_train_step[_zero]`` programs, which hold no ``lax.scan``, so
+    ``make_train_step`` programs, which hold no ``lax.scan``, so
     ``scan_unroll``'s platform branch is not in what gets priced
     (tests/test_autoplan.py pins the child's doc byte-equal to the
     in-process ``search_plan``)."""

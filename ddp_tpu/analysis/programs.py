@@ -135,15 +135,9 @@ def _build_step(ctx: _Ctx, name: str, *, accum: bool, zero: bool,
     mesh = ctx.mesh2d if tp else ctx.mesh1d
     plan = ctx.plan if tp else None
     cfg, sched = _sgd()
-    if zero:
-        from ..train.zero import (make_train_step_zero,
-                                  make_train_step_zero_accum)
-        builder = make_train_step_zero_accum if accum else \
-            make_train_step_zero
-    else:
-        from ..train.step import make_train_step, make_train_step_accum
-        builder = make_train_step_accum if accum else make_train_step
-    fn = builder(ctx.model, cfg, sched, mesh, plan=plan)
+    from ..train.step import make_train_step
+    fn = make_train_step(ctx.model, cfg, sched, mesh, plan=plan,
+                         accum=accum, shard_update=zero)
     state = _train_state(ctx, mesh, zero=zero, plan=plan)
     return BuiltProgram(name, "update", zero, fn,
                         (state, _batch(stacked=accum), _rng()), plan)
@@ -225,13 +219,9 @@ def _build_auto(ctx: _Ctx, name: str) -> BuiltProgram:
     plan = plan_from_doc(doc, ctx.params, ctx.stats)
     zero = bool(doc.get("zero"))
     cfg, sched = _sgd()
-    if zero:
-        from ..train.zero import make_train_step_zero
-        fn = make_train_step_zero(ctx.model, cfg, sched, ctx.mesh2d,
-                                  plan=plan)
-    else:
-        from ..train.step import make_train_step
-        fn = make_train_step(ctx.model, cfg, sched, ctx.mesh2d, plan=plan)
+    from ..train.step import make_train_step
+    fn = make_train_step(ctx.model, cfg, sched, ctx.mesh2d, plan=plan,
+                         shard_update=zero)
     state = _train_state(ctx, ctx.mesh2d, zero=zero, plan=plan)
     return BuiltProgram(name, "update", zero, fn,
                         (state, _batch(), _rng()), plan)

@@ -124,12 +124,9 @@ def trace_candidate(model_name: str, mesh_shape: Tuple[int, int], *,
     cfg = SGDConfig(lr=0.1)
     sched = functools.partial(triangular_lr, base_lr=0.1, num_epochs=2,
                               steps_per_epoch=4)
-    if zero:
-        from ..train.zero import make_train_step_zero
-        fn = make_train_step_zero(model, cfg, sched, mesh, plan=plan)
-    else:
-        from ..train.step import make_train_step
-        fn = make_train_step(model, cfg, sched, mesh, plan=plan)
+    from ..train.step import make_train_step
+    fn = make_train_step(model, cfg, sched, mesh, plan=plan,
+                         shard_update=zero)
     state = _abstract_state(params, stats, (d, m), zero=zero, plan=plan)
     batch = {"image": jax.ShapeDtypeStruct((global_batch,) + tuple(input_hw),
                                            jnp.uint8),

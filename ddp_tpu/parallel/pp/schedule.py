@@ -213,9 +213,9 @@ def eval_params_for(state, pp_plan: StagePlan, tp_plan, eval_mesh: Mesh):
 class _PPStep:
     """The pipeline train step: ``step_fn(state, batch, rng) -> (state,
     loss)``, signature-compatible with
-    :func:`~ddp_tpu.train.step.make_train_step_accum` — ``batch`` is the
-    stacked ``{"image": [A,B,...], "label": [A,B]}`` group placed by
-    :func:`pp_shard_fn`.  Per-stage programs compile lazily on first use
+    :func:`~ddp_tpu.train.step.make_train_step`'s ``accum=True`` step —
+    ``batch`` is the stacked ``{"image": [A,B,...], "label": [A,B]}``
+    group placed by :func:`pp_shard_fn`.  Per-stage programs compile lazily on first use
     and re-trace per distinct A, exactly like the accum step."""
 
     def __init__(self, model_name: str, sgd_config, lr_schedule, mesh,

@@ -37,15 +37,16 @@ from ..optim import sgd as sgd_lib
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, replicated_sharding,
                              scan_unroll)
 from .step import (TrainState, make_accum_scan, make_eval_apply,
-                   make_group_step, make_group_update, make_single_micro,
-                   make_step_wiring, micro_from_table)
+                   make_group_step, make_single_micro, make_step_wiring,
+                   micro_from_table)
 
 
 def make_train_epoch(model, sgd_config: sgd_lib.SGDConfig,
                      lr_schedule: Callable[[jax.Array], jax.Array],
-                     mesh: Mesh, compute_dtype=None,
+                     mesh: Mesh, *, compute_dtype=None,
                      device_augment: bool = False, sync_bn: bool = False,
-                     plan=None):
+                     plan=None, accum: bool = False,
+                     shard_update: bool = False):
     """Build the jitted scan-per-epoch train function over ``mesh``.
 
     Returns ``epoch_fn(state, images, labels, idx, rng) -> (state, losses)``
@@ -53,7 +54,7 @@ def make_train_epoch(model, sgd_config: sgd_lib.SGDConfig,
     data/resident.py: ``images`` a :class:`~ddp_tpu.ops.gather.RowTable`,
     never reshaped or copied in here), ``idx`` is an int32
     ``[steps, global_batch]`` matrix of sample indices sharded on its
-    batch (second) axis, and ``losses`` is
+    batch (last) axis, and ``losses`` is
     the per-step global-mean loss vector ``[steps]`` — the loss stream the
     reference never logs (SURVEY.md §5).
 
@@ -62,79 +63,55 @@ def make_train_epoch(model, sgd_config: sgd_lib.SGDConfig,
     ``plan`` (tp) runs the tensor-parallel per-step body inside the same
     scan — the resident dataset stays replicated, ``idx`` stays sharded on
     ``data`` only.
-    """
-    loss_and_grads, st_specs, st_sh, extra = make_step_wiring(
-        model, mesh, compute_dtype, sync_bn, plan)
-    update = make_group_update(sgd_config, lr_schedule)
 
-    def _shard_body(state: TrainState, images, labels, idx, rng):
-        group = make_group_step(
-            make_single_micro(loss_and_grads,
-                              micro_from_table(images, labels,
-                                               device_augment)),
-            update)
-        return lax.scan(lambda st, idx_row: group(st, idx_row, rng),
-                        state, idx, unroll=scan_unroll(mesh, idx.shape[0]))
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(st_specs, P(), P(), P(None, DATA_AXIS), P()),
-        out_specs=(st_specs, P()),
-        **extra,
-    )
-    rep = replicated_sharding(mesh)
-    return jax.jit(mapped, donate_argnums=(0,), out_shardings=(st_sh, rep))
-
-
-def make_train_epoch_accum(model, sgd_config: sgd_lib.SGDConfig,
-                           lr_schedule: Callable[[jax.Array], jax.Array],
-                           mesh: Mesh, compute_dtype=None,
-                           device_augment: bool = False,
-                           sync_bn: bool = False, plan=None):
-    """Scan-per-epoch training WITH gradient accumulation: ``--resident``
-    composed with ``--grad_accum``.
-
-    Returns ``epoch_fn(state, images, labels, idx, rng) -> (state, losses)``
-    where ``idx`` is int32 ``[G, A, global_batch]`` — G optimizer-step
-    groups of A micro-batches each, sharded on the last (batch) axis.  The
-    outer ``lax.scan`` runs one optimizer step per group; the inner scan
-    accumulates gradients over the group's micro-batches with BN stats
-    chained in micro-batch order, exactly the semantics of the streaming
-    accumulation step (:func:`~ddp_tpu.train.step.make_train_step_accum`,
-    torch's no_sync()+step-every-A) — and the identical RNG fold structure,
-    so the two execution strategies produce the same trajectory (pinned by
-    tests/test_resident.py).  ``losses[g]`` is the mean of group g's
-    micro-batch global-mean losses.
-
+    ``accum=True`` (``--resident`` with ``--grad_accum``): ``idx`` is
+    ``[G, A, global_batch]`` — G optimizer-step groups of A micro-batches.
+    The outer scan runs one optimizer step per group; the inner scan
+    (:func:`~ddp_tpu.train.step.make_accum_scan`) accumulates gradients
+    over the group's micro-batches with BN stats chained in micro-batch
+    order, and ``losses[g]`` is the mean of group g's micro-batch losses.
     Ragged groups (the epoch's remainder of full batches, and the final
     ragged batch — drop_last=False, singlegpu.py:179) arrive as separate
-    calls with their own ``[1, A', B']`` shapes; each distinct shape
-    compiles once.
+    calls with their own ``[1, A', B']`` shapes.  ``shard_update=True``
+    (``--shard_update``) swaps in ZeRO's update, one per step or group.
+
+    The keywords mean what they mean to
+    :func:`~ddp_tpu.train.step.make_train_step`, the wiring and the RNG
+    fold structure are the same, so the epoch program and the step
+    program produce the same trajectory in every combination (pinned by
+    tests/test_resident.py).
     """
-    core, st_specs, st_sh, extra = make_step_wiring(
-        model, mesh, compute_dtype, sync_bn, plan)
-    update = make_group_update(sgd_config, lr_schedule)
+    core, update, st_specs, st_sh, extra = make_step_wiring(
+        model, sgd_config, lr_schedule, mesh, compute_dtype=compute_dtype,
+        sync_bn=sync_bn, plan=plan, shard_update=shard_update)
 
     def _shard_body(state: TrainState, images, labels, idx, rng):
         get_micro = micro_from_table(images, labels, device_augment)
-        # Nested unrolls multiply: BOTH scans are gated on the PRODUCT G*A
-        # of inlined conv bodies, not their own lengths alone (ADVICE r5).
-        # Gating the inner scan on A only would, whenever A <= 32 < G*A,
-        # fully unroll A fwd+bwd bodies INSIDE a rolled while loop —
-        # exactly the pathological XLA:CPU conv-in-rolled-loop shape
-        # scan_unroll exists to avoid.  Product-gated, the two scans are
-        # always rolled/unrolled together.
-        total = idx.shape[0] * idx.shape[1]
-        accum = make_accum_scan(core,
-                                unroll_fn=lambda _a: scan_unroll(mesh, total))
-        group = make_group_step(
-            lambda p, s, xs, g: accum(p, s, xs, get_micro, g), update)
-        return lax.scan(lambda st, idx_group: group(st, idx_group, rng),
+        if accum:
+            # Nested unrolls multiply: BOTH scans are gated on the PRODUCT
+            # G*A of inlined conv bodies, not their own lengths alone
+            # (ADVICE r5).  Gating the inner scan on A only would,
+            # whenever A <= 32 < G*A, fully unroll A fwd+bwd bodies INSIDE
+            # a rolled while loop — exactly the pathological XLA:CPU
+            # conv-in-rolled-loop shape scan_unroll exists to avoid.
+            # Product-gated, the two scans are always rolled/unrolled
+            # together.
+            total = idx.shape[0] * idx.shape[1]
+            scan = make_accum_scan(
+                core, unroll_fn=lambda _a: scan_unroll(mesh, total))
+            group = make_group_step(
+                lambda p, s, xs, g: scan(p, s, xs, get_micro, g), update)
+        else:
+            total = idx.shape[0]
+            group = make_group_step(make_single_micro(core, get_micro),
+                                    update)
+        return lax.scan(lambda st, idx_row: group(st, idx_row, rng),
                         state, idx, unroll=scan_unroll(mesh, total))
 
+    idx_spec = P(None, None, DATA_AXIS) if accum else P(None, DATA_AXIS)
     mapped = jax.shard_map(
         _shard_body, mesh=mesh,
-        in_specs=(st_specs, P(), P(), P(None, None, DATA_AXIS), P()),
+        in_specs=(st_specs, P(), P(), idx_spec, P()),
         out_specs=(st_specs, P()),
         **extra,
     )

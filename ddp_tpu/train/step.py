@@ -46,8 +46,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..optim import sgd as sgd_lib
 from ..ops.losses import cross_entropy_sum_count
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, assemble_from_local,
-                             batch_sharding, data_axis_size, scan_unroll,
-                             replicated_sharding)
+                             batch_sharding, data_axis_size, mesh_size,
+                             replicated_sharding, scan_unroll)
 
 
 def _as_input(x: jax.Array, compute_dtype=None) -> jax.Array:
@@ -304,38 +304,90 @@ def make_group_step(group_grads, update):
     return group_step
 
 
-def make_step_wiring(model, mesh: Mesh, compute_dtype, sync_bn, plan):
-    """``(loss core, state specs, state shardings, extra shard_map
-    kwargs)`` for a step/epoch builder — the tp delta in one place,
-    shared by both step builders here and the epoch builders
-    (train/epoch.py).  The batch specs are UNCHANGED either way (split on
-    ``data``, replicated over ``model``); with a plan the state specs
-    follow its per-leaf PartitionSpecs and ``check_vma=False`` because
-    the TP program's collectives are all explicit with their own
-    transposes (the same regime train/zero.py documents).  A TRIVIAL plan
-    (no column/row layer — an auto plan that searched its way to pure data
-    parallelism, parallel/tp/autoplan.py) wires exactly the plain path:
-    the program it implies IS the 1-D one, and models without a
-    ``tp_axis`` forward must still run under it."""
+def make_step_wiring(model, sgd_config: sgd_lib.SGDConfig,
+                     lr_schedule: Callable[[jax.Array], jax.Array],
+                     mesh: Mesh, *, compute_dtype=None, sync_bn: bool = False,
+                     plan=None, shard_update: bool = False):
+    """``(loss core, update stage, state specs, state shardings, extra
+    shard_map kwargs)`` for a train builder — the tp delta and the ZeRO
+    delta in ONE place, shared by :func:`make_train_step` and the epoch
+    builder (train/epoch.py).  The batch specs are UNCHANGED in every
+    case (split on ``data``, replicated over ``model``).
+
+    With a plan the state specs follow its per-leaf PartitionSpecs and
+    ``check_vma=False`` because the TP program's collectives are all
+    explicit with their own transposes.  A TRIVIAL plan (no column/row
+    layer — an auto plan that searched its way to pure data parallelism,
+    parallel/tp/autoplan.py) wires exactly the plain path: the program it
+    implies IS the 1-D one, and models without a ``tp_axis`` forward must
+    still run under it.
+
+    ``shard_update`` (ZeRO-1, train/zero.py) swaps the update stage for
+    the sharded one and the momentum's spec for the flat buffer's
+    (``P(data)``; ``P(model, data)`` under a plan — here ANY plan, trivial
+    or not, because the state's constructors
+    (:func:`~ddp_tpu.train.zero.init_opt_shard`) lay the buffer out by
+    ``plan is None`` alone).  ``check_vma=False`` because the
+    varying-axes type system has no way (in this JAX version) to re-mark
+    an ``all_gather`` result as replicated; with the check off the
+    gradient psum is NOT auto-inserted, which is exactly what lets the
+    update reduce-*scatter* instead.
+    """
     from ..parallel.tp.plan import (is_trivial, recipe_override,
                                     state_shardings, state_specs)
+    rep = replicated_sharding(mesh)
+    # Two loss cores, chosen here and nowhere else.  make_loss_and_grads
+    # differentiates the psum'd global-mean loss and lets shard_map's
+    # transpose insert the gradient psum: the replicated 1-D program.
+    # zero._make_local_grads differentiates the collective-free LOCAL
+    # objective and leaves the reduction to its caller: ZeRO (a
+    # reduce-scatter in the update) and TP (an explicit psum over
+    # ``data``).  They lower to different programs, so merging them is a
+    # change to measure, not a refactor (ROADMAP C3).
+    if shard_update:
+        from .zero import _make_local_grads, _make_zero_update
+        tp = plan is not None
+        # Without a plan the axis-extent product, not mesh.devices.size:
+        # the auto-plan search prices this wiring on a deviceless
+        # AbstractMesh (parallel/mesh.py:abstract_mesh).
+        R = data_axis_size(mesh) if tp else mesh_size(mesh)
+        core = _make_local_grads(
+            model, R, compute_dtype, sync_bn,
+            tp_axis=MODEL_AXIS if tp else None,
+            tp_recipe=recipe_override(plan) if tp else None)
+        update = _make_zero_update(sgd_config, lr_schedule, R, tp=tp)
+        if tp:
+            return (core, update, state_specs(plan, zero=True),
+                    state_shardings(plan, mesh, zero=True),
+                    {"check_vma": False})
+        flat = sgd_lib.SGDState(P(DATA_AXIS))
+        return (core, update,
+                TrainState(params=P(), batch_stats=P(), opt_state=flat,
+                           step=P()),
+                TrainState(params=rep, batch_stats=rep,
+                           opt_state=sgd_lib.SGDState(
+                               NamedSharding(mesh, P(DATA_AXIS))),
+                           step=rep),
+                {"check_vma": False})
+    update = make_group_update(sgd_config, lr_schedule)
     if plan is None or is_trivial(plan):
         core = make_loss_and_grads(model, compute_dtype=compute_dtype,
                                    sync_bn=sync_bn)
-        return core, P(), replicated_sharding(mesh), {}
+        return core, update, P(), rep, {}
     core = make_loss_and_grads_tp(model, data_axis_size(mesh),
                                   compute_dtype=compute_dtype,
                                   sync_bn=sync_bn,
                                   tp_recipe=recipe_override(plan))
-    return (core, state_specs(plan), state_shardings(plan, mesh),
+    return (core, update, state_specs(plan), state_shardings(plan, mesh),
             {"check_vma": False})
 
 
 def make_train_step(model, sgd_config: sgd_lib.SGDConfig,
                     lr_schedule: Callable[[jax.Array], jax.Array],
-                    mesh: Mesh, compute_dtype=None,
+                    mesh: Mesh, *, compute_dtype=None,
                     device_augment: bool = False, sync_bn: bool = False,
-                    plan=None):
+                    plan=None, accum: bool = False,
+                    shard_update: bool = False):
     """Build the jitted SPMD train step for ``model`` over ``mesh``.
 
     Returns ``step_fn(state, batch, rng) -> (state, loss)`` where ``batch``
@@ -351,57 +403,45 @@ def make_train_step(model, sgd_config: sgd_lib.SGDConfig,
     specs over ``model``, batch still split over ``data`` only, gradients
     reduced over ``data`` only (:func:`make_loss_and_grads_tp`); the state
     must be ``device_put`` onto ``state_shardings(plan, mesh)``.
+
+    ``accum=True`` is gradient accumulation (``--grad_accum``: torch's
+    no_sync()+step-every-A, TPU-shaped): ``batch`` arrays are ``[A, B,
+    ...]`` — A micro-batches of global batch B, sharded on the batch
+    (second) axis (:func:`shard_batch_stacked`).  A ``lax.scan``
+    (:func:`make_accum_scan`) runs the same forward/backward per
+    micro-batch, averaging gradients, and ONE update at lr(step) follows;
+    ``loss`` is the mean of the micro-batch global-mean losses.  Distinct
+    A values (a ragged tail group) compile once each.
+
+    ``shard_update=True`` is the ZeRO-1 weight update (``--shard_update``,
+    train/zero.py): one reduce-scatter + SGD on the local 1/R slice + one
+    all-gather.  ``state.opt_state.momentum_buf`` must come from
+    :func:`~ddp_tpu.train.zero.init_opt_shard` /
+    :func:`~ddp_tpu.train.zero.pytree_to_opt_shard`; under a ``plan`` it
+    composes (params along ``model``, the update along ``data``) — pass
+    the plan to the momentum constructors too.
+
+    The three choices are independent and share every piece
+    (:func:`make_step_wiring`, :func:`make_group_step`), so the semantics
+    cannot drift between flag combinations.
     """
-    core, st_specs, st_sh, extra = make_step_wiring(
-        model, mesh, compute_dtype, sync_bn, plan)
-    _shard_body = make_group_step(
-        make_single_micro(core, _micro_from_batch(device_augment)),
-        make_group_update(sgd_config, lr_schedule))
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(st_specs,
-                  {"image": P(DATA_AXIS), "label": P(DATA_AXIS)}, P()),
-        out_specs=(st_specs, P()),
-        **extra,
-    )
-    return jax.jit(mapped, donate_argnums=(0,),
-                   out_shardings=(st_sh, replicated_sharding(mesh)))
-
-
-def make_train_step_accum(model, sgd_config: sgd_lib.SGDConfig,
-                          lr_schedule: Callable[[jax.Array], jax.Array],
-                          mesh: Mesh, compute_dtype=None,
-                          device_augment: bool = False,
-                          sync_bn: bool = False, plan=None):
-    """Gradient accumulation: one optimizer step over A stacked
-    micro-batches (torch's no_sync()+step-every-A, TPU-shaped).
-
-    ``step_fn(state, batch, rng) -> (state, loss)`` where ``batch`` arrays
-    are ``[A, B, ...]`` — A micro-batches of global batch B, sharded on the
-    batch (second) axis.  Inside the jitted program a ``lax.scan`` runs the
-    shared forward/backward (make_loss_and_grads) per micro-batch,
-    averaging gradients; BN running stats chain through the micro-batches
-    in order (each forward normalises with its own micro-batch statistics,
-    exactly like torch under accumulation); ONE SGD update at lr(step)
-    follows.  Distinct A values (a ragged tail group) compile once each.
-    ``loss`` is the mean of the micro-batch global-mean losses.
-    ``plan`` runs the tensor-parallel variant (see
-    :func:`make_train_step`); the accumulation scaffold is the shared one
-    either way, so the semantics cannot drift.
-    """
-    core, st_specs, st_sh, extra = make_step_wiring(
-        model, mesh, compute_dtype, sync_bn, plan)
-    accum = make_accum_scan(core, unroll_fn=lambda n: scan_unroll(mesh, n))
+    core, update, st_specs, st_sh, extra = make_step_wiring(
+        model, sgd_config, lr_schedule, mesh, compute_dtype=compute_dtype,
+        sync_bn=sync_bn, plan=plan, shard_update=shard_update)
     get_micro = _micro_from_batch(device_augment)
-    _shard_body = make_group_step(
-        lambda p, s, xs, rng: accum(p, s, xs, get_micro, rng),
-        make_group_update(sgd_config, lr_schedule))
+    if accum:
+        scan = make_accum_scan(core,
+                               unroll_fn=lambda n: scan_unroll(mesh, n))
+        _shard_body = make_group_step(
+            lambda p, s, xs, rng: scan(p, s, xs, get_micro, rng), update)
+    else:
+        _shard_body = make_group_step(make_single_micro(core, get_micro),
+                                      update)
+    batch_spec = P(None, DATA_AXIS) if accum else P(DATA_AXIS)
 
     mapped = jax.shard_map(
         _shard_body, mesh=mesh,
-        in_specs=(st_specs, {"image": P(None, DATA_AXIS),
-                             "label": P(None, DATA_AXIS)}, P()),
+        in_specs=(st_specs, {"image": batch_spec, "label": batch_spec}, P()),
         out_specs=(st_specs, P()),
         **extra,
     )
@@ -545,7 +585,7 @@ def shard_batch(batch: dict, mesh: Mesh) -> dict:
 
 def shard_batch_stacked(batch: dict, mesh: Mesh) -> dict:
     """Like :func:`shard_batch` for ``[A, B, ...]`` micro-batch stacks
-    (make_train_step_accum): sharded on the batch (second) axis."""
+    (``make_train_step(accum=True)``): sharded on the batch (second) axis."""
     sharding = NamedSharding(mesh, P(None, DATA_AXIS))
     if jax.process_count() == 1:
         return jax.device_put(batch, sharding)

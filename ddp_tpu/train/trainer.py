@@ -234,12 +234,17 @@ class Trainer:
                 ("--resident: the table in HBM and its row gather hold "
                  "image rows (u8[N,24,128]); token rows stream through "
                  "the host loader", resident),
-                ("--shard_update: the ZeRO update has its own loss core "
-                 "(train/zero.py), which does not sum a token model's "
-                 "routing counters", shard_update),
-                ("a tensor- or pipeline-parallel plan: their loss cores "
-                 "(train/step.py's _tp, parallel/pp/) take one label a "
-                 "sample",
+                ("--shard_update: the wiring (train/step.py:"
+                 "make_step_wiring) picks the local-objective loss core "
+                 "(train/zero.py:_make_local_grads), which pmeans every "
+                 "state leaf; a token model's routing counters are "
+                 "integers that must grow by the replicas' SUM "
+                 "(_reduce_state_leaf, in the replicated core only)",
+                 shard_update),
+                ("a tensor- or pipeline-parallel plan: the wiring picks "
+                 "the same local-objective core for a plan (and "
+                 "parallel/pp/ its own), which takes one label a sample "
+                 "and cannot carry the integer routing counters either",
                  tp_plan is not None or pp_plan is not None),
             ) if on]
             if not_wired:
@@ -391,8 +396,11 @@ class Trainer:
             self.state = TrainState(self.state.params, self.state.batch_stats,
                                     opt, self.state.step)
         self.resident = None
+        # One closure over one builder: _rebuild_step (the guard's
+        # lr_backoff recompile hook) calls it again with a scaled schedule.
         kw = dict(compute_dtype=compute_dtype, device_augment=device_augment,
-                  sync_bn=sync_bn, plan=tp_plan)
+                  sync_bn=sync_bn, plan=tp_plan, accum=self.grad_accum > 1,
+                  shard_update=shard_update)
         if resident:
             # Device-resident path: dataset uploaded once, whole epoch as a
             # single jitted lax.scan (train/epoch.py) — zero per-step host
@@ -404,53 +412,37 @@ class Trainer:
                     "skipped; build the TrainLoader with augment=False and "
                     "pass device_augment=True instead")
             from ..data.resident import ResidentData
-            from .epoch import make_train_epoch, make_train_epoch_accum
-            from .zero import (make_train_epoch_zero,
-                               make_train_epoch_zero_accum)
+            from .epoch import make_train_epoch as build
             with self.tracer.span(
                     "resident_upload",
                     nbytes=train_loader.dataset.images.nbytes):
                 self.resident = ResidentData(train_loader.dataset, mesh)
-            build = {(False, False): make_train_epoch,
-                     (False, True): make_train_epoch_accum,
-                     (True, False): make_train_epoch_zero,
-                     (True, True): make_train_epoch_zero_accum}[
-                (shard_update, self.grad_accum > 1)]
-            self.train_epoch = build(model, sgd_config, lr_schedule, mesh,
-                                     **kw)
         elif pp_plan is not None:
             # Pipeline path: per-stage jitted programs driven by a host
-            # schedule (parallel/pp/schedule.py).  Wrapped to the shared
-            # builder signature so _rebuild_step (the guard's lr_backoff
-            # recompile hook) works unchanged.
+            # schedule (parallel/pp/schedule.py), wrapped to the shared
+            # builder signature.
             from ..parallel.pp.schedule import make_pp_step
 
-            def build(model, sgd_config, sched, mesh, *, compute_dtype=None,
-                      device_augment=False, sync_bn=False, plan=None):
-                del sync_bn  # rejected above; signature parity only
+            def build(model, sgd_config, sched, mesh, *, compute_dtype,
+                      device_augment, plan, sync_bn, shard_update, accum):
+                del sync_bn, shard_update  # refused above
+                del accum  # the schedule's micro-batches ARE the groups
                 return make_pp_step(model.name, sgd_config, sched, mesh,
                                     pp_plan, compute_dtype=compute_dtype,
                                     device_augment=device_augment,
                                     tp_plan=plan, schedule=pp_schedule,
                                     tracer=self.tracer)
-
-            self.train_step = build(model, sgd_config, lr_schedule, mesh,
-                                    **kw)
         else:
-            from .step import make_train_step_accum
-            from .zero import make_train_step_zero, make_train_step_zero_accum
-            build = {(False, False): make_train_step,
-                     (False, True): make_train_step_accum,
-                     (True, False): make_train_step_zero,
-                     (True, True): make_train_step_zero_accum}[
-                (shard_update, self.grad_accum > 1)]
-            self.train_step = build(model, sgd_config, lr_schedule, mesh,
-                                    **kw)
+            build = make_train_step
         # The guard's lr_backoff action rebuilds the jitted program with
         # a scaled schedule — keep the builder and the unscaled schedule.
         self._base_lr_schedule = lr_schedule
         self._rebuild_step = lambda sched: build(model, sgd_config, sched,
                                                  mesh, **kw)
+        if resident:
+            self.train_epoch = self._rebuild_step(lr_schedule)
+        else:
+            self.train_step = self._rebuild_step(lr_schedule)
         if self.resident is not None and self._resume_offset:
             raise ValueError(
                 "resident mode dispatches whole epochs and cannot "

@@ -24,24 +24,27 @@ order (pinned by tests/test_zero.py).  BatchNorm stays per-shard by default;
 ``sync_bn=True`` psums the batch statistics exactly like the replicated
 path's opt-in (multigpu.py:127's commented-out SyncBatchNorm).
 
-The sharded update composes with every execution strategy the replicated
-update supports — streaming per-step, gradient accumulation
-(``make_train_step_zero_accum``), and the device-resident scan-per-epoch
-paths (``make_train_epoch_zero`` / ``make_train_epoch_zero_accum``) — all
-built from the same shared cores (:func:`_make_local_grads`,
-:func:`~ddp_tpu.train.step.make_accum_scan`,
-:func:`_make_zero_update`) so they cannot drift from one another.
+This module holds what is ZeRO's own: the flat momentum's constructors and
+converters, the local-grads core (:func:`_make_local_grads`) and the
+sharded update (:func:`_make_zero_update`).  The programs are built by the
+two train builders (:func:`~ddp_tpu.train.step.make_train_step`,
+:func:`~ddp_tpu.train.epoch.make_train_epoch`) with ``shard_update=True``,
+which compose it with every execution strategy the replicated update
+supports — streaming per-step, gradient accumulation, the device-resident
+scan-per-epoch — through the one wiring function
+(:func:`~ddp_tpu.train.step.make_step_wiring`), so they cannot drift from
+one another.
 
-Implementation note: these steps use ``shard_map(..., check_vma=False)``
-because the varying-axes type system has no way (in this JAX version) to
-re-mark an ``all_gather`` result as replicated; with the check off, the
-gradient psum is NOT auto-inserted, which is exactly what lets us
-reduce-*scatter* instead.  Every collective here is therefore explicit, and
-the differentiated objective is the *local* ``ce_sum/(count*R)`` whose
-shard-sum is the global-mean loss: the transpose of any ``psum`` inside the
-forward (sync-BN statistics) then contributes exactly the cross-shard
-cotangents of that summed objective, while the loss itself is deliberately
-NOT psum'd inside ``jax.grad``.
+Implementation note: the wiring runs these steps under
+``shard_map(..., check_vma=False)`` because the varying-axes type system
+has no way (in this JAX version) to re-mark an ``all_gather`` result as
+replicated; with the check off, the gradient psum is NOT auto-inserted,
+which is exactly what lets us reduce-*scatter* instead.  Every collective
+here is therefore explicit, and the differentiated objective is the *local*
+``ce_sum/(count*R)`` whose shard-sum is the global-mean loss: the transpose
+of any ``psum`` inside the forward (sync-BN statistics) then contributes
+exactly the cross-shard cotangents of that summed objective, while the loss
+itself is deliberately NOT psum'd inside ``jax.grad``.
 """
 from __future__ import annotations
 
@@ -57,10 +60,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..optim import sgd as sgd_lib
 from ..ops.losses import cross_entropy_sum_count
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, data_axis_size,
-                             replicated_sharding, scan_unroll)
-from .step import (TrainState, _as_input, _micro_from_batch,
-                   make_accum_scan, make_group_step, make_single_micro,
-                   micro_from_table)
+                             replicated_sharding)
+from .step import TrainState, _as_input
 
 
 def padded_size(params, axis_size: int) -> int:
@@ -277,173 +278,3 @@ def _make_zero_update(sgd_config: sgd_lib.SGDConfig,
                           state.step + 1)
 
     return zero_update
-
-
-def _zero_state_specs(plan=None) -> TrainState:
-    if plan is not None:
-        from ..parallel.tp.plan import state_specs
-        return state_specs(plan, zero=True)
-    return TrainState(params=P(), batch_stats=P(),
-                      opt_state=sgd_lib.SGDState(P(DATA_AXIS)), step=P())
-
-
-def _zero_jit(mapped, mesh: Mesh, plan=None):
-    rep = replicated_sharding(mesh)
-    if plan is not None:
-        from ..parallel.tp.plan import state_shardings
-        return jax.jit(mapped, donate_argnums=(0,),
-                       out_shardings=(state_shardings(plan, mesh,
-                                                      zero=True), rep))
-    state_shardings_ = TrainState(
-        params=rep, batch_stats=rep,
-        opt_state=sgd_lib.SGDState(NamedSharding(mesh, P(DATA_AXIS))),
-        step=rep)
-    return jax.jit(mapped, donate_argnums=(0,),
-                   out_shardings=(state_shardings_, rep))
-
-
-def _zero_pieces(model, mesh: Mesh, sgd_config, lr_schedule, compute_dtype,
-                 sync_bn, plan):
-    """(R, local_grads, zero_update) for the four builders below — R and
-    the tp threading decided in ONE place: the data-axis size and the
-    model's ``tp_axis`` forward under a plan, the flat-mesh size and the
-    plain forward without."""
-    if plan is None:
-        # Axis-extent product, not mesh.devices.size: the auto-plan search
-        # prices this builder on a deviceless AbstractMesh
-        # (parallel/mesh.py:abstract_mesh).
-        from ..parallel.mesh import mesh_size
-        R = mesh_size(mesh)
-        local_grads = _make_local_grads(model, R, compute_dtype, sync_bn)
-        return R, local_grads, _make_zero_update(sgd_config, lr_schedule, R)
-    from ..parallel.tp.plan import recipe_override
-    R = data_axis_size(mesh)
-    local_grads = _make_local_grads(model, R, compute_dtype, sync_bn,
-                                    tp_axis=MODEL_AXIS,
-                                    tp_recipe=recipe_override(plan))
-    return R, local_grads, _make_zero_update(sgd_config, lr_schedule, R,
-                                             tp=True)
-
-
-def make_train_step_zero(model, sgd_config: sgd_lib.SGDConfig,
-                         lr_schedule: Callable[[jax.Array], jax.Array],
-                         mesh: Mesh, compute_dtype=None,
-                         device_augment: bool = False,
-                         sync_bn: bool = False, plan=None):
-    """Like :func:`~ddp_tpu.train.step.make_train_step` but with the
-    weight update sharded over ``data``.  ``state.opt_state.momentum_buf``
-    must come from :func:`init_opt_shard` / :func:`pytree_to_opt_shard`.
-    ``plan`` (tp, 2-D mesh) composes: params along ``model``, the update
-    along ``data`` — pass the plan to the momentum constructors too.
-    """
-    _R, local_grads, zero_update = _zero_pieces(
-        model, mesh, sgd_config, lr_schedule, compute_dtype, sync_bn, plan)
-    _shard_body = make_group_step(
-        make_single_micro(local_grads, _micro_from_batch(device_augment)),
-        zero_update)
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(_zero_state_specs(plan),
-                  {"image": P(DATA_AXIS), "label": P(DATA_AXIS)}, P()),
-        out_specs=(_zero_state_specs(plan), P()),
-        check_vma=False,
-    )
-    return _zero_jit(mapped, mesh, plan)
-
-
-def make_train_step_zero_accum(model, sgd_config: sgd_lib.SGDConfig,
-                               lr_schedule: Callable[[jax.Array], jax.Array],
-                               mesh: Mesh, compute_dtype=None,
-                               device_augment: bool = False,
-                               sync_bn: bool = False, plan=None):
-    """Gradient accumulation with the sharded update: ``batch`` arrays are
-    ``[A, B, ...]`` micro-batch stacks (as for
-    :func:`~ddp_tpu.train.step.make_train_step_accum`, same RNG fold
-    structure); grads are averaged over the inner scan, then ONE
-    reduce-scatter + sharded SGD + all-gather."""
-    _R, local_grads, zero_update = _zero_pieces(
-        model, mesh, sgd_config, lr_schedule, compute_dtype, sync_bn, plan)
-    accum = make_accum_scan(local_grads,
-                            unroll_fn=lambda n: scan_unroll(mesh, n))
-    get_micro = _micro_from_batch(device_augment)
-    _shard_body = make_group_step(
-        lambda p, s, xs, rng: accum(p, s, xs, get_micro, rng), zero_update)
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(_zero_state_specs(plan),
-                  {"image": P(None, DATA_AXIS), "label": P(None, DATA_AXIS)},
-                  P()),
-        out_specs=(_zero_state_specs(plan), P()),
-        check_vma=False,
-    )
-    return _zero_jit(mapped, mesh, plan)
-
-
-def make_train_epoch_zero(model, sgd_config: sgd_lib.SGDConfig,
-                          lr_schedule: Callable[[jax.Array], jax.Array],
-                          mesh: Mesh, compute_dtype=None,
-                          device_augment: bool = False,
-                          sync_bn: bool = False, plan=None):
-    """Device-resident scan-per-epoch with the sharded update:
-    ``--resident`` composed with ``--shard_update``.  Same signature as
-    :func:`~ddp_tpu.train.epoch.make_train_epoch` (``idx``: int32
-    ``[steps, global_batch]``); the RNG fold structure matches the
-    streaming zero step, so the two agree step-for-step."""
-    _R, local_grads, zero_update = _zero_pieces(
-        model, mesh, sgd_config, lr_schedule, compute_dtype, sync_bn, plan)
-
-    def _shard_body(state: TrainState, images, labels, idx, rng):
-        group = make_group_step(
-            make_single_micro(local_grads,
-                          micro_from_table(images, labels, device_augment)),
-            zero_update)
-        return lax.scan(lambda st, idx_row: group(st, idx_row, rng),
-                        state, idx, unroll=scan_unroll(mesh, idx.shape[0]))
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(_zero_state_specs(plan), P(), P(), P(None, DATA_AXIS),
-                  P()),
-        out_specs=(_zero_state_specs(plan), P()),
-        check_vma=False,
-    )
-    return _zero_jit(mapped, mesh, plan)
-
-
-def make_train_epoch_zero_accum(model, sgd_config: sgd_lib.SGDConfig,
-                                lr_schedule: Callable[[jax.Array],
-                                                      jax.Array],
-                                mesh: Mesh, compute_dtype=None,
-                                device_augment: bool = False,
-                                sync_bn: bool = False, plan=None):
-    """``--resident`` + ``--grad_accum`` + ``--shard_update`` together:
-    the grouped epoch scan (``idx``: ``[G, A, global_batch]``, as for
-    :func:`~ddp_tpu.train.epoch.make_train_epoch_accum`) with one sharded
-    update per group."""
-    _R, local_grads, zero_update = _zero_pieces(
-        model, mesh, sgd_config, lr_schedule, compute_dtype, sync_bn, plan)
-
-    def _shard_body(state: TrainState, images, labels, idx, rng):
-        get_micro = micro_from_table(images, labels, device_augment)
-        # Product bound G*A on BOTH scans, as in
-        # epoch.make_train_epoch_accum: nested unrolls multiply, and an
-        # A-only-gated inner scan could fully unroll conv bodies inside a
-        # rolled outer loop (the pathological XLA:CPU shape — ADVICE r5).
-        total = idx.shape[0] * idx.shape[1]
-        accum = make_accum_scan(local_grads,
-                                unroll_fn=lambda _a: scan_unroll(mesh, total))
-        group = make_group_step(
-            lambda p, s, xs, g: accum(p, s, xs, get_micro, g), zero_update)
-        return lax.scan(lambda st, idx_group: group(st, idx_group, rng),
-                        state, idx, unroll=scan_unroll(mesh, total))
-
-    mapped = jax.shard_map(
-        _shard_body, mesh=mesh,
-        in_specs=(_zero_state_specs(plan), P(), P(),
-                  P(None, None, DATA_AXIS), P()),
-        out_specs=(_zero_state_specs(plan), P()),
-        check_vma=False,
-    )
-    return _zero_jit(mapped, mesh, plan)

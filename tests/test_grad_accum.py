@@ -1,4 +1,4 @@
-"""Gradient accumulation (--grad_accum / make_train_step_accum).
+"""Gradient accumulation (--grad_accum / make_train_step(accum=True)).
 
 Ground truth is hand-composed from the same building blocks: A separate
 forward/backwards on the micro-batches (BN stats chained in order), mean of
@@ -16,8 +16,7 @@ from ddp_tpu.models import get_model
 from ddp_tpu.optim import SGDConfig, triangular_lr
 from ddp_tpu.parallel import make_mesh
 from ddp_tpu.train import Trainer, make_train_step, shard_batch
-from ddp_tpu.train.step import (init_train_state, make_train_step_accum,
-                                shard_batch_stacked)
+from ddp_tpu.train.step import init_train_state, shard_batch_stacked
 
 
 def _setup(n_mesh, model_name="vgg"):
@@ -48,7 +47,7 @@ def test_accum_of_one_equals_plain_step():
     for _ in range(2):
         s_plain, l_plain = plain(s_plain, b, rng)
 
-    accum = make_train_step_accum(model, cfg, sched, mesh)
+    accum = make_train_step(model, cfg, sched, mesh, accum=True)
     s_acc = init_train_state(*jax.tree_util.tree_map(jnp.array,
                                                      (params, stats)))
     b1 = shard_batch_stacked({"image": ds.images[None], "label":
@@ -86,7 +85,7 @@ def test_accum_matches_hand_composition():
     labels = ds.labels.reshape(2, 16)
     rng = jax.random.key(7)
 
-    accum = make_train_step_accum(model, cfg, sched, mesh)
+    accum = make_train_step(model, cfg, sched, mesh, accum=True)
     s_acc = init_train_state(*jax.tree_util.tree_map(jnp.array,
                                                      (params, stats)))
     batch = shard_batch_stacked({"image": imgs, "label": labels}, mesh)

@@ -284,14 +284,14 @@ def _run_ref(model, params, stats, batches, d, m):
     from ddp_tpu.optim.sgd import SGDConfig
     from ddp_tpu.parallel.tp.plan import (is_trivial, plan_for_model,
                                           state_shardings)
-    from ddp_tpu.train.step import (init_train_state, make_train_step_accum,
+    from ddp_tpu.train.step import (init_train_state, make_train_step,
                                     shard_batch_stacked)
     mesh = make_mesh(shape=(d, m))
     plan = plan_for_model("deepnn", params, stats, model_size=m)
     sched = functools.partial(triangular_lr, base_lr=0.1, num_epochs=2,
                               steps_per_epoch=4)
-    step = make_train_step_accum(model, SGDConfig(lr=0.1), sched, mesh,
-                                 plan=plan)
+    step = make_train_step(model, SGDConfig(lr=0.1), sched, mesh,
+                           plan=plan, accum=True)
     state = init_train_state(params, stats)
     if not is_trivial(plan):
         state = jax.device_put(state, state_shardings(plan, mesh))

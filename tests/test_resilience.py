@@ -765,17 +765,16 @@ def test_abort_fast_path_canary():
 # -- scan-unroll product gating (ADVICE r5) --------------------------------
 
 
-def _trace_accum_epoch(monkeypatch, module_name, builder):
+def _trace_accum_epoch(monkeypatch, shard_update):
     """Trace an accumulation epoch program with scan_unroll recorded:
     G*A > 32 but A <= 32 — the shape where an A-gated inner scan would
     inline conv bodies inside a rolled outer loop."""
-    import importlib
-
     from ddp_tpu.parallel.mesh import scan_unroll as real_scan_unroll
+    from ddp_tpu.train import epoch as mod
     from ddp_tpu.train.epoch import put_index_matrix
     from ddp_tpu.train.step import TrainState, init_train_state
+    from ddp_tpu.train.zero import init_opt_shard
 
-    mod = importlib.import_module(module_name)
     calls = []
 
     def recording(mesh, length=None):
@@ -788,14 +787,15 @@ def _trace_accum_epoch(monkeypatch, module_name, builder):
     params, stats = model.init(jax.random.key(0))
     sched = functools.partial(triangular_lr, base_lr=0.1, num_epochs=1,
                               steps_per_epoch=34)
-    fn = builder(mod)(model, SGDConfig(), sched, mesh)
+    fn = mod.make_train_epoch(model, SGDConfig(), sched, mesh, accum=True,
+                              shard_update=shard_update)
     G, A, B = 17, 2, 8  # G*A = 34 > 32, A = 2 <= 32
     from ddp_tpu.ops.gather import RowTable
     images = RowTable.from_rows(jnp.zeros((16, 32, 32, 3), jnp.float32))
     labels = jnp.zeros((16,), jnp.int32)
     idx = put_index_matrix(np.zeros((G, A, B), np.int32), mesh)
-    if module_name.endswith("zero"):
-        state = TrainState(params, stats, mod.init_opt_shard(params, mesh),
+    if shard_update:
+        state = TrainState(params, stats, init_opt_shard(params, mesh),
                            jnp.zeros((), jnp.int32))
     else:
         state = init_train_state(params, stats)
@@ -803,18 +803,14 @@ def _trace_accum_epoch(monkeypatch, module_name, builder):
     return G, A, calls
 
 
-@pytest.mark.parametrize("module_name,builder", [
-    ("ddp_tpu.train.epoch", lambda m: m.make_train_epoch_accum),
-    ("ddp_tpu.train.zero", lambda m: m.make_train_epoch_zero_accum),
-])
-def test_accum_inner_unroll_gated_on_product(monkeypatch, module_name,
-                                             builder):
+@pytest.mark.parametrize("shard_update", [False, True])
+def test_accum_inner_unroll_gated_on_product(monkeypatch, shard_update):
     """ADVICE r5: BOTH the outer epoch scan and the inner accum scan must
     gate their unroll on the G*A product — an inner scan gated on A alone
     would fully unroll A conv fwd+bwd bodies inside a rolled while loop
     whenever A <= 32 < G*A (the pathological XLA:CPU conv-in-rolled-loop
     shape)."""
-    G, A, calls = _trace_accum_epoch(monkeypatch, module_name, builder)
+    G, A, calls = _trace_accum_epoch(monkeypatch, shard_update)
     assert len(calls) == 2  # outer epoch scan + inner accum scan
     assert calls == [G * A, G * A]
 

@@ -51,8 +51,8 @@ from ddp_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, batch_sharding,
 from ddp_tpu.parallel.tp.plan import (format_plan_table, local_param_count,
                                       plan_for_model, state_shardings)
 from ddp_tpu.train.step import (init_train_state, make_eval_forward,
-                                make_train_step, make_train_step_accum,
-                                shard_batch, shard_batch_stacked)
+                                make_train_step, shard_batch,
+                                shard_batch_stacked)
 
 # Measured on this backend (fp32, 3 steps, lr 0.1): cross-mesh-shape max
 # param delta is 1.5e-8 — identical to the PURE-DP delta between two 1-D
@@ -87,8 +87,9 @@ def _run_steps(model, params0, mesh, plan, batches, *, zero=False):
     """Train len(batches) steps from params0; returns (flat params, losses,
     final state)."""
     if zero:
-        from ddp_tpu.train.zero import init_opt_shard, make_train_step_zero
-        step = make_train_step_zero(model, _SGD, _SCHED, mesh, plan=plan)
+        from ddp_tpu.train.zero import init_opt_shard
+        step = make_train_step(model, _SGD, _SCHED, mesh, plan=plan,
+                               shard_update=True)
         state = init_train_state(
             jax.tree_util.tree_map(jnp.asarray, params0), {})
         state = state._replace(
@@ -289,7 +290,8 @@ def test_tp_accum_m1_bit_identical(deepnn_params):
     rng = jax.random.key(5)
 
     def run(mesh, plan):
-        step = make_train_step_accum(model, _SGD, _SCHED, mesh, plan=plan)
+        step = make_train_step(model, _SGD, _SCHED, mesh, plan=plan,
+                               accum=True)
         state = init_train_state(
             jax.tree_util.tree_map(jnp.asarray, params0), {})
         if plan is not None:

@@ -16,7 +16,7 @@ from ddp_tpu.optim import SGDConfig, triangular_lr
 from ddp_tpu.parallel import make_mesh
 from ddp_tpu.train import shard_batch
 from ddp_tpu.train.step import TrainState, init_train_state, make_train_step
-from ddp_tpu.train.zero import init_opt_shard, make_train_step_zero
+from ddp_tpu.train.zero import init_opt_shard
 
 # Matches an HLO op DEFINITION of the given kind, tuple-shaped (variadic)
 # or not: "%name = f32[123]{0} all-gather(..." / "= (f32[], f32[]) all-reduce(".
@@ -63,7 +63,8 @@ def test_zero_step_collective_inventory():
     all-reduces (the loss/count psum).  A param-scale all-reduce here
     means the auto-psum came back and gradients are double-counted."""
     model, params, stats, mesh, sched, batch = _setup(2)
-    step = make_train_step_zero(model, SGDConfig(lr=0.1), sched, mesh)
+    step = make_train_step(model, SGDConfig(lr=0.1), sched, mesh,
+                           shard_update=True)
     st = TrainState(params, stats, init_opt_shard(params, mesh),
                     jnp.zeros((), jnp.int32))
     txt = _compiled_text(step, st, batch)
