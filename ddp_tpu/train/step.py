@@ -327,11 +327,10 @@ def make_step_wiring(model, sgd_config: sgd_lib.SGDConfig,
     (``P(data)``; ``P(model, data)`` under a plan — here ANY plan, trivial
     or not, because the state's constructors
     (:func:`~ddp_tpu.train.zero.init_opt_shard`) lay the buffer out by
-    ``plan is None`` alone).  ``check_vma=False`` because the
-    varying-axes type system has no way (in this JAX version) to re-mark
-    an ``all_gather`` result as replicated; with the check off the
-    gradient psum is NOT auto-inserted, which is exactly what lets the
-    update reduce-*scatter* instead.
+    ``plan is None`` alone).  ``check_vma=False`` because an
+    ``all_gather`` result cannot be re-marked replicated, and with the
+    check off no gradient psum is auto-inserted, which is what lets the
+    update reduce-*scatter* instead (train/zero.py's implementation note).
     """
     from ..parallel.tp.plan import (is_trivial, recipe_override,
                                     state_shardings, state_specs)
@@ -357,18 +356,14 @@ def make_step_wiring(model, sgd_config: sgd_lib.SGDConfig,
             tp_recipe=recipe_override(plan) if tp else None)
         update = _make_zero_update(sgd_config, lr_schedule, R, tp=tp)
         if tp:
-            return (core, update, state_specs(plan, zero=True),
-                    state_shardings(plan, mesh, zero=True),
-                    {"check_vma": False})
-        flat = sgd_lib.SGDState(P(DATA_AXIS))
-        return (core, update,
-                TrainState(params=P(), batch_stats=P(), opt_state=flat,
-                           step=P()),
-                TrainState(params=rep, batch_stats=rep,
-                           opt_state=sgd_lib.SGDState(
-                               NamedSharding(mesh, P(DATA_AXIS))),
-                           step=rep),
-                {"check_vma": False})
+            specs = state_specs(plan, zero=True)
+            shardings = state_shardings(plan, mesh, zero=True)
+        else:
+            flat = P(DATA_AXIS)
+            specs = TrainState(P(), P(), sgd_lib.SGDState(flat), P())
+            shardings = TrainState(
+                rep, rep, sgd_lib.SGDState(NamedSharding(mesh, flat)), rep)
+        return core, update, specs, shardings, {"check_vma": False}
     update = make_group_update(sgd_config, lr_schedule)
     if plan is None or is_trivial(plan):
         core = make_loss_and_grads(model, compute_dtype=compute_dtype,

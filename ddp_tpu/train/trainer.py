@@ -396,8 +396,6 @@ class Trainer:
             self.state = TrainState(self.state.params, self.state.batch_stats,
                                     opt, self.state.step)
         self.resident = None
-        # One closure over one builder: _rebuild_step (the guard's
-        # lr_backoff recompile hook) calls it again with a scaled schedule.
         kw = dict(compute_dtype=compute_dtype, device_augment=device_augment,
                   sync_bn=sync_bn, plan=tp_plan, accum=self.grad_accum > 1,
                   shard_update=shard_update)
