@@ -8,6 +8,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
     gather     python -m ddp_tpu.ops.gather       Pallas row gather == table[idx]
     attention  python -m ddp_tpu.ops.attention    the token model's attention kernel
                                                   against the XLA loop and float32
+    ssd        python -m ddp_tpu.ops.ssd          the token model's scan kernels
+                                                  against the XLA path and float32
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
     train_again  the same command once more       adds no compile-cache entries
     serve      python -m ddp_tpu.serve            /predict x3, SIGTERM drain, exit 0
@@ -368,20 +370,21 @@ class Smoke:
             fail(f"no 'gather: ok kernel={kernel}' line", cmd, out)
         return m.group(0)
 
-    def attention(self) -> str:
-        """The kernel at the token cell's shape: the child raises where it
-        is further from float32 than the XLA loop or the mixer does not
-        take it; its table (distances, milliseconds, block sweep, paths
-        traced) is shown."""
-        cmd = [PY, "-m", "ddp_tpu.ops.attention"]
+    def kernel_check(self, name: str) -> str:
+        """``python -m ddp_tpu.ops.<name>``: a token-model kernel at the
+        token cell's shape.  The child raises where the kernel is further
+        from float32 than the XLA path or the mixer does not take it; its
+        table (distances, milliseconds, block sweep, paths traced) is
+        shown."""
+        cmd = [PY, "-m", f"ddp_tpu.ops.{name}"]
         out = self.run(cmd)
         self.check_device(cmd, out)
-        for line in re.findall(r"^attention: .*$", out, re.M)[:-1]:
+        for line in re.findall(rf"^{name}: .*$", out, re.M)[:-1]:
             print(f"[smoke]   {line}", flush=True)
         kernel = "pallas" if self.platform == "tpu" else "interpret"
-        m = re.search(rf"^attention: ok kernel={kernel} .*$", out, re.M)
+        m = re.search(rf"^{name}: ok kernel={kernel} .*$", out, re.M)
         if not m:
-            fail(f"no 'attention: ok kernel={kernel}' line", cmd, out)
+            fail(f"no '{name}: ok kernel={kernel}' line", cmd, out)
         return m.group(0)
 
     def lm(self) -> str:
@@ -399,8 +402,8 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "attention", "train", "train_again", "serve", "bf16",
-          "resident", "shard_update", "resume", "lm", "generate")
+PHASES = ("gather", "attention", "ssd", "train", "train_again", "serve",
+          "bf16", "resident", "shard_update", "resume", "lm", "generate")
 
 
 def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
@@ -414,7 +417,8 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
     cache_before = s.cache_entries()
     table = {
         "gather": s.gather,
-        "attention": s.attention,
+        "attention": lambda: s.kernel_check("attention"),
+        "ssd": lambda: s.kernel_check("ssd"),
         "train": lambda: s.train_dp("train"),
         "train_again": s.train_again,
         "serve": s.serve_predict,

@@ -12,7 +12,9 @@ Each block is ``x + mixer(RMSNorm(x))``, one mixer a block by the
 character of ``hybrid_override_pattern``:
 
 - ``M``, a Mamba-2 mixer in the chunked form: products inside chunks of
-  ``chunk_size`` tokens, a ``lax.scan`` over the chunks' states.
+  ``chunk_size`` tokens and a recurrence over the chunks' states: the
+  blocked kernels of ops/ssd.py where they apply (a TPU, whole chunks and
+  lanes), else plain XLA with a ``lax.scan`` over the chunks.
 - ``*``, grouped-query causal attention without positional encoding:
   the blocked kernel of ops/attention.py where it applies (a TPU, whole
   blocks), else a block of queries at a time against the keys before it.
@@ -44,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import attention
+from ..ops import attention, ssd
 from ..ops.layers import linear
 
 F32 = jnp.float32
@@ -210,9 +212,20 @@ def mamba_mixer(p, x, dm: dict, cd):
         c = xbc[..., d_inner + g * n:].reshape(bsz, t, g, n)
         dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"])
         a = -jnp.exp(p["A_log"])
-        y = ssd_chunked(xs, dt, a, b, c, p["D"], dm["chunk"], cd)
-        y = gated_norm(y.reshape(bsz, t, d_inner), z, p["gate_norm"], g,
-                       dm["eps"], cd)
+        # The blocked kernels where the shapes and the backend allow it
+        # (ops/ssd.py: a chunk's decay matrices and the running state stay
+        # in VMEM, and the gate and the norm, whose groups are the scan's,
+        # are applied there); else the chunked form in plain XLA.
+        if ssd.kernel_applies(t, dm["h"], dm["p"], g, n, dm["chunk"],
+                              jnp.dtype(cd).itemsize):
+            ssd.TRACED["kernel"] += 1
+            y = ssd.ssd_scan(xs, dt, a, b, c, p["D"], z, p["gate_norm"],
+                             dm["chunk"], dm["eps"])
+        else:
+            ssd.TRACED["xla"] += 1
+            y = ssd_chunked(xs, dt, a, b, c, p["D"], dm["chunk"], cd)
+            y = gated_norm(y.reshape(bsz, t, d_inner), z, p["gate_norm"],
+                           g, dm["eps"], cd)
     with jax.named_scope("ssm_proj"):
         return linear(y, p["out_proj"].astype(cd))
 

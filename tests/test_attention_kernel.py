@@ -280,7 +280,7 @@ for name in ("vgg", "resnet18"):
     assert "stablehlo" in step.lower(state, batch,
                                      jax.random.key(0)).as_text()
 seen = sorted(m for m in sys.modules if m in (
-    "ddp_tpu.ops.attention", "ddp_tpu.models.nemotron_h",
+    "ddp_tpu.ops.attention", "ddp_tpu.ops.ssd", "ddp_tpu.models.nemotron_h",
     "jax.experimental.pallas"))
 print("SEEN", seen)
 """
@@ -289,9 +289,10 @@ print("SEEN", seen)
 def test_classifier_processes_never_import_the_kernel():
     """The benchmark's classifier cells build ``vgg`` and ``resnet18``
     through ``get_model`` and the Trainer: a fresh process that does so,
-    and lowers a train step of each, has imported neither the kernel's
-    module nor the token model's (nor Pallas), so nothing this kernel
-    brings can reach their set-up, their programs or their cache keys."""
+    and lowers a train step of each, has imported neither kernel's module
+    (attention, the scan of ops/ssd.py) nor the token model's (nor
+    Pallas), so nothing these kernels bring can reach their set-up, their
+    programs or their cache keys."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     out = subprocess.run([sys.executable, "-c", _CLASSIFIER_PROCESS],

@@ -13,8 +13,9 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
     """The real commands as children — gather check, the attention kernel's
-    check (off the chip: through the interpreter), train + checkpoint +
-    eval, the same train again adding nothing to the compile cache,
+    and the scan kernels' checks (off the chip: through the interpreter),
+    train + checkpoint + eval, the same train again adding nothing to the
+    compile cache,
     serve + /predict + SIGTERM drain, resume at epoch 1 — with every check
     of the smoke applied.  The expected platform is this test's argument;
     ``python chip_smoke.py`` itself always expects tpu."""
@@ -25,8 +26,8 @@ def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     result = chip_smoke.run_smoke(
         "cpu", model="deepnn", batch=8, out=str(tmp_path / "out"),
-        phases=("gather", "attention", "train", "train_again", "serve",
-                "resume"),
+        phases=("gather", "attention", "ssd", "train", "train_again",
+                "serve", "resume"),
         loss_band=(2.0, 2.7))
     assert result == {"ok": True, "device": {"platform": "cpu",
                                              "kind": "cpu", "count": 1}}

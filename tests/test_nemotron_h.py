@@ -25,7 +25,7 @@ from benchmark import flops_seq  # noqa: E402
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from ddp_tpu.models import MODEL_NAMES, get_model  # noqa: E402
 from ddp_tpu.models import nemotron_h as sysm  # noqa: E402
-from ddp_tpu.ops import attention  # noqa: E402
+from ddp_tpu.ops import attention, ssd  # noqa: E402
 
 CONFIG_FILE = os.path.join(ROOT, "benchmark", "configs",
                            "nemotron3_nano_30b_a3b_ep16.json")
@@ -98,7 +98,8 @@ def reference_loss_and_grads(config, params, state, ids, targets):
 @pytest.mark.parametrize("cd", [None, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("pattern,path", [
     ("M", "xla"), ("*", "xla"), ("E", "xla"), ("MEMEM*EME", "xla"),
-    ("*", "kernel"), ("MEMEM*EME", "kernel")])
+    ("*", "kernel"), ("MEMEM*EME", "kernel"), ("M", "scan_kernel"),
+    ("MEMEM*EME", "scan_kernel")])
 def test_matches_reference(pattern, path, cd, monkeypatch):
     # Several query blocks, the last one ragged.
     monkeypatch.setattr(sysm, "ATTN_QUERY_BLOCK", 32)
@@ -107,6 +108,7 @@ def test_matches_reference(pattern, path, cd, monkeypatch):
     monkeypatch.setattr(sysm, "MOE_ROW_TILE", 16)
     config, t = tiny(pattern), T
     monkeypatch.setattr(attention, "TRACED", {"kernel": 0, "xla": 0})
+    monkeypatch.setattr(ssd, "TRACED", {"kernel": 0, "xla": 0})
     if path == "kernel":
         # The chip's path, through the interpreter: heads of whole lanes,
         # two query blocks of two key tiles.
@@ -116,12 +118,27 @@ def test_matches_reference(pattern, path, cd, monkeypatch):
         monkeypatch.setattr(attention, "causal_gqa", functools.partial(
             attention.causal_gqa, interpret=True))
         config, t = tiny(pattern, head_dim=128), 256
+    if path == "scan_kernel":
+        # The scan's chip path, through the interpreter: a state of whole
+        # lanes, eight heads of 64 a group, three chunks of 128, a chunk
+        # and two a grid step.
+        monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+        monkeypatch.setattr(ssd, "FWD_CHUNKS", 1)
+        monkeypatch.setattr(ssd, "BWD_CHUNKS", 2)
+        monkeypatch.setattr(ssd, "ssd_scan", functools.partial(
+            ssd.ssd_scan, interpret=True))
+        config, t = tiny(pattern, mamba_num_heads=16, mamba_head_dim=64,
+                         ssm_state_size=128, chunk_size=128), 384
     params, state = seeded(config)
     ids, targets = batch(t=t)
     loss, grads, logits = system_loss_and_grads(config, params, state, ids,
                                                 targets, cd)
     taken = {k for k, n in attention.TRACED.items() if n}
-    assert taken == ({path} if "*" in pattern else set())
+    assert taken == ({"kernel" if path == "kernel" else "xla"}
+                     if "*" in pattern else set())
+    taken = {k for k, n in ssd.TRACED.items() if n}
+    assert taken == ({"kernel" if path == "scan_kernel" else "xla"}
+                     if "M" in pattern else set())
     r_loss, r_grads, r_logits = reference_loss_and_grads(
         config, params, state, ids, targets)
     tol = 2e-4 if cd is None else 4e-2
@@ -396,12 +413,16 @@ def test_three_epochs_through_the_trainer(monkeypatch):
     from ddp_tpu.obs.tracer import SpanTracer
     tracer, registry = SpanTracer(ring=1 << 16), MetricsRegistry()
     monkeypatch.setattr(attention, "TRACED", {"kernel": 0, "xla": 0})
+    monkeypatch.setattr(ssd, "TRACED", {"kernel": 0, "xla": 0})
     trainer = _trainer(tiny(), tracer=tracer, registry=registry)
     trainer.train(3)
-    # Heads of 16 on the CPU: the XLA loop, chosen once when the step is
-    # traced (forward, and again under each checkpoint).
+    # Heads of 16 and a state of 16 on the CPU: the XLA loop and the
+    # chunked form in XLA, chosen once when the step is traced (forward,
+    # and again under each checkpoint).
     print("attention paths traced:", attention.TRACED)
+    print("scan paths traced:", ssd.TRACED)
     assert attention.TRACED["kernel"] == 0 < attention.TRACED["xla"]
+    assert ssd.TRACED["kernel"] == 0 < ssd.TRACED["xla"]
     losses = np.asarray(trainer.loss_history)
     assert losses.shape == (24,) and np.isfinite(losses).all()
     assert losses[-8:].mean() < losses[:8].mean()

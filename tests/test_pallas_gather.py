@@ -251,3 +251,36 @@ def test_attention_kernels_compile_for_v5e_at_the_cells_shape(
     # One head's float32 scores are 268 MB; the program's temporaries are
     # the log-sum-exp, ``di`` and padding: a few MB.
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
+                                                         monkeypatch):
+    """The token cell's Mamba-2 scan (ops/ssd.py; here because the
+    described chip's fixture lives in this file only): 2 sequences of
+    8,192 tokens, 64 heads of 64 in 8 groups, state 128, chunks of 128 in
+    bf16 at the module's constants, forward and backward, through Mosaic:
+    both kernels (the gate and the norm inside them) are in the program,
+    within the VMEM limit the module sets, and what is left for HBM is the
+    saved entering states and float32 ``y``, not a ``[Q,Q]`` matrix a head
+    a chunk (1.07 GB each)."""
+    from ddp_tpu.ops import ssd
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    bsz, t, h, p, g, n, q = 2, 8192, 64, 64, 8, 128, 128
+    assert ssd.kernel_applies(t, h, p, g, n, q, 2)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    args = (spec((bsz, t, h, p)), spec((bsz, t, h), f32), spec((h,), f32),
+            spec((bsz, t, g, n)), spec((bsz, t, g, n)), spec((h,), f32),
+            spec((bsz, t, h * p)), spec((h * p,), f32))
+    compiled = jax.jit(ssd._vjp_of(
+        lambda *a: ssd.ssd_scan(*a, q, 1e-5))).lower(
+            args, spec((bsz, t, h * p))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    # The entering states are 537 MB, float32 y 268 MB, the rows' layout
+    # of dt and its cumulative sum and their cotangents a few MB each.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1100 * 2**20
