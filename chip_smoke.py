@@ -10,6 +10,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
                                                   against the XLA loop and float32
     ssd        python -m ddp_tpu.ops.ssd          the token model's scan kernels
                                                   against the XLA path and float32
+    sambay     python -m ddp_tpu.models.sambay    one step of the SambaY stage (layers
+                                                  14-19 of 32): tiny, then published widths
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
     train_again  the same command once more       adds no compile-cache entries
     serve      python -m ddp_tpu.serve            /predict x3, SIGTERM drain, exit 0
@@ -18,7 +20,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
     lm         python -m ddp_tpu.train.lm         compile coverage (64-wide preset)
     generate   python -m ddp_tpu.serve --generate /generate x3, drain, exit 0
 
-(``multigpu.py`` over every chip when the machine shows more than one.)
+(``multigpu.py`` over every chip when the machine shows more than one.
+``python chip_smoke.py sambay lm`` runs the phases named and no others.)
 
 This parent imports neither jax nor ddp_tpu: every phase is a child that
 owns the chip while it runs and releases it when it exits.  Children get
@@ -53,6 +56,8 @@ PHASE_TIMEOUT_S = 600
 STEPS = 8                      # per epoch, per chip count: size = 8 x batch x chips
 LN10_BAND = (2.30, 2.42)       # first-step CE, 10 classes; CPU run of seed 0: 2.3597
 LN256_BAND = (5.0, 6.2)        # first-step CE of the byte LM; ln 256 = 5.545
+SAMBAY_CONFIG = os.path.join("benchmark", "configs",
+                             "phi4_mini_flash_stage14_19.json")
 
 DEVICE_RE = re.compile(
     r'^device: platform=(\S+) device_kind="([^"]*)" visible=(\d+) '
@@ -387,6 +392,22 @@ class Smoke:
             fail(f"no '{name}: ok kernel={kernel}' line", cmd, out)
         return m.group(0)
 
+    def sambay(self) -> str:
+        """One training step of the second token model's pipeline stage,
+        at a tiny width and at the published widths (8,192 tokens): the
+        child raises on a wrong logits shape or a loss or parameter that
+        is not finite."""
+        cmd = [PY, "-m", "ddp_tpu.models.sambay", SAMBAY_CONFIG]
+        out = self.run(cmd)
+        self.check_device(cmd, out)
+        for line in re.findall(r"^sambay: .*$", out, re.M)[:-1]:
+            print(f"[smoke]   {line}", flush=True)
+        steps = 2 if self.platform == "tpu" else 1
+        m = re.search(rf"^sambay: ok steps={steps} .*$", out, re.M)
+        if not m:
+            fail(f"no 'sambay: ok steps={steps}' line", cmd, out)
+        return m.group(0)
+
     def lm(self) -> str:
         cmd = [PY, "-m", "ddp_tpu.train.lm", "--steps", "6",
                "--snapshot_path", self.path("lm/ckpt.npz")]
@@ -402,8 +423,9 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "attention", "ssd", "train", "train_again", "serve",
-          "bf16", "resident", "shard_update", "resume", "lm", "generate")
+PHASES = ("gather", "attention", "ssd", "sambay", "train", "train_again",
+          "serve", "bf16", "resident", "shard_update", "resume", "lm",
+          "generate")
 
 
 def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
@@ -419,6 +441,7 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
         "gather": s.gather,
         "attention": lambda: s.kernel_check("attention"),
         "ssd": lambda: s.kernel_check("ssd"),
+        "sambay": s.sambay,
         "train": lambda: s.train_dp("train"),
         "train_again": s.train_again,
         "serve": s.serve_predict,
@@ -457,8 +480,15 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
 
 
 def main() -> int:
+    # ``python chip_smoke.py [phase ...]``: the phases named, in the
+    # table's order; all of them where none is.
+    unknown = sorted(set(sys.argv[1:]) - set(PHASES))
+    if unknown:
+        print(f"unknown phase(s) {unknown}; phases: {', '.join(PHASES)}")
+        return 2
+    phases = tuple(p for p in PHASES if p in sys.argv[1:]) or PHASES
     try:
-        result = run_smoke("tpu")
+        result = run_smoke("tpu", phases=phases)
     except SmokeFailure as e:
         print(e, flush=True)
         return 1

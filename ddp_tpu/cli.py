@@ -47,14 +47,16 @@ def build_parser(description: str) -> argparse.ArgumentParser:
                    help="Input batch size on each device (default: 512)")
     # Framework extensions (all default to reference behavior).
     p.add_argument("--model", default="vgg",
-                   choices=["vgg", "deepnn", "resnet18", "tinylm", "nemotron_h"],
+                   choices=["vgg", "deepnn", "resnet18", "tinylm", "nemotron_h",
+                            "sambay"],
                    help="Model to train (reference trains VGG)")
     p.add_argument("--model_config", default=None, metavar="FILE",
                    help="Configuration file (JSON) of a model that is "
-                        "built from one: --model nemotron_h reads its "
-                        "layer pattern, widths and the share held here "
-                        "from it, e.g. benchmark/configs/"
-                        "nemotron3_nano_30b_a3b_ep16.json.  With "
+                        "built from one: --model nemotron_h and --model "
+                        "sambay read their layers, widths and the share "
+                        "held here from it, e.g. benchmark/configs/"
+                        "nemotron3_nano_30b_a3b_ep16.json and "
+                        "phi4_mini_flash_stage14_19.json.  With "
                         "--synthetic such a model trains on the seeded "
                         "token generator (data/tokens.py) at the file's "
                         "seq_len")

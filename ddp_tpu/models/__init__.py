@@ -31,7 +31,7 @@ _MODULE_MODELS = {
 }
 # name -> module with ``build(config) -> (init, apply, tokens)``: models
 # built from a configuration file (``--model_config``).
-_CONFIG_MODELS = {"nemotron_h": "nemotron_h"}
+_CONFIG_MODELS = {"nemotron_h": "nemotron_h", "sambay": "sambay"}
 MODEL_NAMES = tuple(_MODULE_MODELS) + tuple(_CONFIG_MODELS)
 
 

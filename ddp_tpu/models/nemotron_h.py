@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import attention, ssd
+from ..ops import attention, seq, ssd
 from ..ops.layers import linear
 
 F32 = jnp.float32
@@ -194,18 +194,14 @@ def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int, cd):
 
 def mamba_mixer(p, x, dm: dict, cd):
     bsz, t, _ = x.shape
-    d_inner, g, n, k = dm["d_inner"], dm["g"], dm["n"], dm["k"]
+    d_inner, g, n = dm["d_inner"], dm["g"], dm["n"]
     with jax.named_scope("ssm_proj"):
         zxbcdt = linear(x, p["in_proj"].astype(cd))
     z = zxbcdt[..., :d_inner]
     xbc = zxbcdt[..., d_inner:d_inner + dm["conv_dim"]]
     dt = zxbcdt[..., d_inner + dm["conv_dim"]:]
     with jax.named_scope("ssm_conv"):
-        # Causal depthwise conv: tap k-1 multiplies the current position.
-        padded = jnp.pad(xbc.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
-        xbc = sum(padded[:, i:i + t] * p["conv_w"][i] for i in range(k)) \
-            + p["conv_b"]
-        xbc = jax.nn.silu(xbc).astype(cd)
+        xbc = seq.causal_conv_silu(xbc, p["conv_w"], p["conv_b"], cd)
     with jax.named_scope("ssm_scan"):
         xs = xbc[..., :d_inner].reshape(bsz, t, dm["h"], dm["p"])
         b = xbc[..., d_inner:d_inner + g * n].reshape(bsz, t, g, n)
@@ -232,34 +228,11 @@ def mamba_mixer(p, x, dm: dict, cd):
 
 # -- *: attention -------------------------------------------------------------
 
-def _attend(q, k, v, *, start: int, scale: float, cd):
-    """One key-value head of one sequence: its ``R`` query heads' queries
-    ``start ...`` against the keys up to their own position.  ``q``
-    [R,bq,hd], ``k``/``v`` [S,hd] with S = start + bq.  The scores are
-    held keys-first, ``[S, R*bq]``: with the queries as the minor
-    dimension XLA:TPU runs both products as plain matrix products (10.6 ms
-    a head forward at T = 8,192); queries-first, the same float32 scores
-    cost 49 ms a block once S passes 4,096 (PERF.md, findings of PR 28)."""
-    r, bq, hd = q.shape
-    scores = jnp.dot(k, q.reshape(r * bq, hd).T,
-                     preferred_element_type=F32) * scale
-    qi = start + (jnp.arange(r * bq) % bq)[None, :]
-    si = jnp.arange(k.shape[0])[:, None]
-    probs = jax.nn.softmax(jnp.where(si <= qi, scores, -jnp.inf), axis=0)
-    out = lax.dot_general(probs.astype(cd), v, (((0,), (0,)), ((), ())))
-    return out.reshape(r, bq, hd)
-
-
 def _attend_head(q, k, v, *, scale: float, cd):
-    """A block of queries at a time, each under its own checkpoint: the
-    scores at [T,T] never exist at once, forward or backward.  ``q``
-    [R,T,hd], ``k``/``v`` [T,hd]."""
-    blk = ATTN_QUERY_BLOCK
-    out = [jax.checkpoint(functools.partial(
-        _attend, start=s, scale=scale, cd=cd))(
-            q[:, s:s + blk], k[:s + blk], v[:s + blk])
-        for s in range(0, q.shape[1], blk)]
-    return jnp.concatenate(out, axis=1)
+    """The XLA loop where the kernel does not apply: a block of
+    ``ATTN_QUERY_BLOCK`` queries at a time, keys-first (ops/seq.py)."""
+    return seq.attend_head(q, k, v, scale=scale, cd=cd,
+                           block=ATTN_QUERY_BLOCK)
 
 
 def attention_mixer(p, x, dm: dict, cd):
