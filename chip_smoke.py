@@ -10,6 +10,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
                                                   against the XLA loop and float32
     ssd        python -m ddp_tpu.ops.ssd          the token model's scan kernels
                                                   against the XLA path and float32
+    selscan    python -m ddp_tpu.ops.selscan      the second token model's scan kernels
+                                                  against the XLA path and float32
     sambay     python -m ddp_tpu.models.sambay    one step of the SambaY stage (layers
                                                   14-19 of 32): tiny, then published widths
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
@@ -423,9 +425,9 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "attention", "ssd", "sambay", "train", "train_again",
-          "serve", "bf16", "resident", "shard_update", "resume", "lm",
-          "generate")
+PHASES = ("gather", "attention", "ssd", "selscan", "sambay", "train",
+          "train_again", "serve", "bf16", "resident", "shard_update",
+          "resume", "lm", "generate")
 
 
 def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
@@ -441,6 +443,7 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
         "gather": s.gather,
         "attention": lambda: s.kernel_check("attention"),
         "ssd": lambda: s.kernel_check("ssd"),
+        "selscan": lambda: s.kernel_check("selscan"),
         "sambay": s.sambay,
         "train": lambda: s.train_dp("train"),
         "train_again": s.train_again,

@@ -14,8 +14,14 @@ matrix, no positional encoding.  The mixer by the PUBLISHED layer index
 - ``mamba``, a Mamba-1 mixer: the selective scan (a diagonal ``A``
   ``[d_inner, N]`` and a step ``dt`` a channel, so no product over
   chunks as Mamba-2 has) runs a token a step over a carried float32
-  state, in chunks under a checkpoint.  Layer ``half`` hands its scan's
-  result ``m`` (before the gate) to every later layer.
+  state.  Where ``ops/selscan.py:kernel_applies`` says so (a TPU, whole
+  blocks of tokens and lane tiles of channels: read off the input) the
+  scope ``sel_scan`` (softplus, recurrence, skip, gate) is that module's
+  Pallas kernel pair, which keeps the ``[N, tile]`` state and a block's
+  states in VMEM, forward and backward; everywhere else it is
+  :func:`selective_scan`, in chunks under a checkpoint in plain XLA.
+  Layer ``half`` hands its scan's result ``m`` (before the gate) to
+  every later layer.
 - ``window`` and ``full``, differential attention (two softmax maps a
   head pair, subtracted, a norm over the pair's 128-wide result) with
   64-wide queries and keys against a 128-wide value: a block of queries
@@ -32,10 +38,14 @@ beside the residual stream.
 
 Precision: parameters float32; matrix products in ``compute_dtype`` with
 float32 accumulation; softmax statistics, ``lam``, both norms'
-statistics, ``dt``, ``A`` and the state recurrence in float32.
+statistics, ``dt``, ``A``, the state recurrence, ``y``, the skip and
+the gate in float32, on the kernel's path as on the XLA path (the kernel
+reads ``x``, ``z``, ``B``, ``C`` and writes ``gated`` and ``m`` in
+``compute_dtype`` exactly where the mixer casts).
 
 The trace-time tallies of :data:`TRACED` say which attention pattern and
-how many scans the compiled program holds (the model has no
+how many scans the compiled program holds, and ``ops/selscan.py:TRACED``
+how many of the scans went through the kernel (the model has no
 data-dependent event to count, so no counter rides its state).
 """
 from __future__ import annotations
@@ -48,7 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import seq
+from ..ops import selscan, seq
 from ..ops.layers import linear
 
 F32 = jnp.float32
@@ -183,12 +193,21 @@ def mamba_mixer(p, u, dm: dict, cd):
         rbc = linear(xs, p["x_proj"].astype(cd))
         dt = jnp.matmul(rbc[..., :r], p["dt_proj"].astype(cd),
                         preferred_element_type=F32)
+    kernel = selscan.kernel_applies(xs.shape[1], xs.shape[2], n,
+                                    jnp.dtype(cd).itemsize)
+    selscan.TRACED["kernel" if kernel else "xla"] += 1
     with jax.named_scope("sel_scan"):
-        dt = jax.nn.softplus(dt + p["dt_bias"])
-        a = -jnp.exp(p["A_log"]).T
-        y = selective_scan(xs, dt, a, rbc[..., r:r + n], rbc[..., r + n:],
-                           SCAN_CHUNK) + p["D"] * xs.astype(F32)
-        gated = (y * jax.nn.silu(z.astype(F32))).astype(cd)
+        if kernel:
+            gated, y = selscan.selscan(
+                xs, z, dt, p["dt_bias"], -jnp.exp(p["A_log"]).T,
+                rbc[..., r:r + n], rbc[..., r + n:], p["D"])
+        else:
+            dt = jax.nn.softplus(dt + p["dt_bias"])
+            a = -jnp.exp(p["A_log"]).T
+            y = selective_scan(xs, dt, a, rbc[..., r:r + n],
+                               rbc[..., r + n:], SCAN_CHUNK) \
+                + p["D"] * xs.astype(F32)
+            gated = (y * jax.nn.silu(z.astype(F32))).astype(cd)
     with jax.named_scope("ssm_proj"):
         return linear(gated, p["out_proj"].astype(cd)), y.astype(cd)
 
@@ -436,8 +455,9 @@ def _self_check(config_file: str) -> None:
         runs.append(("published widths", published))
     for tag, config in runs:
         init, apply, (vocab, t) = build(config)
-        for k in TRACED:
-            TRACED[k] = 0
+        for tally in (TRACED, selscan.TRACED):
+            for k in tally:
+                tally[k] = 0
         ids = np.asarray(jax.random.randint(jax.random.key(1), (2, t), 0,
                                             vocab), np.int32)
         shape = jax.eval_shape(
@@ -464,7 +484,8 @@ def _self_check(config_file: str) -> None:
               f"V={vocab} parameters={n} logits={list(shape)} loss={loss:.4f}"
               f" (ln V = {math.log(vocab):.4f}) finite={finite} first step "
               f"{time.monotonic() - t0:.1f}s with its compile (smoke timing)"
-              f" traced={TRACED}", flush=True)
+              f" traced={TRACED} scans through={selscan.TRACED}",
+              flush=True)
         if not (finite and math.isfinite(loss)
                 and abs(loss - math.log(vocab)) < 1.0):
             raise RuntimeError(f"sambay ({tag}): loss {loss}, finite "

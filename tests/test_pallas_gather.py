@@ -284,3 +284,33 @@ def test_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
     # The entering states are 537 MB, float32 y 268 MB, the rows' layout
     # of dt and its cumulative sum and their cotangents a few MB each.
     assert compiled.memory_analysis().temp_size_in_bytes < 1100 * 2**20
+
+
+def test_selscan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
+                                                            monkeypatch):
+    """The second token cell's Mamba-1 selective scan (ops/selscan.py; here
+    because the described chip's fixture lives in this file only): 2
+    sequences of 8,192 tokens, 5,120 channels, state 16 in bf16 at the
+    module's constants, forward and backward with a cotangent on both
+    results, through Mosaic: both kernels are in the program, within the
+    VMEM limit the module sets, and what is left for HBM is the states
+    entering the blocks (42 MB), the two results and the partial sums,
+    not a block's states (84 MB a block of 128 tokens in the XLA form)."""
+    from ddp_tpu.ops import selscan
+    monkeypatch.setattr(selscan, "_use_pallas", lambda: True)
+    bsz, t, ch, n = 2, 8192, 5120, 16
+    assert selscan.kernel_applies(t, ch, n, 2)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    args = (spec((bsz, t, ch)), spec((bsz, t, ch)), spec((bsz, t, ch), f32),
+            spec((ch,), f32), spec((n, ch), f32), spec((bsz, t, n)),
+            spec((bsz, t, n)), spec((ch,), f32))
+    compiled = jax.jit(selscan._vjp_of(selscan.selscan)).lower(
+        args, (spec((bsz, t, ch)), spec((bsz, t, ch)))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "selscan_fwd" in text and "selscan_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20

@@ -671,14 +671,17 @@ def test_classifier_processes_never_import_the_token_models():
     assert out.stdout.strip().splitlines()[-1] == "SEEN []"
 
 
-def test_sambay_alone_imports_no_kernel():
-    """The model itself needs no Pallas either: ``ops/seq.py`` is plain
-    XLA."""
+def test_sambay_alone_imports_its_own_kernel_only():
+    """The model brings its selective scan's kernels (``ops/selscan.py``,
+    and so Pallas) and nothing of ``nemotron_h``'s: neither that model nor
+    its attention and scan kernels; ``ops/seq.py`` is plain XLA."""
     code = ("import sys; import ddp_tpu.models.sambay; print('SEEN', sorted("
-            "m for m in sys.modules if m in ('jax.experimental.pallas', "
-            "'ddp_tpu.ops.attention', 'ddp_tpu.ops.ssd')))")
+            "m for m in sys.modules if m in ('ddp_tpu.ops.selscan', "
+            "'ddp_tpu.models.nemotron_h', 'ddp_tpu.ops.attention', "
+            "'ddp_tpu.ops.ssd')))")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
-    assert out.stdout.strip().splitlines()[-1] == "SEEN []"
+    assert out.stdout.strip().splitlines()[-1] \
+        == "SEEN ['ddp_tpu.ops.selscan']"
