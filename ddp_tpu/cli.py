@@ -48,15 +48,17 @@ def build_parser(description: str) -> argparse.ArgumentParser:
     # Framework extensions (all default to reference behavior).
     p.add_argument("--model", default="vgg",
                    choices=["vgg", "deepnn", "resnet18", "tinylm", "nemotron_h",
-                            "sambay"],
+                            "sambay", "glm4_moe_lite"],
                    help="Model to train (reference trains VGG)")
     p.add_argument("--model_config", default=None, metavar="FILE",
                    help="Configuration file (JSON) of a model that is "
-                        "built from one: --model nemotron_h and --model "
-                        "sambay read their layers, widths and the share "
-                        "held here from it, e.g. benchmark/configs/"
-                        "nemotron3_nano_30b_a3b_ep16.json and "
-                        "phi4_mini_flash_stage14_19.json.  With "
+                        "built from one: --model nemotron_h, --model "
+                        "sambay and --model glm4_moe_lite read their "
+                        "layers, widths and the share held here from it, "
+                        "e.g. benchmark/configs/"
+                        "nemotron3_nano_30b_a3b_ep16.json, "
+                        "phi4_mini_flash_stage14_19.json and "
+                        "glm47_flash_ep8.json.  With "
                         "--synthetic such a model trains on the seeded "
                         "token generator (data/tokens.py) at the file's "
                         "seq_len")

@@ -31,7 +31,8 @@ _MODULE_MODELS = {
 }
 # name -> module with ``build(config) -> (init, apply, tokens)``: models
 # built from a configuration file (``--model_config``).
-_CONFIG_MODELS = {"nemotron_h": "nemotron_h", "sambay": "sambay"}
+_CONFIG_MODELS = {"nemotron_h": "nemotron_h", "sambay": "sambay",
+                  "glm4_moe_lite": "glm4_moe_lite"}
 MODEL_NAMES = tuple(_MODULE_MODELS) + tuple(_CONFIG_MODELS)
 
 

@@ -22,8 +22,10 @@ character of ``hybrid_override_pattern``:
   ``num_experts_per_tok`` largest ``s + b`` chosen; this chip computes
   the shared expert and the weighted results of the chosen experts it
   holds (``experts_held = [first, count]``), a tile of rows at a time over
-  row tiles sorted by expert.  Dropless while the held experts' load is
-  within ``MOE_LOAD_HEADROOM`` times what uniform routing sends here;
+  row tiles sorted by expert: the layer of models/moe.py, which
+  ``glm4_moe_lite`` shares, with this model's expert (two matrices around
+  ``relu2``).  Dropless while the held experts' load is within
+  ``moe.MOE_LOAD_HEADROOM`` times what uniform routing sends here;
   ``dropped`` counts the rest.
 
 Precision: parameters float32; matrix products in ``compute_dtype`` with
@@ -48,13 +50,11 @@ from jax import lax
 
 from ..ops import attention, seq, ssd
 from ..ops.layers import linear
+from ..ops.seq import rms_norm
+from . import moe
 
 F32 = jnp.float32
 ATTN_QUERY_BLOCK = 1024
-MOE_ROW_TILE = 512
-# The expert layer's row buffer holds this many times the load that
-# uniform routing sends to the experts held here.
-MOE_LOAD_HEADROOM = 5
 
 
 def dims(config: dict) -> dict:
@@ -101,16 +101,6 @@ def dims(config: dict) -> dict:
 
 def layer_name(i: int) -> str:
     return f"layer_{i:02d}"
-
-
-def rms_norm(x, weight, eps: float, out_dtype):
-    xf = x.astype(F32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * weight).astype(out_dtype)
-
-
-def relu2(x):
-    return jnp.square(jnp.maximum(x, 0))
 
 
 # -- M: Mamba-2 ---------------------------------------------------------------
@@ -269,110 +259,10 @@ def attention_mixer(p, x, dm: dict, cd):
 
 # -- E: experts ---------------------------------------------------------------
 
-def route_weights(s, e_bias, dm: dict):
-    """The chosen experts and their weights: the ``top_k`` largest ``s +
-    b``; weights are ``s`` itself (without ``b``), over their sum, times
-    the scaling factor.  ``s`` [N,router] float32 -> (idx, w) [N,top_k]."""
-    _, idx = lax.top_k(s + e_bias, dm["top_k"])
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if dm["norm_topk"]:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx, w * dm["scale"]
-
-
-def shared_expert(p, x, cd):
-    return linear(relu2(linear(x, p["shared_up"].astype(cd))),
-                  p["shared_down"].astype(cd))
-
-
-def row_plan(key, count: int, tile: int, tiles: int):
-    """Which assignment each row holds, in a buffer of ``tiles`` tiles of
-    ``tile`` rows in which every held expert's rows start on a tile's
-    edge.  ``key`` [A]: the held expert of an assignment, ``count`` where
-    it is held elsewhere.  Returns ``sizes`` [count] (assignments to each
-    held expert), ``src`` [tiles * tile] (the assignment of a row; ``A``
-    for a row of padding), ``tile_expert`` [tiles] and ``dropped`` (held
-    here and no room)."""
-    n, cap = key.shape[0], tiles * tile
-    sizes = jnp.sum(key[:, None] == jnp.arange(count), axis=0,
-                    dtype=jnp.int32)
-    padded = (sizes + tile - 1) // tile * tile
-    ends = jnp.cumsum(padded)
-    # Sorted by expert (stably: by token inside an expert), an assignment's
-    # rank among its expert's is its rank less the ranks before the expert.
-    order = jnp.argsort(key, stable=True)
-    key_s = key[order]
-    e = jnp.minimum(key_s, count - 1)
-    row = (ends - padded)[e] + jnp.arange(n) - (jnp.cumsum(sizes) - sizes)[e]
-    placed = (key_s < count) & (row < cap)
-    src = jnp.full((cap + 1,), n, jnp.int32).at[
-        jnp.where(placed, row, cap)].set(order)[:cap]
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        ends, jnp.arange(tiles) * tile, side="right"), count - 1)
-    dropped = jnp.sum((key_s < count) & (row >= cap), dtype=jnp.int32)
-    return sizes, src, tile_expert, dropped
-
-
 def expert_mixer(p, st, x, dm: dict, cd, *, train: bool):
-    """Returns ``(y, new layer state)``.  The held experts' products run
-    over a buffer of row tiles, each tile one expert's (:func:`row_plan`),
-    a tile at a time with its expert's weights: every tile is computed
-    whether rows fell on it or not, so a step's time does not follow the
-    routers' load, and padding rows are zeros that pass through ``relu2``
-    and both products as zeros (no mask: ``jax.lax.ragged_dot``, which
-    this replaced, leaves the rows past its groups as it finds them, and
-    made the step's time follow the seed: PERF.md, findings of PR 28).
-
-    The buffer holds ``MOE_LOAD_HEADROOM`` times the rows that uniform
-    routing sends here (``top_k * count / router`` a token; never more
-    than ``min(top_k, count)`` a token, which is every assignment that can
-    fall on a held expert) plus a tile an expert for the edges: no
-    assignment is dropped while the held experts' load is within that,
-    whatever its split among them, and ``dropped`` counts those that
-    found no room beyond it.  The buffer's size is memory AND time: its
-    rows are gathered, multiplied and scattered back whether they hold a
-    token or not (the worst case, 6 rows a token where uniform routing
-    sends 0.375, would triple the layer's time)."""
-    bsz, t, d = x.shape
-    n_tok, k = bsz * t, dm["top_k"]
-    first, count, tile = dm["first"], dm["count"], MOE_ROW_TILE
-    rows = n_tok * min(MOE_LOAD_HEADROOM * k * count / dm["router"],
-                       min(k, count))
-    tiles = -(-int(rows) // tile) + count
-    xf = x.reshape(n_tok, d)
-    with jax.named_scope("moe_route"):
-        s = jax.nn.sigmoid(jnp.matmul(xf.astype(F32), p["router"],
-                                      precision=lax.Precision.HIGHEST))
-        idx, w = route_weights(s, st["e_bias"], dm)
-        held = (idx >= first) & (idx < first + count)
-        sizes, src, tile_expert, dropped = row_plan(
-            jnp.where(held, idx - first, count).reshape(-1), count, tile,
-            tiles)
-        # Assignment a is slot a % k of token a // k; a row of padding
-        # reads the zero row and weighs nothing.
-        token = src // k
-        rows = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])[token]
-        row_w = jnp.concatenate([w.reshape(-1), jnp.zeros((1,), w.dtype)])[
-            src]
-    with jax.named_scope("moe_experts"):
-        up, down = p["up"].astype(cd), p["down"].astype(cd)
-        out = lax.map(
-            lambda a: jnp.dot(relu2(jnp.dot(a[0], up[a[1]])), down[a[1]]),
-            (rows.reshape(tiles, tile, d), tile_expert))
-    with jax.named_scope("moe_route"):
-        # Back to token order: each row adds its weighted result to its
-        # token (padding to the row past the tokens' end).
-        routed = jnp.zeros((n_tok + 1, d), F32).at[token].add(
-            out.reshape(tiles * tile, d).astype(F32) * row_w[:, None])[
-                :n_tok]
-    with jax.named_scope("moe_shared"):
-        y = shared_expert(p, xf, cd).astype(F32) + routed
-    new_st = st
-    if train:
-        new_st = {"e_bias": st["e_bias"],
-                  "assignments": st["assignments"] + sizes,
-                  "dropped": st["dropped"] + dropped}
-    return y.astype(cd).reshape(bsz, t, d), new_st
+    """The expert layer the token models share (models/moe.py), with this
+    model's expert: two matrices around ``relu2``."""
+    return moe.expert_layer(p, st, x, dm, cd, train=train, form=moe.RELU2)
 
 
 # -- the network ---------------------------------------------------------------

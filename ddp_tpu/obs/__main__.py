@@ -142,7 +142,7 @@ def main(argv: Optional[list] = None) -> int:
         return 0
     if args.prom is not None:
         from .registry import parse_exposition
-        from .routing import format_routing
+        from .routing import format_lm_loss, format_routing
         try:
             with open(args.prom) as f:
                 families = parse_exposition(f.read())
@@ -150,7 +150,8 @@ def main(argv: Optional[list] = None) -> int:
             print(f"cannot read exposition {args.prom}: {e}",
                   file=sys.stderr)
             return 2
-        print(format_routing(families))
+        print("\n".join(filter(None, (format_routing(families),
+                                      format_lm_loss(families)))))
         return 0
     if not args.spill:
         p.error("a spill file is required (or use --postmortem / --prom)")

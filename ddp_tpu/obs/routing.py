@@ -16,7 +16,9 @@ Exported through the run's registry (obs/registry.py):
 
 ``expert`` is the index among the experts held here (the router's id is
 ``experts_held[0]`` more).  ``python -m ddp_tpu.obs --prom FILE`` prints
-them from a run's ``.prom`` file.
+them from a run's ``.prom`` file, and below them the losses of a model
+with more than one prediction depth (``ddp_lm_loss{depth}``, a gauge the
+Trainer sets where it flushes losses: :func:`format_lm_loss`).
 """
 from __future__ import annotations
 
@@ -107,3 +109,12 @@ def format_routing(families: dict) -> str:
                      f"{load_max_over_mean(counts):>8.2f}  "
                      + " ".join(map(str, counts)))
     return "\n".join(lines)
+
+
+def format_lm_loss(families: dict) -> str:
+    """``ddp_lm_loss{depth}`` of a parsed exposition, a depth a line;
+    empty where the run's model had one depth."""
+    by_depth = sorted((int(dict(labels)["depth"]), v) for (_n, labels), v in
+                      families.get("ddp_lm_loss", {}).get("samples",
+                                                          {}).items())
+    return "\n".join(f"ddp_lm_loss depth {d}: {v:.5f}" for d, v in by_depth)

@@ -342,21 +342,77 @@ def _ms(fn, *args, reps: int = 5) -> float:
     return 1e3 * (time.perf_counter() - t0) / reps
 
 
+# (pairs, query heads a pair, tokens, head width) the self-check runs: the
+# first token cell's grouped shape, and the latent-attention cell's (every
+# query head its own 256-wide keys and values: 2 sequences x 20 heads).
+# The second of each pair is what a process without a TPU runs instead,
+# through the interpreter.
+SELF_CHECK_SHAPES = (((4, 16, 8192, 128), (2, 4, 1024, 128)),
+                     ((40, 1, 8192, 256), (2, 1, 1024, 256)))
+
+
 def _self_check() -> None:
-    """On a TPU, at the token cell's shape (4 pairs, 16 heads a pair,
-    8,192 tokens, 128 a head, bf16): each path's distance from the
-    float32 answer (output and the three gradients), milliseconds forward
-    and forward plus backward for the kernel, the XLA loop and the shipped
-    kernel, and the block sweep.  Elsewhere: a small shape (two query
-    blocks of two key tiles) through the interpreter, distances only.  Raises where the kernel is further from
-    float32 than the XLA loop by more than a quarter."""
+    """On a TPU, at each cell's shape (:data:`SELF_CHECK_SHAPES`, bf16):
+    each path's distance from the float32 answer (output and the three
+    gradients) and milliseconds forward and forward plus backward for the
+    kernel and the XLA loop; at the first shape also the shipped kernel
+    and the block sweep.  Elsewhere: small shapes (two query blocks of two
+    key tiles) through the interpreter, distances only.  Raises where the
+    kernel is further from float32 than the XLA loop by more than a
+    quarter, and where a model's mixer does not take the kernel at its
+    cell's shape on a TPU."""
     from ..parallel.mesh import make_mesh
     from ..utils.platform import device_line, enable_compile_cache
 
     enable_compile_cache()
     print(device_line(make_mesh()), flush=True)
     on_chip = _use_pallas()
-    p, r, t, hd = (4, 16, 8192, 128) if on_chip else (2, 4, 1024, 128)
+    for i, shapes in enumerate(SELF_CHECK_SHAPES):
+        _check_shape(*shapes[0 if on_chip else 1], on_chip=on_chip,
+                     yardsticks=on_chip and i == 0)
+    # The mixers themselves at these shapes: which path they are traced
+    # through.
+    from ..models import glm4_moe_lite
+    from ..models.nemotron_h import attention_mixer
+    cd = jnp.bfloat16
+    (p, r, t, hd), (p2, _, t2, hd2) = (s[0 if on_chip else 1]
+                                       for s in SELF_CHECK_SHAPES)
+    heads, d = 2 * r, 256
+    weights = {name: jax.ShapeDtypeStruct(shape, F32) for name, shape in (
+        ("q", (d, heads * hd)), ("k", (d, 2 * hd)), ("v", (d, 2 * hd)),
+        ("o", (heads * hd, d)))}
+    jax.eval_shape(
+        lambda w, x: attention_mixer(
+            w, x, {"heads": heads, "kv_heads": 2, "head_dim": hd}, cd),
+        weights, jax.ShapeDtypeStruct((p // 2, t, d), cd))
+    print(f"attention: mixer traced through {TRACED}", flush=True)
+    if on_chip and TRACED != {"kernel": 1, "xla": 0}:
+        raise RuntimeError("attention_mixer did not take the kernel at the "
+                           "token cell's shape on a TPU")
+    dm = {"heads": p2 // 2, "nope": hd2 - 64, "rope": 64, "qk": hd2,
+          "v": hd2, "q_rank": 96, "kv_rank": 64, "eps": 1e-5}
+    weights = {name: jax.ShapeDtypeStruct(shape, F32) for name, shape in (
+        ("q_a", (d, 96)), ("q_norm", (96,)), ("q_b", (96, p2 // 2 * hd2)),
+        ("kv_a", (d, 64 + 64)), ("kv_norm", (64,)),
+        ("kv_b", (64, p2 // 2 * (2 * hd2 - 64))), ("o", (p2 // 2 * hd2, d)))}
+    jax.eval_shape(
+        lambda w, x: glm4_moe_lite.mla(
+            w, x, dm, cd, *glm4_moe_lite.rope_angles(t2, 64, 1e6)),
+        weights, jax.ShapeDtypeStruct((2, t2, d), cd))
+    print(f"attention: mla traced through {glm4_moe_lite.TRACED}",
+          flush=True)
+    if on_chip and glm4_moe_lite.TRACED["core_kernel"] != 1:
+        raise RuntimeError("glm4_moe_lite.mla did not take the kernel at "
+                           "the latent-attention cell's shape on a TPU")
+    print(f"attention: ok kernel={'pallas' if on_chip else 'interpret'} "
+          + " ".join(f"P={a} R={b} T={c} hd={e}" for a, b, c, e in (
+              s[0 if on_chip else 1] for s in SELF_CHECK_SHAPES))
+          + " within a quarter of the XLA path's distance from float32",
+          flush=True)
+
+
+def _check_shape(p: int, r: int, t: int, hd: int, *, on_chip: bool,
+                 yardsticks: bool) -> None:
     cd, scale = jnp.bfloat16, 1.0 / math.sqrt(hd)
     keys = jax.random.split(jax.random.key(0), 4)
     q, w = (jax.random.normal(key, (p, r, t, hd), F32)
@@ -381,6 +437,7 @@ def _self_check() -> None:
     dist = {name: [_rel(a, b) for a, b in zip(jax.jit(vjp_of(fn))(*low),
                                               exact)]
             for name, fn in forward.items()}
+    del exact
     print("attention: distance from float32     o         dq        dk"
           "        dv")
     for name, d in dist.items():
@@ -392,41 +449,27 @@ def _self_check() -> None:
             raise RuntimeError(
                 f"causal_gqa's {what} is {got:.5f} from the float32 answer, "
                 f"the XLA path {ref:.5f}: further by more than a quarter")
-
-    if on_chip:
+    if not on_chip:
+        return
+    if yardsticks:
         forward["shipped"] = _shipped(r, t, scale, FWD_BLOCKS, BWD_BLOCKS)
-        print("attention: ms            forward  forward+backward")
-        for name, fn in forward.items():
-            print(f"attention:   {name:<8}{_ms(jax.jit(fn), *low[:3]):11.2f}"
-                  f"{_ms(jax.jit(vjp_of(fn)), *low):11.2f}", flush=True)
-        _, res = jax.jit(_causal_gqa_fwd, static_argnums=(3, 4))(
-            *low[:3], scale, False)
-        print("attention: sweep (query block, key tile): ms forward, "
-              "ms backward")
-        for blocks in SWEEP:
-            f = jax.jit(functools.partial(
-                _forward, scale=scale, blocks=blocks, interpret=False))
-            b = jax.jit(functools.partial(
-                _backward, scale=scale, blocks=blocks, interpret=False))
-            print(f"attention:   {blocks!s:<14}{_ms(f, *low[:3]):9.2f}"
-                  f"{_ms(b, *res, low[3]):9.2f}", flush=True)
-    # The mixer itself at this shape: which path it is traced through.
-    from ..models.nemotron_h import attention_mixer
-    heads, d = 2 * r, 256
-    weights = {name: jax.ShapeDtypeStruct(shape, F32) for name, shape in (
-        ("q", (d, heads * hd)), ("k", (d, 2 * hd)), ("v", (d, 2 * hd)),
-        ("o", (heads * hd, d)))}
-    jax.eval_shape(
-        lambda w, x: attention_mixer(
-            w, x, {"heads": heads, "kv_heads": 2, "head_dim": hd}, cd),
-        weights, jax.ShapeDtypeStruct((p // 2, t, d), cd))
-    print(f"attention: mixer traced through {TRACED}", flush=True)
-    if on_chip and TRACED != {"kernel": 1, "xla": 0}:
-        raise RuntimeError("attention_mixer did not take the kernel at the "
-                           "token cell's shape on a TPU")
-    print(f"attention: ok kernel={'pallas' if on_chip else 'interpret'} "
-          f"P={p} R={r} T={t} hd={hd} within a quarter of the XLA path's "
-          f"distance from float32", flush=True)
+    print("attention: ms            forward  forward+backward")
+    for name, fn in forward.items():
+        print(f"attention:   {name:<8}{_ms(jax.jit(fn), *low[:3]):11.2f}"
+              f"{_ms(jax.jit(vjp_of(fn)), *low):11.2f}", flush=True)
+    if not yardsticks:
+        return
+    _, res = jax.jit(_causal_gqa_fwd, static_argnums=(3, 4))(
+        *low[:3], scale, False)
+    print("attention: sweep (query block, key tile): ms forward, "
+          "ms backward")
+    for blocks in SWEEP:
+        f = jax.jit(functools.partial(
+            _forward, scale=scale, blocks=blocks, interpret=False))
+        b = jax.jit(functools.partial(
+            _backward, scale=scale, blocks=blocks, interpret=False))
+        print(f"attention:   {blocks!s:<14}{_ms(f, *low[:3]):9.2f}"
+              f"{_ms(b, *res, low[3]):9.2f}", flush=True)
 
 
 if __name__ == "__main__":

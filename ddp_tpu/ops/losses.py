@@ -2,7 +2,7 @@
 reduction (singlegpu.py:105)."""
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,3 +52,60 @@ def cross_entropy_sum_count(logits: jax.Array, labels: jax.Array,
         return ce.sum(), jnp.asarray(ce.shape[0], jnp.float32)
     maskf = mask.astype(jnp.float32)
     return (ce * maskf).sum(), maskf.sum()
+
+
+# The float leaf of a model's state that the loss core fills with each
+# prediction depth's own mean loss (train/step.py), so that they reach the
+# host where the Trainer flushes losses and no step pays a device read.
+LM_LOSS = "lm_loss"
+
+
+class DepthLogits(NamedTuple):
+    """What a model with more than one prediction depth yields in place of
+    logits when it trains: each depth's output after its final norm
+    (``hidden[k]`` ``[B,T,d]``), the ONE head they share (``[d,V]``) and
+    each depth's weight in the loss.  Depth ``k`` at position ``i``
+    predicts the token ``k + 1`` further on, so it is scored against the
+    labels shifted by ``k`` (:func:`shift_labels`).  The head stays out of
+    the model so that the loss core can take a depth's head and loss at a
+    time (:func:`depth_cross_entropy`): ``[B,T,V]`` float32 logits exist
+    for one depth at once."""
+    hidden: Tuple[jax.Array, ...]
+    head: jax.Array
+    weights: Tuple[float, ...]
+
+    def logits(self, depth: int) -> jax.Array:
+        return jnp.matmul(self.hidden[depth], self.head,
+                          preferred_element_type=jnp.float32)
+
+
+def shift_labels(labels: jax.Array, k: int) -> jax.Array:
+    """Per-position labels ``[B,T]`` moved ``k`` positions earlier; the
+    last ``k`` positions have none and read :data:`IGNORE`."""
+    if k == 0:
+        return labels
+    return jnp.concatenate(
+        [labels[:, k:], jnp.full_like(labels[:, :k], IGNORE)], axis=1)
+
+
+def depth_cross_entropy(out: DepthLogits, labels: jax.Array):
+    """``(sums [D], counts [D])``: :func:`cross_entropy_sum_count` of each
+    depth against its own shift of ``labels``.  A depth's head and loss
+    run under one checkpoint, a sequence at a time: its logits are made
+    again for the backward pass, never kept beside another depth's, and
+    float32 ``[T,V]`` of ONE sequence and its cotangent are what lives at
+    once (at 2 x 8,192 x 19,360 that is 2.4 GB less at the step's peak
+    than a batch's: PERF.md, findings of PR 35)."""
+    @jax.checkpoint
+    def one(hidden, head, labels):
+        def sequence(row):
+            return cross_entropy_sum_count(
+                jnp.matmul(row[0][None], head,
+                           preferred_element_type=jnp.float32),
+                row[1][None])
+        sums, counts = jax.lax.map(sequence, (hidden, labels))
+        return sums.sum(), counts.sum()
+
+    sums, counts = zip(*(one(h, out.head, shift_labels(labels, k))
+                         for k, h in enumerate(out.hidden)))
+    return jnp.stack(sums), jnp.stack(counts)

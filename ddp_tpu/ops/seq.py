@@ -1,7 +1,8 @@
-"""What the token models' mixers share, in plain XLA: the causal
+"""What the token models' mixers share, in plain XLA: RMSNorm
+(``models/nemotron_h.py``, ``models/glm4_moe_lite.py``), the causal
 depthwise convolution of a state-space mixer and the blocked keys-first
-attention loop (``models/nemotron_h.py`` where its kernel does not apply,
-``models/sambay.py`` always).  ``ops/__init__.py`` does not import this
+attention loop (``nemotron_h`` and ``glm4_moe_lite`` where the kernel does
+not apply, ``models/sambay.py`` always).  ``ops/__init__.py`` does not import this
 module: a classifier's process never sees it.
 """
 from __future__ import annotations
@@ -14,6 +15,13 @@ import jax.numpy as jnp
 from jax import lax
 
 F32 = jnp.float32
+
+
+def rms_norm(x, weight, eps: float, out_dtype):
+    """``x / rms(x) * weight``, the statistics in float32."""
+    xf = x.astype(F32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * weight).astype(out_dtype)
 
 
 def causal_conv_silu(x, w, b, cd):

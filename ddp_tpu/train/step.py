@@ -44,7 +44,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..optim import sgd as sgd_lib
-from ..ops.losses import cross_entropy_sum_count
+from ..ops.losses import (LM_LOSS, DepthLogits, cross_entropy_sum_count,
+                          depth_cross_entropy)
 from ..parallel.mesh import (DATA_AXIS, MODEL_AXIS, assemble_from_local,
                              batch_sharding, data_axis_size, mesh_size,
                              replicated_sharding, scan_unroll)
@@ -98,6 +99,17 @@ def make_loss_and_grads(model, compute_dtype=None, sync_bn: bool = False):
                     params, batch_stats,
                     _as_input(images, compute_dtype), train=True,
                     rng=rng, compute_dtype=compute_dtype)
+            # A model with more than one prediction depth: each depth's
+            # global mean over its own counted positions, weighted; the
+            # depths' own losses ride the model's state to the host.
+            if isinstance(logits, DepthLogits):
+                with jax.named_scope("lm_head"):
+                    sums, counts = depth_cross_entropy(logits, labels)
+                by_depth = (lax.psum(sums, DATA_AXIS)
+                            / lax.psum(counts, DATA_AXIS))
+                loss = jnp.dot(jnp.asarray(logits.weights, by_depth.dtype),
+                               by_depth)
+                return loss, {**new_stats, LM_LOSS: by_depth}
             # A token model's loss (logits [B,T,V] against per-position
             # labels, ignored positions left out of sum and count) is
             # named for the device trace; a classifier's keeps its names.

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.tracer import get_tracer
+from ..ops.losses import LM_LOSS
 from ..optim.sgd import SGDConfig, SGDState
 from ..parallel import dist
 from ..parallel.mesh import replicated_sharding
@@ -316,6 +317,17 @@ class Trainer:
         self.routing = RoutingCounters(
             registry, baseline=jax.device_get(_counters(
                 self.state.batch_stats)))
+        # A model with more than one prediction depth: the loss core leaves
+        # each depth's own loss in a float leaf of the model's state
+        # (ops/losses.py:LM_LOSS), which rides with the counters.
+        self.lm_loss: list = []
+        self._depth_losses = (isinstance(batch_stats, dict)
+                              and LM_LOSS in batch_stats)
+        self._lm_gauge = None
+        if self._depth_losses and registry is not None:
+            self._lm_gauge = registry.gauge(
+                "ddp_lm_loss", "The last flushed step's mean loss at each "
+                "prediction depth (0: the next token)", ("depth",))
         # Host-side mirror of state.step: reading the device scalar would
         # block on the in-flight epoch (the exact stall the deferred loss
         # read removes), and the step count per epoch is host-known.
@@ -693,8 +705,11 @@ class Trainer:
         # with its losses: copies, because the next step donates the state.
         # (Kept beside the losses, by the epoch's first step: the flush's
         # signature is a seam that fault drills wrap.)
+        riders = _counters(self.state.batch_stats)
+        if self._depth_losses:
+            riders[LM_LOSS] = self.state.batch_stats[LM_LOSS]
         self._pending_counters[start_step] = jax.tree_util.tree_map(
-            jnp.copy, _counters(self.state.batch_stats))
+            jnp.copy, riders)
         prev, self._pending_losses = (self._pending_losses,
                                       (epoch, start_step, stacked))
         if prev is not None:
@@ -714,6 +729,12 @@ class Trainer:
             (stacked, self._pending_counters.pop(start_step, None)))
         arr = (np.asarray(arr) if stacked is not None
                else np.zeros(0, np.float32))
+        if counters and LM_LOSS in counters:
+            # The epoch's last step's loss at each prediction depth.
+            self.lm_loss = np.asarray(counters.pop(LM_LOSS)).tolist()
+            if self._lm_gauge is not None:
+                for depth, value in enumerate(self.lm_loss):
+                    self._lm_gauge.labels(depth=str(depth)).set(value)
         if counters:
             self.routing.update(counters)
         losses = arr.tolist()

@@ -24,6 +24,7 @@ if ROOT not in sys.path:
 from benchmark import flops_seq  # noqa: E402
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from ddp_tpu.models import MODEL_NAMES, get_model  # noqa: E402
+from ddp_tpu.models import moe  # noqa: E402
 from ddp_tpu.models import nemotron_h as sysm  # noqa: E402
 from ddp_tpu.ops import attention, ssd  # noqa: E402
 
@@ -105,7 +106,7 @@ def test_matches_reference(pattern, path, cd, monkeypatch):
     monkeypatch.setattr(sysm, "ATTN_QUERY_BLOCK", 32)
     monkeypatch.setattr(ref, "QUERY_BLOCK", 48)
     # Several row tiles an expert, the last one of each part padding.
-    monkeypatch.setattr(sysm, "MOE_ROW_TILE", 16)
+    monkeypatch.setattr(moe, "MOE_ROW_TILE", 16)
     config, t = tiny(pattern), T
     monkeypatch.setattr(attention, "TRACED", {"kernel": 0, "xla": 0})
     monkeypatch.setattr(ssd, "TRACED", {"kernel": 0, "xla": 0})
@@ -190,8 +191,8 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     with jax.default_matmul_precision("highest"):
         uncut = jax.vmap(lambda row: ref.experts(
             p, st["e_bias"], row, ref.dims(whole)))(x)
-    shared = sysm.shared_expert(p, x.reshape(-1, 64), jnp.float32).reshape(
-        x.shape)
+    shared = moe.shared_expert(p, x.reshape(-1, 64), jnp.float32,
+                               moe.RELU2).reshape(x.shape)
     total = shared
     for first in (0, 4, 8, 12):
         share = tiny("E", experts_held=[first, 4])
@@ -230,7 +231,7 @@ def _zero(params, layer, leaf):
 
 
 def _fault_shared_dropped(mp, config, params, state):
-    mp.setattr(sysm, "shared_expert", lambda p, x, cd: jnp.zeros(
+    mp.setattr(moe, "shared_expert", lambda p, x, cd, form: jnp.zeros(
         (x.shape[0], p["shared_down"].shape[1]), cd))
     return config, params, state
 
@@ -251,7 +252,7 @@ def _fault_bias_not_in_choice(mp, config, params, state):
 def _fault_bias_in_weights(mp, config, params, state):
     def route_weights(s, e_bias, dm):
         return _ROUTE(s + e_bias, jnp.zeros_like(e_bias), dm)
-    mp.setattr(sysm, "route_weights", route_weights)
+    mp.setattr(moe, "route_weights", route_weights)
     return config, params, state
 
 
@@ -287,7 +288,7 @@ def _fault_bf16_recurrence(mp, config, params, state):
     return config, params, state
 
 
-_ROUTE, _GATED = sysm.route_weights, sysm.gated_norm
+_ROUTE, _GATED = moe.route_weights, sysm.gated_norm
 FAULTS = {
     "shared_expert_dropped": _fault_shared_dropped,
     "routed_scaling_factor_dropped": _fault_scale_dropped,
@@ -323,7 +324,7 @@ def test_planted_fault_fails_the_comparison(fault, monkeypatch):
 def _one_expert_wins(config, monkeypatch):
     """Expert 5 (held: 4..7) wins every token by its bias; the router's
     weights are left as they are."""
-    monkeypatch.setattr(sysm, "MOE_ROW_TILE", 16)
+    monkeypatch.setattr(moe, "MOE_ROW_TILE", 16)
     params, state = seeded(config)
     p, st = params["layers"]["layer_00"], state["layer_00"]
     st = dict(st, e_bias=jnp.zeros((16,)).at[5].set(10.0))
@@ -343,7 +344,7 @@ def test_dropless_when_every_token_goes_to_one_held_expert(
     assignment that can fall on a held expert (4 a token); at twice the
     uniform load's 1.5 rows a token it still holds a load of two rows a
     token of which one a token falls on ONE expert."""
-    monkeypatch.setattr(sysm, "MOE_LOAD_HEADROOM", headroom)
+    monkeypatch.setattr(moe, "MOE_LOAD_HEADROOM", headroom)
     y, y_ref, new = _one_expert_wins(tiny("E"), monkeypatch)
     assert int(new["dropped"]) == 0
     assert int(new["assignments"][1]) == 2 * T
@@ -354,7 +355,7 @@ def test_dropped_counts_the_assignments_that_found_no_room(monkeypatch):
     """A buffer of half a row a token (a third of the uniform load's 1.5)
     cannot hold a row a token: what found no room is counted, and adds
     nothing."""
-    monkeypatch.setattr(sysm, "MOE_LOAD_HEADROOM", 1 / 3)
+    monkeypatch.setattr(moe, "MOE_LOAD_HEADROOM", 1 / 3)
     y, y_ref, new = _one_expert_wins(tiny("E"), monkeypatch)
     routed_here = int(new["assignments"].sum())
     cap = (-(-int(2 * T * 0.5) // 16) + 4) * 16
@@ -371,7 +372,7 @@ def test_row_plan_places_every_assignment_once(load):
     key = {"even": np.arange(40) % 5,               # 4 = held elsewhere
            "one_expert": np.full(40, 2),
            "none_here": np.full(40, 4)}[load].astype(np.int32)
-    sizes, src, tile_expert, dropped = map(np.asarray, sysm.row_plan(
+    sizes, src, tile_expert, dropped = map(np.asarray, moe.row_plan(
         jnp.asarray(key), count, tile, tiles))
     here = np.flatnonzero(key < count)
     assert sizes.tolist() == [int((key == e).sum()) for e in range(count)]
