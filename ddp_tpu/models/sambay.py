@@ -24,10 +24,19 @@ matrix, no positional encoding.  The mixer by the PUBLISHED layer index
   every later layer.
 - ``window`` and ``full``, differential attention (two softmax maps a
   head pair, subtracted, a norm over the pair's 128-wide result) with
-  64-wide queries and keys against a 128-wide value: a block of queries
-  at a time, keys-first (ops/seq.py), the window layer against the key
-  blocks that its window touches only.  The ``full`` layer (``half +
-  1``) hands its keys and values on.
+  64-wide queries and keys against a 128-wide value.  Where
+  ``ops/attention.py:kernel_applies`` says so (a TPU, 64-wide maps beside
+  a 128-wide value, whole blocks of tokens: read off the input) the core
+  of all three patterns (``window``, ``full``, ``cross``) is that
+  module's Pallas kernel pair ``diff_attention``, which keeps a tile's
+  scores in VMEM, reads ``q``, ``k``, ``v`` as the projections wrote
+  them, never visits a key tile past the diagonal or before the window,
+  and subtracts and norms a block's float32 rows where they lie.
+  Everywhere else (the CPU, the tests' tiny shapes, a length that is not
+  whole blocks) it is :func:`_core_loop`: a block of
+  queries at a time, keys-first (ops/seq.py), the window layer against
+  the key blocks that its window touches only.  The ``full`` layer
+  (``half + 1``) hands its keys and values on.
 - ``gmu``, the gated memory unit ``(silu(u W1) * m) W2``, and ``cross``,
   differential attention of this layer's queries over layer ``half +
   1``'s keys and values.
@@ -39,14 +48,20 @@ beside the residual stream.
 Precision: parameters float32; matrix products in ``compute_dtype`` with
 float32 accumulation; softmax statistics, ``lam``, both norms'
 statistics, ``dt``, ``A``, the state recurrence, ``y``, the skip and
-the gate in float32, on the kernel's path as on the XLA path (the kernel
-reads ``x``, ``z``, ``B``, ``C`` and writes ``gated`` and ``m`` in
-``compute_dtype`` exactly where the mixer casts).
+the gate in float32, on the kernels' paths as on the XLA paths (the scan
+kernel reads ``x``, ``z``, ``B``, ``C`` and writes ``gated`` and ``m`` in
+``compute_dtype`` exactly where the mixer casts; forward the attention
+kernel casts each map's probabilities for their own value product and
+subtracts the float32 results, where the loop casts the maps' float32
+difference: the same sum).
 
 The trace-time tallies of :data:`TRACED` say which attention pattern and
-how many scans the compiled program holds, and ``ops/selscan.py:TRACED``
-how many of the scans went through the kernel (the model has no
-data-dependent event to count, so no counter rides its state).
+how many scans the compiled program holds and how many attention cores
+went through the kernel pair (``core_kernel``) and through the loop
+(``core_xla``), and ``ops/selscan.py:TRACED`` how many of the scans went
+through the kernel (the model has no data-dependent event to count, so no
+counter rides its state).  On the chip the cell's program reads
+``core_kernel`` 3, ``core_xla`` 0.
 """
 from __future__ import annotations
 
@@ -58,12 +73,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import selscan, seq
+from ..ops import attention, selscan, seq
 from ..ops.layers import linear
 
 F32 = jnp.float32
-# Queries a block of the attention loop: the window layer's is its window,
-# so that a block walks two key blocks; the global layers' as nemotron_h's.
+# Queries a block of the XLA attention loop: the window layer's is its
+# window, so that a block walks two key blocks; the global layers' as
+# nemotron_h's.  (The kernel's blocks are ops/attention.py's.)
 ATTN_QUERY_BLOCK = 1024
 # Tokens a chunk of the selective scan: a carried state a chunk is what
 # its backward pass keeps.  (Chunks of 256, and 8 or 16 steps unrolled in
@@ -71,7 +87,9 @@ ATTN_QUERY_BLOCK = 1024
 SCAN_CHUNK = 128
 _TN = (((0,), (0,)), ((), ()))
 
-TRACED = {"mamba": 0, "window": 0, "full": 0, "cross": 0, "gmu": 0}
+# Layers traced by kind, and which path took an attention layer's core.
+TRACED = {"mamba": 0, "window": 0, "full": 0, "cross": 0, "gmu": 0,
+          "core_kernel": 0, "core_xla": 0}
 
 
 def kind_of(l: int, half: int) -> str:
@@ -240,19 +258,17 @@ def _diff_block(q, k, v, lam, sub_norm, *, start: int, lo: int, window,
     return (o * (sub_norm * gain)).astype(cd).reshape(r, bq, v.shape[-1])
 
 
-def diff_core(p, q, k, v, l: int, dm: dict, cd, window: Optional[int]):
-    """``q`` [B,T,pairs,2,hd], ``k`` [B,T,kv_pairs,2,hd], ``v``
-    [B,T,kv_pairs,2hd] -> [B,T,pairs*2hd].  A (sequence, key-value pair)
-    at a time (a product batched over them is what XLA:TPU lowers to a
-    dilated convolution: PERF.md, findings of PR 28), a block of queries
-    at a time under its own checkpoint, against the keys from the block
-    that holds its window's first key on: the window layer never visits a
-    key block before that."""
+def _core_loop(q, k, v, lam, sub_norm, *, window: Optional[int], cd, **kw):
+    """The core in plain XLA: a (sequence, key-value pair) at a time (a
+    product batched over them is what XLA:TPU lowers to a dilated
+    convolution: PERF.md, findings of PR 28), a block of queries at a time
+    under its own checkpoint, against the keys from the block that holds
+    its window's first key on: the window layer never visits a key block
+    before that."""
     bsz, t, pairs, _, hd = q.shape
     kvp = k.shape[2]
     rep = pairs // kvp
     block = min(window, ATTN_QUERY_BLOCK) if window else ATTN_QUERY_BLOCK
-    lam = lam_of(p, l).astype(F32)
 
     def unit(args):
         q_u, k_u, v_u = args  # [2,R,T,hd], [2,T,hd], [T,2hd]
@@ -260,11 +276,9 @@ def diff_core(p, q, k, v, l: int, dm: dict, cd, window: Optional[int]):
         for s in range(0, t, block):
             lo, hi = (max(0, s - window) if window else 0), s + block
             f = jax.checkpoint(functools.partial(
-                _diff_block, start=s, lo=lo, window=window,
-                scale=1.0 / math.sqrt(hd), gain=1.0 - lam0_of(l),
-                eps=dm["eps"], cd=cd))
+                _diff_block, start=s, lo=lo, window=window, cd=cd, **kw))
             out.append(f(q_u[:, :, s:s + block], k_u[:, lo:hi],
-                         v_u[lo:hi], lam, p["sub_norm"]))
+                         v_u[lo:hi], lam, sub_norm))
         return jnp.concatenate(out, axis=1)  # [R,T,2hd]
 
     o = lax.map(unit, (
@@ -274,6 +288,37 @@ def diff_core(p, q, k, v, l: int, dm: dict, cd, window: Optional[int]):
         v.transpose(0, 2, 1, 3).reshape(bsz * kvp, t, 2 * hd)))
     return o.reshape(bsz, kvp, rep, t, 2 * hd).transpose(
         0, 3, 1, 2, 4).reshape(bsz, t, pairs * 2 * hd)
+
+
+def _core_kernel(q, k, v, lam, sub_norm, *, window: Optional[int],
+                 scale: float, gain: float, eps: float, cd,
+                 interpret: bool = False):
+    """The core through ``ops/attention.py:diff_attention``: the kernel
+    pair reads ``q``, ``k`` and ``v`` (in ``cd``, the result's type too)
+    as the projections wrote them (a pair's two maps are 128 lanes side by
+    side) and holds a block of rows of ``A1 V`` and ``A2 V`` in float32
+    where it subtracts and norms them; the gradients of ``lam`` and
+    ``sub_norm`` come back from the backward kernel."""
+    del cd
+    bsz, t = q.shape[:2]
+    return attention.diff_attention(
+        q.reshape(bsz, t, -1), k.reshape(bsz, t, -1), v.reshape(bsz, t, -1),
+        lam, sub_norm * gain, scale, eps, window, interpret)
+
+
+def diff_core(p, q, k, v, l: int, dm: dict, cd, window: Optional[int]):
+    """``q`` [B,T,pairs,2,hd], ``k`` [B,T,kv_pairs,2,hd], ``v``
+    [B,T,kv_pairs,2hd] -> [B,T,pairs*2hd]: the blocked Pallas kernel pair
+    where ``ops/attention.py:kernel_applies`` says so (a TPU, two 64-wide
+    maps beside a 128-wide value, whole blocks of tokens within the VMEM
+    budget: read off the input), the XLA loop everywhere else."""
+    t, hd = q.shape[1], q.shape[-1]
+    kernel = attention.kernel_applies(t, hd, jnp.dtype(cd).itemsize, 2 * hd)
+    TRACED["core_kernel" if kernel else "core_xla"] += 1
+    return (_core_kernel if kernel else _core_loop)(
+        q, k, v, lam_of(p, l).astype(F32), p["sub_norm"], window=window,
+        scale=1.0 / math.sqrt(hd), gain=1.0 - lam0_of(l), eps=dm["eps"],
+        cd=cd)
 
 
 def self_attention(p, u, l: int, dm: dict, cd, window: Optional[int]):
@@ -432,8 +477,10 @@ def _self_check(config_file: str) -> None:
     """One training step (``make_train_step``, SGD, bf16 compute, two
     sequences) of the stage the file holds: at a tiny width on any
     backend and, on a TPU, at the file's own widths and sequence length.
-    Checks the logits' shape, that the loss starts near ``ln V`` and that
-    loss and every updated parameter are finite.  Raises otherwise."""
+    Checks the logits' shape, that the loss starts near ``ln V``, that
+    loss and every updated parameter are finite and, at the file's widths,
+    that every attention core took the kernel pair; prints the step
+    program's tallies.  Raises otherwise."""
     import json
     import time
 
@@ -455,9 +502,6 @@ def _self_check(config_file: str) -> None:
         runs.append(("published widths", published))
     for tag, config in runs:
         init, apply, (vocab, t) = build(config)
-        for tally in (TRACED, selscan.TRACED):
-            for k in tally:
-                tally[k] = 0
         ids = np.asarray(jax.random.randint(jax.random.key(1), (2, t), 0,
                                             vocab), np.int32)
         shape = jax.eval_shape(
@@ -466,6 +510,10 @@ def _self_check(config_file: str) -> None:
         if shape != (2, t, vocab):
             raise RuntimeError(f"sambay ({tag}): logits {shape}, expected "
                                f"{(2, t, vocab)}")
+        # From here the tallies are the step program's own.
+        for tally in (TRACED, selscan.TRACED):
+            for k in tally:
+                tally[k] = 0
         step = make_train_step(ModelDef("sambay", init, apply, (vocab, t)),
                                SGDConfig(lr=0.01, momentum=0.9),
                                lambda s: 0.01, mesh,
@@ -490,6 +538,9 @@ def _self_check(config_file: str) -> None:
                 and abs(loss - math.log(vocab)) < 1.0):
             raise RuntimeError(f"sambay ({tag}): loss {loss}, finite "
                                f"parameters {finite}")
+        if tag != "tiny" and TRACED["core_xla"]:
+            raise RuntimeError(f"sambay ({tag}): {TRACED['core_xla']} "
+                               "attention cores took the XLA loop on a TPU")
         del state
     print(f"sambay: ok steps={len(runs)} "
           + " ".join(f"[{tag}]" for tag, _ in runs), flush=True)

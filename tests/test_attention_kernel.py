@@ -1,12 +1,16 @@
-"""The token model's attention kernel (ops/attention.py) on the CPU,
-through the Pallas interpreter at small shapes: against the XLA loop it
-replaces on the chip and against a dense float32 answer, forward and the
-three gradients; the sum over a key-value head's query heads; the tile
-classifier; a call inside ``shard_map``; who takes which path; and that
-the classifier cells' processes cannot see any of it.  (The kernels at
-the cell's shape through the TPU's compiler: tests/test_pallas_gather.py,
-where the described chip's fixture lives.)"""
+"""The token models' attention kernels (ops/attention.py) on the CPU,
+through the Pallas interpreter at small shapes: against the XLA loop they
+replace on the chip and against a dense float32 answer, forward and the
+gradients; the sum over a key-value head's query heads; the tile
+classifier, with and without a window; a call inside ``shard_map``; who
+takes which path; the two-map form (``diff_attention``: a 64-wide key pair
+beside a 128-wide value, a window) as ``models/sambay.py`` runs it; that
+``causal_gqa``'s kernels at the accepted cells' shapes are the parent's;
+and that the classifier cells' processes cannot see any of it.  (The
+kernels at the cell's shape through the TPU's compiler:
+tests/test_pallas_gather.py, where the described chip's fixture lives.)"""
 import functools
+import json
 import math
 import os
 import re
@@ -21,6 +25,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ddp_tpu.models import nemotron_h as sysm
+from ddp_tpu.models import sambay
 from ddp_tpu.ops import attention
 from ddp_tpu.ops.layers import linear
 from ddp_tpu.parallel.mesh import DATA_AXIS, make_mesh
@@ -258,6 +263,299 @@ def test_kernel_applies_at_the_cells_shape_and_the_mixer_takes_it(
     assert not attention.kernel_applies(8192 + 128, 128, 2)
 
 
+# -- two maps a pair, one value: models/sambay.py's cores --------------------------------
+
+DIFF_KW = dict(scale=1.0 / 8.0, gain=0.2, eps=1e-5)
+DIFF_NAMES = ("o", "dq", "dk", "dv", "dlam", "dnorm")
+
+
+def diff_operands(t, bsz=1, pairs=4, kvp=2, seed=3):
+    """``q, k, v, lam, sub_norm`` as ``sambay.diff_core`` holds them (a
+    pair's two 64-wide maps, a 128-wide value) and a cotangent."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return (jax.random.normal(ks[0], (bsz, t, pairs, 2, 64)),
+            jax.random.normal(ks[1], (bsz, t, kvp, 2, 64)),
+            jax.random.normal(ks[2], (bsz, t, kvp, 128)),
+            jnp.float32(0.79),
+            1.0 + 0.1 * jax.random.normal(ks[3], (128,)),
+            jax.random.normal(ks[4], (bsz, t, pairs * 128)))
+
+
+def diff_dense(q, k, v, lam, sub_norm, *, window, cd, scale, gain, eps):
+    """Float32, every map's whole [T,T] square at once."""
+    del cd
+    bsz, t, pairs, _, hd = q.shape
+    kvp = k.shape[2]
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("btgrmd,bsgmd->bgrmts",
+                       q.reshape(bsz, t, kvp, pairs // kvp, 2, hd), k) * scale
+        qi, si = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        seen = si <= qi
+        if window is not None:
+            seen = seen & (si > qi - window)
+        a = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("bgrts,bsgd->btgrd", a[:, :, :, 0] - lam * a[:, :, :, 1],
+                       v)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return (o * (sub_norm * gain)).reshape(bsz, t, pairs * 2 * hd)
+
+
+def diff_kernel(*a, **kw):
+    return sambay._core_kernel(*a, interpret=True, **kw)
+
+
+def diff_out_and_grads(path, window, cd, q, k, v, lam, sub_norm, do):
+    o, pull = jax.vjp(functools.partial(path, window=window, cd=cd,
+                                        **DIFF_KW), q, k, v, lam, sub_norm)
+    return (o,) + pull(do.astype(o.dtype))
+
+
+@pytest.fixture
+def diff_blocks(monkeypatch, request):
+    fwd, bwd = getattr(request, "param", ((128, 128), (128, 128)))
+    monkeypatch.setattr(attention, "DIFF_FWD_BLOCKS", fwd)
+    monkeypatch.setattr(attention, "DIFF_BWD_BLOCKS", bwd)
+    monkeypatch.setattr(sambay, "ATTN_QUERY_BLOCK", 128)
+    return fwd, bwd
+
+
+# A window smaller than, equal to and larger than a key tile, one that no
+# block divides, and the cell's 512 at its own blocks over 2,048 tokens.
+DIFF_CASES = [
+    (256, None, ((128, 128), (128, 128))), (384, None, ((128, 128),) * 2),
+    (512, None, UNEQUAL), (512, None, UNEQUAL[::-1]),
+    (512, 96, ((128, 128),) * 2), (512, 128, ((128, 128),) * 2),
+    (512, 200, ((128, 128),) * 2), (512, 384, UNEQUAL),
+    (512, 128, UNEQUAL[::-1]), (2048, 512, ((512, 512), (512, 512)))]
+
+
+@pytest.mark.parametrize("t,window,diff_blocks", DIFF_CASES,
+                         indirect=["diff_blocks"])
+def test_two_maps_float32_match_the_loop_and_the_dense_answer(
+        t, window, diff_blocks):
+    """``RMSNorm((A1 - lam A2) V)`` and its five gradients: the kernel
+    pair's path against ``_diff_block``'s loop and the dense answer."""
+    args = diff_operands(t, pairs=2, kvp=1) if t > 512 else diff_operands(t)
+    got = diff_out_and_grads(diff_kernel, window, jnp.float32, *args)
+    for name, g, loop, exact in zip(
+            DIFF_NAMES, got,
+            diff_out_and_grads(sambay._core_loop, window, jnp.float32, *args),
+            diff_out_and_grads(diff_dense, window, jnp.float32, *args)):
+        # lam's gradient is one nearly cancelling sum over everything.
+        tol = 1e-4 if name == "dlam" else 3e-6
+        assert g.dtype == jnp.float32 and g.shape == exact.shape
+        assert rel(g, exact) < tol, (name, rel(g, exact))
+        assert rel(g, loop) < tol, (name, rel(g, loop))
+
+
+@pytest.mark.parametrize("t,window,diff_blocks", [
+    (256, None, ((128, 128),) * 2), (512, None, UNEQUAL),
+    (512, 128, ((128, 128),) * 2), (512, 200, UNEQUAL[::-1])],
+    indirect=["diff_blocks"])
+def test_two_maps_bf16_are_no_further_from_float32_than_the_loop(
+        t, window, diff_blocks):
+    """Forward the kernel casts each map's probabilities apart where the
+    loop casts their difference; backward it subtracts them in float32 as
+    the loop does.  Neither is held to a number, only the kernel to the
+    loop (``lam``'s gradient, a nearly cancelling sum, to three times)."""
+    q, k, v, lam, sub_norm, do = diff_operands(t)
+    exact = diff_out_and_grads(diff_dense, window, jnp.float32, q, k, v, lam,
+                               sub_norm, do)
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (lam, sub_norm,
+                                                              do)
+    got = diff_out_and_grads(diff_kernel, window, jnp.bfloat16, *low)
+    loop = diff_out_and_grads(sambay._core_loop, window, jnp.bfloat16, *low)
+    for name, g, lo, ex in zip(DIFF_NAMES, got, loop, exact):
+        if name == "dlam":
+            assert max(rel(g, ex), rel(lo, ex)) < 0.02, (rel(g, ex),
+                                                         rel(lo, ex))
+        else:
+            assert 0 < rel(g, ex) < 1.25 * rel(lo, ex) < 0.02, \
+                (name, rel(g, ex), rel(lo, ex))
+    assert got[0].dtype == got[1].dtype == jnp.bfloat16
+
+
+def test_two_maps_dk_dv_are_summed_over_the_query_pairs(diff_blocks):
+    """One call over ``R`` query pairs against the unrepeated key-value
+    pair gives the sum of ``R`` one-pair calls' dK and dV, and each query
+    pair's own lanes of dQ."""
+    q, k, v, lam, w, do = diff_operands(256, pairs=2, kvp=1)
+    flat = tuple(a.reshape(1, 256, -1) for a in (q, k, v))
+
+    def grads(q, k, v, do):
+        _, pull = jax.vjp(lambda q, k, v: attention.diff_attention(
+            q, k, v, lam, w, 0.125, 1e-5, None, True), q, k, v)
+        return pull(do)
+
+    dq, dk, dv = grads(*flat, do)
+    halves = [grads(flat[0][..., h:h + 128], flat[1], flat[2],
+                    do[..., h:h + 128]) for h in (0, 128)]
+    assert rel(dq, jnp.concatenate([h[0] for h in halves], axis=-1)) < 1e-6
+    assert rel(dk, sum(h[1] for h in halves)) < 1e-6
+    assert rel(dv, sum(h[2] for h in halves)) < 1e-6
+    assert rel(dk, halves[0][1]) > 0.1
+
+
+@pytest.mark.parametrize("window", [100, 256, 512, 1000])
+@pytest.mark.parametrize("bq,bkv", attention.SWEEP + ((128, 128), (384, 256)))
+def test_tile_kinds_with_a_window_against_the_mask(bq, bkv, window):
+    """Skipped, clear and crossing tiles under a window, for every block
+    pair of the chip's sweep, against the mask written out; and that
+    :func:`_walk` visits exactly the tiles that hold a visible key, the
+    clear ones without a mask."""
+    t = 3072
+    qi, si = np.arange(t)[:, None], np.arange(t)[None, :]
+    mask = (si <= qi) & (si > qi - window)
+    for i in range(t // bq):
+        clear, visited, first, inside = attention.tile_kinds(i, bq, bkv,
+                                                             window)
+        assert (clear, visited) == attention.tile_kinds(i, bq, bkv)
+        walked = {}
+        attention_walk(i, bq, bkv, window, walked)
+        for j in range(t // bkv):
+            tile = mask[i * bq:(i + 1) * bq, j * bkv:(j + 1) * bkv]
+            want = ("clear" if tile.all() else
+                    "crossing" if tile.any() else "skipped")
+            kind = ("skipped" if j < first or j >= visited else
+                    "clear" if inside <= j < clear else "crossing")
+            assert kind == want, (i, j, kind, want)
+            assert walked.get(j, "skipped") == want, (i, j, walked)
+    traced = jax.jit(lambda i: attention.tile_kinds(i, bq, bkv, window))(
+        jnp.int32(1))
+    assert tuple(int(x) for x in traced) == attention.tile_kinds(1, bq, bkv,
+                                                                 window)
+
+
+def attention_walk(i, bq, bkv, window, walked):
+    """``_walk``'s loops run eagerly: which tiles, and whether masked."""
+    def step(j, masked):
+        assert int(j) not in walked
+        walked[int(j)] = "crossing" if masked else "clear"
+
+    with jax.disable_jit():
+        attention._walk(i, bq, bkv, step, window)
+
+
+def test_two_maps_inside_shard_map_with_check_vma(diff_blocks):
+    """The training step's setting: the data split over the mesh, ``lam``
+    and the norm's weight replicated, their gradients summed over it."""
+    args = diff_operands(256, bsz=2)
+    mesh = make_mesh(2)
+    run = functools.partial(diff_out_and_grads, diff_kernel, None,
+                            jnp.float32)
+    data, whole = P(DATA_AXIS), P()
+    specs = (data, data, data, whole, whole, data)
+    sharded = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=specs,
+        out_specs=(data, data, data, data, whole, whole), check_vma=True))
+    for name, g, want in zip(DIFF_NAMES, sharded(*args), jax.jit(run)(*args)):
+        assert g.shape == want.shape
+        assert rel(g, want) < (1e-5 if name == "dlam" else 1e-6), name
+
+
+def test_two_maps_malformed_operands_are_refused(diff_blocks):
+    q, k, v, lam, w, _ = diff_operands(256)
+    q, k, v = (a.reshape(1, 256, -1) for a in (q, k, v))
+    rest = (lam, w, 0.125, 1e-5, None, True)
+    with pytest.raises(ValueError, match="wants k and v"):
+        attention.diff_attention(q, k[:, :128], v, *rest)
+    with pytest.raises(ValueError, match="wants k and v"):
+        attention.diff_attention(q[..., :192], k, v, *rest)
+    with pytest.raises(ValueError, match="whole blocks"):
+        attention.diff_attention(q[:, :192], k[:, :192], v[:, :192], *rest)
+
+
+def test_kernel_applies_to_two_maps_beside_a_value(monkeypatch):
+    """At the real constants: the second token cell's shape (8,192 tokens,
+    64-wide keys beside a 128-wide value, bf16 and float32) passes, and so
+    do whole lanes of equal width as before; other pairings of widths, a
+    length that is no whole block, a budget too small and a process
+    without a TPU do not."""
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    assert attention.kernel_applies(8192, 64, 2, 128)
+    assert attention.kernel_applies(8192, 64, 4, 128)
+    assert attention.kernel_applies(8192, 128, 2, 128)
+    assert attention.kernel_applies(8192, 256, 2) \
+        == attention.kernel_applies(8192, 256, 2, 256)
+    assert not attention.kernel_applies(8192, 64, 2, 64)
+    assert not attention.kernel_applies(8192, 32, 2, 64)
+    assert not attention.kernel_applies(8192, 128, 2, 256)
+    assert not attention.kernel_applies(8192 + 128, 64, 2, 128)
+    assert not attention.kernel_applies(256, 8, 2, 16)       # the tiny preset
+    monkeypatch.setattr(attention, "VMEM_LIMIT_BYTES", 2**20)
+    assert not attention.kernel_applies(8192, 64, 2, 128)
+    monkeypatch.undo()
+    monkeypatch.setattr(attention, "_use_pallas", lambda: False)
+    assert not attention.kernel_applies(8192, 64, 2, 128)
+
+
+# -- causal_gqa at the accepted cells' shapes is the parent's ---------------------------
+
+GOLDEN = os.path.join(ROOT, "tests", "golden", "causal_gqa_kernels.json")
+# (pairs, query heads a pair, tokens, head width): the first token cell's
+# and the third's (attention.SELF_CHECK_SHAPES), in bf16.
+ACCEPTED_SHAPES = {"nemotron_h": (4, 16, 8192, 128),
+                   "glm4_moe_lite": (40, 1, 8192, 256)}
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def kernel_fingerprint(shape):
+    """What ``causal_gqa``'s two ``pallas_call`` sites hold at ``shape``,
+    forward and backward: each kernel's body as a jaxpr (which prints no
+    source location), its grid, every operand's block shape and index map,
+    the scratch (the body's trailing references) and the compiler's
+    parameters."""
+    p, r, t, hd = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((p, t, hd), jnp.bfloat16)
+
+    def run(q, k, v, do):
+        o, pull = jax.vjp(lambda q, k, v: attention.causal_gqa(
+            q, k, v, 1.0 / math.sqrt(hd)), q, k, v)
+        return (o,) + pull(do)
+
+    calls = {}
+    for eqn in _pallas_calls(jax.make_jaxpr(run)(q, kv, kv, q).jaxpr):
+        grid = eqn.params["grid_mapping"]
+        calls[str(eqn.params["name"])] = {
+            "grid": [int(g) for g in grid.grid],
+            "blocks": [[str(b.block_shape), str(b.index_map_jaxpr)]
+                       for b in grid.block_mappings],
+            "operands": [str(v.aval) for v in eqn.params["jaxpr"].invars],
+            "scratch_operands": int(grid.num_scratch_operands),
+            "results": [str(a) for a in eqn.params["out_avals"]],
+            "compiler_params": str(eqn.params["compiler_params"]),
+            "body": str(eqn.params["jaxpr"]),
+        }
+    return calls
+
+
+@pytest.mark.parametrize("model", sorted(ACCEPTED_SHAPES))
+def test_accepted_callers_kernels_are_the_parents(model):
+    """``nemotron_h``'s and ``glm4_moe_lite``'s cells run the kernels they
+    ran before the two-map form and the window arrived: body, grid, block
+    specs and scratch equal the golden written from the parent commit
+    (``python tests/test_attention_kernel.py`` rewrites it from whatever
+    ``ddp_tpu`` is on the path)."""
+    with open(GOLDEN) as f:
+        golden = json.load(f)[model]
+    got = kernel_fingerprint(ACCEPTED_SHAPES[model])
+    assert sorted(got) == sorted(golden) == ["causal_gqa_bwd",
+                                             "causal_gqa_fwd"]
+    for name in got:
+        for part, value in got[name].items():
+            assert value == golden[name][part], (name, part)
+
+
 # -- the classifier cells cannot see the change ------------------------------------------
 
 _CLASSIFIER_PROCESS = """
@@ -300,3 +598,11 @@ def test_classifier_processes_never_import_the_kernel():
                          timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip().splitlines()[-1] == "SEEN []"
+
+
+if __name__ == "__main__":
+    with open(GOLDEN, "w") as f:
+        json.dump({m: kernel_fingerprint(s) for m, s in
+                   ACCEPTED_SHAPES.items()}, f, indent=1, sort_keys=True)
+        f.write("\n")
+    print("wrote", GOLDEN, "from", attention.__file__)

@@ -253,6 +253,41 @@ def test_attention_kernels_compile_for_v5e_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
+@pytest.mark.parametrize("window", [None, 512], ids=["global", "window"])
+def test_two_map_attention_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, monkeypatch, window):
+    """The second token cell's differential attention cores
+    (ops/attention.py:diff_attention): 2 sequences x 8,192 tokens, 20
+    query pairs over 10 key-value pairs, two 64-wide maps beside a
+    128-wide value in bf16 at the module's block constants, with and
+    without the cell's window, norm and all, forward and backward, through
+    Mosaic: both kernels are in the program and HBM holds the two float32
+    results, the statistics and nothing the size of a map's scores."""
+    from ddp_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    bsz, t, pairs, kvp = attention.DIFF_SELF_CHECK_SHAPES[0]
+    assert attention.kernel_applies(t, 64, 2, 128)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def out_and_grads(q, k, v, lam, weight, do):
+        o, pull = jax.vjp(lambda *a: attention.diff_attention(
+            *a, 0.125, 1e-5, window), q, k, v, lam, weight)
+        return (o,) + pull(do)
+
+    compiled = jax.jit(out_and_grads).lower(
+        spec(bsz, t, pairs * 128), spec(bsz, t, kvp * 128),
+        spec(bsz, t, kvp * 128), spec(dtype=jnp.float32),
+        spec(128, dtype=jnp.float32), spec(bsz, t, pairs * 128)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "diff_attention_fwd" in text and "diff_attention_bwd" in text
+    # ``A1 V`` and ``A2 V`` in float32 are 168 MB each; one map's float32
+    # scores for one pair would be 268 MB more.
+    assert compiled.memory_analysis().temp_size_in_bytes < 640 * 2**20
+
+
 def test_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
                                                          monkeypatch):
     """The token cell's Mamba-2 scan (ops/ssd.py; here because the

@@ -5,8 +5,9 @@ selective scan in chunks; the window's edge; differential attention at
 ``lam = 0``; the hand-over's gradients; the vocabulary slice; planted
 faults; the Trainer's path; the parameter counts; the operation count;
 the configuration file; the rehearsed benchmark cell.  Also: what the two
-token models share lowers to the parent's text, and the classifiers'
-processes never import any of it."""
+token models share lowers to the parent's text, the attention cores take
+the kernel pair at the cell's shape and the parent's loop at the tiny
+preset's, and the classifiers' processes never import any of it."""
 import copy
 import functools
 import json
@@ -30,7 +31,7 @@ from benchmark import flops_sambay  # noqa: E402
 from benchmark.reference import sambay as ref  # noqa: E402
 from ddp_tpu.models import get_model  # noqa: E402
 from ddp_tpu.models import nemotron_h, sambay as sysm  # noqa: E402
-from ddp_tpu.ops import seq  # noqa: E402
+from ddp_tpu.ops import attention, selscan, seq  # noqa: E402
 
 CONFIG_FILE = os.path.join(ROOT, "benchmark", "configs",
                            "phi4_mini_flash_stage14_19.json")
@@ -128,7 +129,10 @@ def test_matches_reference(config, cd, monkeypatch):
     # Which path compiled: the tally counts every layer traced.
     assert kinds[:6] == (["mamba", "window"] * 3 if len(kinds) == 16 else
                          ["mamba", "window", "mamba", "full", "gmu", "cross"])
-    assert sysm.TRACED == {k: kinds.count(k) for k in sysm.TRACED}
+    attention_layers = sum(kinds.count(k) for k in ("window", "full", "cross"))
+    assert sysm.TRACED == {
+        **{k: kinds.count(k) for k in sysm.TRACED},
+        "core_kernel": 0, "core_xla": attention_layers}
     with jax.default_matmul_precision("highest"):
         r_loss, r_grads, _ = ref.loss_and_grads(
             config, params, state, np.asarray(ids), np.asarray(targets))
@@ -632,6 +636,107 @@ def test_shared_conv_lowers_to_the_parents_text(cd):
         == _grad_text(functools.partial(_parents_conv, cd=cd), *shapes)
 
 
+# -- who takes the attention cores -------------------------------------------------------
+
+def _parents_diff_block(q, k, v, lam, sub_norm, *, start, lo, window, scale,
+                        gain, eps, cd):
+    """``_diff_block`` as it stood before the kernel (66a9e2e)."""
+    _, r, bq, _ = q.shape
+    a1, a2 = (seq.block_probs(q[j], k[j], start=start, scale=scale, lo=lo,
+                              window=window) for j in (0, 1))
+    o = lax.dot_general((a1 - lam * a2).astype(cd), v,
+                        (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return (o * (sub_norm * gain)).astype(cd).reshape(r, bq, v.shape[-1])
+
+
+def _parents_diff_core(p, q, k, v, l, dm, cd, window):
+    """``diff_core`` as it stood before the kernel (66a9e2e)."""
+    bsz, t, pairs, _, hd = q.shape
+    kvp = k.shape[2]
+    rep = pairs // kvp
+    block = min(window, sysm.ATTN_QUERY_BLOCK) if window \
+        else sysm.ATTN_QUERY_BLOCK
+    lam = sysm.lam_of(p, l).astype(jnp.float32)
+
+    def unit(args):
+        q_u, k_u, v_u = args
+        out = []
+        for s in range(0, t, block):
+            lo, hi = (max(0, s - window) if window else 0), s + block
+            f = jax.checkpoint(functools.partial(
+                _parents_diff_block, start=s, lo=lo, window=window,
+                scale=1.0 / math.sqrt(hd), gain=1.0 - sysm.lam0_of(l),
+                eps=dm["eps"], cd=cd))
+            out.append(f(q_u[:, :, s:s + block], k_u[:, lo:hi],
+                         v_u[lo:hi], lam, p["sub_norm"]))
+        return jnp.concatenate(out, axis=1)
+
+    o = lax.map(unit, (
+        q.reshape(bsz, t, kvp, rep, 2, hd).transpose(0, 2, 4, 3, 1, 5)
+        .reshape(bsz * kvp, 2, rep, t, hd),
+        k.transpose(0, 2, 3, 1, 4).reshape(bsz * kvp, 2, t, hd),
+        v.transpose(0, 2, 1, 3).reshape(bsz * kvp, t, 2 * hd)))
+    return o.reshape(bsz, kvp, rep, t, 2 * hd).transpose(
+        0, 3, 1, 2, 4).reshape(bsz, t, pairs * 2 * hd)
+
+
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("window", [None, 32], ids=["full", "window"])
+def test_tiny_preset_cores_lower_to_the_parents_loop(window, tpu,
+                                                     monkeypatch):
+    """At the benchmark's tiny preset (8-wide maps beside a 16-wide value,
+    256 tokens) ``kernel_applies`` says no, on a TPU backend too, and the
+    core, forward and backward, lowers to the parent's text."""
+    tiny = json.load(open(os.path.join(
+        ROOT, "benchmark", "tests", "tiny", "train_seq.json")))["config"]
+    dm = sysm.dims({**published(), **tiny})
+    t, cd = int(tiny["seq_len"]), jnp.bfloat16
+    monkeypatch.setattr(attention, "_use_pallas", lambda: tpu)
+    monkeypatch.setattr(sysm, "TRACED", dict.fromkeys(sysm.TRACED, 0))
+    assert (dm["hd"], t) == (8, 256)
+    assert not attention.kernel_applies(t, dm["hd"], 2, 2 * dm["hd"])
+    p = {name: jax.ShapeDtypeStruct((dm["hd"],), jnp.float32)
+         for name in ("lq1", "lk1", "lq2", "lk2")}
+    p["sub_norm"] = jax.ShapeDtypeStruct((2 * dm["hd"],), jnp.float32)
+    shapes = (p,
+              jax.ShapeDtypeStruct((2, t, dm["pairs"], 2, dm["hd"]), cd),
+              jax.ShapeDtypeStruct((2, t, dm["kv_pairs"], 2, dm["hd"]), cd),
+              jax.ShapeDtypeStruct((2, t, dm["kv_pairs"], 2 * dm["hd"]), cd))
+    kw = dict(l=15, dm=dm, cd=cd, window=window)
+    assert _grad_text(functools.partial(sysm.diff_core, **kw), *shapes) \
+        == _grad_text(functools.partial(_parents_diff_core, **kw), *shapes)
+    assert (sysm.TRACED["core_kernel"], sysm.TRACED["core_xla"]) == (0, 1)
+
+
+def test_the_cells_program_takes_the_kernel_in_all_three_cores(monkeypatch):
+    """The cell's configuration, traced (not lowered: off the chip only
+    the interpreter lowers) with a TPU backend stood in: ``window``,
+    ``full`` and ``cross`` each go through ``diff_attention``, forward
+    and backward, the window layer with its 512, and no core through the
+    loop; on this backend the same program traces the loop three times."""
+    config = published()
+    init, apply, (vocab, t) = sysm.build(config)
+    params = jax.eval_shape(lambda: init(jax.random.key(0))[0])
+    ids = jax.ShapeDtypeStruct((2, t), jnp.int32)
+    for tpu, want in ((True, (3, 0)), (False, (0, 3))):
+        # A function of its own a pass: the tracing cache knows nothing of
+        # the stand-in.
+        grad = jax.grad(lambda p, x: apply(
+            p, {}, x, compute_dtype=jnp.bfloat16)[0].sum())
+        monkeypatch.setattr(attention, "_use_pallas", lambda: tpu)
+        monkeypatch.setattr(selscan, "_use_pallas", lambda: tpu)
+        monkeypatch.setattr(sysm, "TRACED", dict.fromkeys(sysm.TRACED, 0))
+        text = str(jax.make_jaxpr(grad)(params, ids))
+        assert (sysm.TRACED["core_kernel"], sysm.TRACED["core_xla"]) == want
+        assert sysm.TRACED["window"] == sysm.TRACED["full"] \
+            == sysm.TRACED["cross"] == 1
+        for name in ("diff_attention_fwd", "diff_attention_bwd"):
+            assert (name in text) == tpu, name
+        assert ("causal_gqa" in text) is False
+
+
 _CLASSIFIER_PROCESS = """
 import sys
 import jax, jax.numpy as jnp
@@ -671,10 +776,11 @@ def test_classifier_processes_never_import_the_token_models():
     assert out.stdout.strip().splitlines()[-1] == "SEEN []"
 
 
-def test_sambay_alone_imports_its_own_kernel_only():
+def test_sambay_alone_imports_its_own_kernels_only():
     """The model brings its selective scan's kernels (``ops/selscan.py``,
-    and so Pallas) and nothing of ``nemotron_h``'s: neither that model nor
-    its attention and scan kernels; ``ops/seq.py`` is plain XLA."""
+    and so Pallas) and, since its attention cores run the blocked kernel
+    pair, ``ops/attention.py``; nothing else of ``nemotron_h``'s: neither
+    that model nor its scan kernels; ``ops/seq.py`` is plain XLA."""
     code = ("import sys; import ddp_tpu.models.sambay; print('SEEN', sorted("
             "m for m in sys.modules if m in ('ddp_tpu.ops.selscan', "
             "'ddp_tpu.models.nemotron_h', 'ddp_tpu.ops.attention', "
@@ -684,4 +790,4 @@ def test_sambay_alone_imports_its_own_kernel_only():
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip().splitlines()[-1] \
-        == "SEEN ['ddp_tpu.ops.selscan']"
+        == "SEEN ['ddp_tpu.ops.attention', 'ddp_tpu.ops.selscan']"
