@@ -14,6 +14,7 @@ reference behavior.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import os
@@ -587,30 +588,25 @@ def _hard_exit(code: int) -> None:  # monkeypatch seam for tests
     os._exit(code)
 
 
-def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
-    """The reference ``main()`` body proper (multigpu.py:224-248), between
-    rendezvous and teardown — both owned by :func:`run`."""
-    enable_compile_cache()
-    # A searched plan doc (--auto_plan) IS the mesh/zero configuration:
-    # the search already chose the shape and the ZeRO setting, so the doc
-    # drives both and any redundant flags must agree rather than win.
-    auto_doc = None
-    if getattr(args, "auto_plan", None):
-        from .parallel.tp.autoplan import read_plan_doc
-        try:
-            auto_doc = read_plan_doc(args.auto_plan)
-        except (OSError, ValueError) as e:
-            raise SystemExit(f"--auto_plan: {e}")
-        if auto_doc["model"] != args.model:
-            raise SystemExit(
-                f"--auto_plan was searched for model "
-                f"{auto_doc['model']!r} but this run trains "
-                f"{args.model!r}; re-run the search for this model")
-        if auto_doc.get("zero") and not args.shard_update:
-            args.shard_update = True
-            if jax.process_index() == 0:
-                print("auto plan: ZeRO update sharding on "
-                      "(plan doc zero=true)")
+@contextlib.contextmanager
+def _early_phase(early: Optional[list], phase: str):
+    """A set-up phase that ends before the run's tracer exists (its ring
+    is sized from the loader): the clock readings are kept in ``early``
+    and become spans once it does.  ``early`` is None under
+    ``--obs_off``, and then no clock is read."""
+    if early is None:
+        yield
+        return
+    t0 = time.monotonic()
+    yield
+    early.append((phase, t0, time.monotonic() - t0))
+
+
+def _build_mesh(args: argparse.Namespace, auto_doc: Optional[dict],
+                num_devices: Optional[int]):
+    """The run's mesh from the plan doc, ``--mesh_shape`` or the device
+    count, redundant flags checked against each other.  ``make_mesh`` is
+    the first call that starts the backend."""
     if auto_doc is not None:
         doc_dims = tuple(int(v) for v in auto_doc["mesh_shape"])
         doc_full = doc_dims + (1,) * (3 - len(doc_dims))
@@ -633,8 +629,8 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
                 f"--num_devices {args.num_devices} contradicts the auto "
                 f"plan's searched mesh {doc_str} (= {n_doc} devices); "
                 "drop one")
-        mesh = make_mesh(shape=doc_dims)
-    elif args.mesh_shape:
+        return make_mesh(shape=doc_dims)
+    if args.mesh_shape:
         dims = _parse_mesh_shape(args.mesh_shape)
         n_mesh = 1
         for v in dims:
@@ -644,27 +640,13 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
                 f"--num_devices {args.num_devices} contradicts "
                 f"--mesh_shape {','.join(map(str, dims))} (= {n_mesh} "
                 "devices); drop one")
-        mesh = make_mesh(shape=dims)
-    else:
-        mesh = make_mesh(args.num_devices or num_devices)
-    # Batch math divides by the DATA axis only: on a 2-D mesh the model
-    # axis replicates the batch (parallel/mesh.py:data_axis_size).
-    from .parallel.mesh import data_axis_size
-    n_replicas = data_axis_size(mesh)
-    model_config = None
-    if args.model_config:
-        with open(args.model_config) as f:
-            model_config = json.load(f)
-    model = get_model(args.model, model_config)
-    # The native kernel serves host augmentation only; a run that
-    # augments on device, or trains on token ids, never builds it.
-    if args.device_augment or args.resident or model.tokens:
-        native_augment = "n/a"
-    else:
-        from .data import native
-        native_augment = "on" if native.get_lib() is not None else "off"
-    print(device_line(mesh, native_augment=native_augment), flush=True)
+        return make_mesh(shape=dims)
+    return make_mesh(args.num_devices or num_devices)
 
+
+def _load_data(args: argparse.Namespace, model):
+    """The run's train and test sets: seeded token rows for a token
+    model, the synthetic images or CIFAR-10 from ``--data_root``."""
     if model.tokens:
         if not args.synthetic:
             raise SystemExit(
@@ -701,12 +683,63 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
                 "dataset; it would be silently ignored for real CIFAR-10. "
                 "Pass --synthetic, or drop the flag.")
         train_ds, test_ds = cifar10.load(args.data_root)
+    return train_ds, test_ds
 
-    if args.init_from_torch:
-        params, batch_stats = _load_torch_init(args.model,
-                                               args.init_from_torch)
+
+def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
+    """The reference ``main()`` body proper (multigpu.py:224-248), between
+    rendezvous and teardown — both owned by :func:`run`."""
+    enable_compile_cache()
+    early = None if args.obs_off else []  # (phase, start, seconds)
+    # A searched plan doc (--auto_plan) IS the mesh/zero configuration:
+    # the search already chose the shape and the ZeRO setting, so the doc
+    # drives both and any redundant flags must agree rather than win.
+    auto_doc = None
+    if getattr(args, "auto_plan", None):
+        from .parallel.tp.autoplan import read_plan_doc
+        try:
+            auto_doc = read_plan_doc(args.auto_plan)
+        except (OSError, ValueError) as e:
+            raise SystemExit(f"--auto_plan: {e}")
+        if auto_doc["model"] != args.model:
+            raise SystemExit(
+                f"--auto_plan was searched for model "
+                f"{auto_doc['model']!r} but this run trains "
+                f"{args.model!r}; re-run the search for this model")
+        if auto_doc.get("zero") and not args.shard_update:
+            args.shard_update = True
+            if jax.process_index() == 0:
+                print("auto plan: ZeRO update sharding on "
+                      "(plan doc zero=true)")
+    with _early_phase(early, "backend_start"):
+        mesh = _build_mesh(args, auto_doc, num_devices)
+    # Batch math divides by the DATA axis only: on a 2-D mesh the model
+    # axis replicates the batch (parallel/mesh.py:data_axis_size).
+    from .parallel.mesh import data_axis_size
+    n_replicas = data_axis_size(mesh)
+    model_config = None
+    if args.model_config:
+        with open(args.model_config) as f:
+            model_config = json.load(f)
+    model = get_model(args.model, model_config)
+    # The native kernel serves host augmentation only; a run that
+    # augments on device, or trains on token ids, never builds it.
+    if args.device_augment or args.resident or model.tokens:
+        native_augment = "n/a"
     else:
-        params, batch_stats = model.init(jax.random.key(args.seed))
+        from .data import native
+        native_augment = "on" if native.get_lib() is not None else "off"
+    print(device_line(mesh, native_augment=native_augment), flush=True)
+
+    with _early_phase(early, "data_load"):
+        train_ds, test_ds = _load_data(args, model)
+
+    with _early_phase(early, "model_init"):
+        if args.init_from_torch:
+            params, batch_stats = _load_torch_init(args.model,
+                                                   args.init_from_torch)
+        else:
+            params, batch_stats = model.init(jax.random.key(args.seed))
     compute_dtype = jnp.bfloat16 if args.bf16 else None
 
     # Tensor-parallel plan (parallel/tp/plan.py): resolved against the
@@ -820,7 +853,6 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
         if stale and jax.process_index() > 0:
             stale = f"{stale}.host{jax.process_index()}"
         if stale:
-            import contextlib
             with contextlib.suppress(OSError):
                 os.unlink(stale)
     else:
@@ -834,9 +866,13 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
         # rule bench.py documents).  The spill file is never truncated
         # by the ring — offline reports see every span regardless.
         ring = max(4096, len(train_loader) * 8)
+        # The tracer's zero is the process's start, so the spill reads
+        # in process age; JAX's preparation of every executable from
+        # here on is a span of it (obs/startup.py).
+        from .obs import startup
+        anchor = dict(host=jax.process_index(), t0=startup.PROCESS_START)
         try:
-            tracer = SpanTracer(spill_path=spill, ring=ring,
-                                host=jax.process_index())
+            tracer = SpanTracer(spill_path=spill, ring=ring, **anchor)
         except OSError as e:
             # An unwritable spill location must not kill a training run
             # the way it would not have before telemetry existed —
@@ -845,8 +881,10 @@ def _run_body(args: argparse.Namespace, *, num_devices: Optional[int]) -> float:
             print(f"WARNING: cannot open --trace_spill {spill!r} ({e}); "
                   "tracing continues in-memory only (no spill file)",
                   file=sys.stderr)
-            tracer = SpanTracer(spill_path=None, ring=ring,
-                                host=jax.process_index())
+            tracer = SpanTracer(spill_path=None, ring=ring, **anchor)
+        startup.attach(tracer)
+        for phase, start, dur_s in early:
+            tracer.add_span(phase, start, dur_s)
     # Resilience surface (ddp_tpu/resilience/): graceful SIGTERM/SIGINT
     # handling is on whenever we own the main thread (signal.signal is
     # main-thread-only; embedded callers keep their own handlers), the

@@ -2,6 +2,8 @@
 
 - ``tracer``    — low-overhead span tracer every hot path reports into
                   (bounded ring + JSONL spill; ``--obs_off`` = no-op).
+- ``startup``   — JAX's preparation of every executable as spans of the
+                  attached tracer, and the process's age on its clock.
 - ``export``    — Perfetto ``trace_event`` export + the terminal reports
                   behind ``python -m ddp_tpu.obs``.
 - ``live``      — rolling live stats (median/p90 step time, samples/sec,

@@ -5,7 +5,9 @@ A spill file (``--trace_spill``; obs/tracer.py) is append-only JSON lines
 ``{"phase", "step", "start_s", "dur_s", "overlap", "host"}``, plus the
 optional counts ``n`` (items) and ``nbytes`` where the site gave them
 (``dispatch``: samples, ``loss_flush``: losses, ``h2d`` and a resident
-epoch's ``epoch_setup``: bytes shipped).  Multi-host
+epoch's ``epoch_setup``: bytes shipped, ``prepare_compile``: 1 where the
+backend compiled, 0 where it read the executable back) and, on JAX's
+preparation spans (obs/startup.py), the executable's ``name``.  Multi-host
 runs write one spill per host (rank suffixes); :func:`read_spill` merges
 any number of them into one timeline.
 
@@ -23,7 +25,11 @@ Report semantics: ``overlap=True`` spans ran on producer threads
 consumer loop, so the wall-time identity only holds over *non-overlap*
 spans — :func:`phase_summary` keeps the two ledgers separate and
 reports the non-overlap sum as a fraction of wall (the acceptance
-check: within 10% on a default CPU-box run).
+check: within 10% on a default CPU-box run).  Serial spans may NEST (a
+``prepare_compile`` inside the ``dispatch`` whose call caused it,
+``resident_upload`` inside ``trainer_init``): :func:`span_parents`
+decides each span's parent from the intervals, and a sum that is compared
+with wall time counts top-level spans only.
 """
 from __future__ import annotations
 
@@ -31,14 +37,20 @@ import json
 import statistics
 from typing import Dict, Iterable, List, Optional, Tuple
 
-# Canonical phase order: consumer-loop phases first in pipeline order
+# Canonical phase order: a run's set-up first (cli.run's three phases,
+# Trainer.__init__ with the table's upload inside it, then JAX's
+# preparation of an executable, which nests in whatever caused it:
+# obs/startup.py), then the consumer-loop phases in pipeline order
 # (epoch_setup and epoch_close are the epoch's two ends on that thread),
 # then the boundary/background phases, then the serving engine's batch
 # pipeline (ddp_tpu/serve/ — queue_wait is per-request and overlap=True;
 # batch_form..d2h are the engine thread's serial stages, sharing "h2d"
 # with the training pipeline).  Unknown phases sort after these (the
 # tracer accepts free-form names).
-PHASE_ORDER = ("epoch_setup", "data_wait", "host_augment", "h2d",
+PHASE_ORDER = ("backend_start", "data_load", "model_init", "trainer_init",
+               "resident_upload",
+               "prepare_trace", "prepare_lower", "prepare_compile",
+               "epoch_setup", "data_wait", "host_augment", "h2d",
                "dispatch", "epoch_close", "loss_flush", "drift_audit",
                "ckpt_write", "ckpt_upload", "eval",
                "queue_wait", "batch_form", "pad", "forward", "d2h",
@@ -127,8 +139,9 @@ def to_trace_events(spans: List[dict]) -> dict:
         args = {"overlap": bool(s["overlap"])}
         if s.get("step") is not None:
             args["step"] = int(s["step"])
-        if s.get("req") is not None:
-            args["req"] = str(s["req"])
+        for key in ("req", "name"):
+            if s.get(key) is not None:
+                args[key] = str(s[key])
         for key in ("n", "nbytes"):
             if s.get(key) is not None:
                 args[key] = int(s[key])
@@ -301,29 +314,76 @@ def format_requests_report(spans: List[dict], top: int = 10) -> str:
     return "\n".join(lines)
 
 
+# -- nesting ----------------------------------------------------------------
+
+# A spill line rounds a span's start and length to a microsecond each, so
+# a span may seem to end this much after the span that holds it.
+_NEST_SLACK_S = 2e-6
+
+
+def span_parents(spans: List[dict]) -> List[Optional[int]]:
+    """For each span, the index in ``spans`` of its innermost enclosing
+    serial span; None at top level and for every ``overlap`` span.
+
+    A host's serial spans are one thread's, so they tile it or nest and
+    the parent is decidable from the intervals alone, whatever the order
+    the spans were recorded in (a span lands when it ENDS, and JAX's
+    preparation spans are told of afterwards): nothing is noted on the
+    hot path.  ``spans[i]["phase"]`` of the result is the phase that
+    caused a preparation: ``dispatch``, ``trainer_init``."""
+    parents: List[Optional[int]] = [None] * len(spans)
+    by_host: Dict[int, List[Tuple[float, float, int]]] = {}
+    for i, s in enumerate(spans):
+        if not s["overlap"]:
+            start = float(s["start_s"])
+            by_host.setdefault(int(s.get("host", 0)), []).append(
+                (start, start + float(s["dur_s"]), i))
+    for serial in by_host.values():
+        # By start, the longer first where two start together, so that
+        # whatever holds a span has been seen before it.
+        serial.sort(key=lambda t: (t[0], -t[1]))
+        held_by: List[Tuple[float, int]] = []  # (end, index), outermost first
+        for _start, end, i in serial:
+            while held_by and held_by[-1][0] < end - _NEST_SLACK_S:
+                held_by.pop()  # ends before this one does: not around it
+            if held_by:
+                parents[i] = held_by[-1][1]
+            held_by.append((end, i))
+    return parents
+
+
 # -- terminal reports ------------------------------------------------------
+
+LANES = ("serial", "nested", "overlap")
+
 
 def phase_summary(spans: List[dict]) -> Tuple[List[dict], float, float]:
     """Per-phase ledger + the wall identity.
 
-    Returns ``(rows, wall_s, critical_s)``: one row per phase (count,
-    total/median/mean ms, overlap flag, and the summed counts ``n`` and
-    ``nbytes``, None where no span of the phase carries one), the run's
-    wall time (span of
-    the whole timeline), and the *critical* sum — total time of
-    non-overlap spans only, the quantity comparable to wall (producer
-    threads run concurrently and would double-count)."""
+    Returns ``(rows, wall_s, critical_s)``: one row per phase and lane
+    (``serial``: top-level spans of the consumer thread; ``nested``:
+    serial spans inside another, :func:`span_parents`; ``overlap``:
+    producer threads) with count, total/median/mean ms, overlap flag, and
+    the summed counts ``n`` and ``nbytes``, None where no span of the
+    phase carries one; the run's wall time (span of the whole timeline);
+    and the *critical* sum — total time of the ``serial`` lane only, the
+    quantity comparable to wall (producer threads run concurrently, and a
+    nested span's time is its parent's: either would double-count)."""
     if not spans:
         return [], 0.0, 0.0
-    by_phase: Dict[Tuple[str, bool], List[dict]] = {}
-    for s in spans:
-        by_phase.setdefault((s["phase"], bool(s["overlap"])), []).append(s)
+    by_phase: Dict[Tuple[str, str], List[dict]] = {}
+    for s, parent in zip(spans, span_parents(spans)):
+        lane = ("overlap" if s["overlap"]
+                else "serial" if parent is None else "nested")
+        by_phase.setdefault((s["phase"], lane), []).append(s)
     rows = []
-    for (phase, overlap), group in sorted(
-            by_phase.items(), key=lambda kv: _phase_rank(kv[0][0])):
+    for (phase, lane), group in sorted(
+            by_phase.items(),
+            key=lambda kv: (_phase_rank(kv[0][0]), LANES.index(kv[0][1]))):
         durs = [float(s["dur_s"]) for s in group]
         row = {
-            "phase": phase, "overlap": overlap, "count": len(durs),
+            "phase": phase, "lane": lane, "overlap": lane == "overlap",
+            "count": len(durs),
             "total_ms": sum(durs) * 1e3,
             "median_ms": statistics.median(durs) * 1e3,
             "mean_ms": sum(durs) / len(durs) * 1e3,
@@ -334,7 +394,8 @@ def phase_summary(spans: List[dict]) -> Tuple[List[dict], float, float]:
         rows.append(row)
     wall_s = (max(s["start_s"] + s["dur_s"] for s in spans)
               - min(s["start_s"] for s in spans))
-    critical_s = sum(s["dur_s"] for s in spans if not s["overlap"])
+    critical_s = sum(r["total_ms"] for r in rows
+                     if r["lane"] == "serial") / 1e3
     return rows, wall_s, critical_s
 
 
@@ -447,7 +508,7 @@ def _format_host_report(spans: List[dict], *, host: int, top: int,
     for r in rows:
         share = r["total_ms"] / (wall_s * 1e3) * 100.0 if wall_s else 0.0
         line = (
-            f"{r['phase']:<16} {'overlap' if r['overlap'] else 'serial':<8} "
+            f"{r['phase']:<16} {r['lane']:<8} "
             f"{r['count']:>7} {r['total_ms']:>12.2f} "
             f"{r['median_ms']:>11.3f} {r['mean_ms']:>11.3f} {share:>6.1f}%")
         if counted:
@@ -458,7 +519,9 @@ def _format_host_report(spans: List[dict], *, host: int, top: int,
     pct = critical_s / wall_s * 100.0 if wall_s else 0.0
     lines.append("")
     lines.append(f"phase sum (serial lanes): {critical_s * 1e3:.1f} ms = "
-                 f"{pct:.1f}% of wall {wall_s * 1e3:.1f} ms")
+                 f"{pct:.1f}% of wall {wall_s * 1e3:.1f} ms"
+                 + (" (a nested span's time is its parent's)"
+                    if any(r["lane"] == "nested" for r in rows) else ""))
     walls = step_walls(spans)
     if walls:
         lines.append("")

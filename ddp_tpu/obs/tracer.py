@@ -18,7 +18,9 @@ offline tooling consumes (``python -m ddp_tpu.obs``: phase breakdown,
 step histogram, slowest-K, Perfetto export — obs/export.py).
 
 Phases are free-form strings; the canonical training phases live in
-:data:`~ddp_tpu.obs.export.PHASE_ORDER` (epoch_setup, data_wait,
+:data:`~ddp_tpu.obs.export.PHASE_ORDER` (the set-up phases backend_start
+.. trainer_init and JAX's prepare_trace, prepare_lower, prepare_compile,
+which obs/startup.py records; then epoch_setup, data_wait,
 host_augment, h2d, dispatch, epoch_close, loss_flush, ckpt_write, eval).
 A span may carry two counts, ``n`` (items: samples, losses) and
 ``nbytes``, given to ``span()`` or, where they are known only inside the
@@ -45,7 +47,8 @@ trace, on the thread that ran it, with ``step`` and the counts as its
 arguments (one ``jax.profiler.TraceAnnotation`` entered and left with the
 span).  With no session the price is one ``is_enabled()`` call a span.
 ``add_span`` records an interval that is already over, so it has no
-event (the threaded engine's ``data_wait``, ``pp_bubble``).
+event (the threaded engine's ``data_wait``, ``pp_bubble``, JAX's
+preparation of an executable, which alone carries a ``name``).
 
 Thread safety: producers (prefetch pool/thread, checkpoint writer) and
 the consumer loop record concurrently; the ring, last-span table and
@@ -107,7 +110,8 @@ class NullTracer:
     def add_span(self, phase: str, start_monotonic: float, dur_s: float,
                  step: Optional[int] = None, overlap: bool = False,
                  req: Optional[str] = None, n: Optional[int] = None,
-                 nbytes: Optional[int] = None) -> None:
+                 nbytes: Optional[int] = None,
+                 name: Optional[str] = None) -> None:
         pass
 
     def now(self) -> float:
@@ -199,23 +203,25 @@ class SpanTracer:
     the profiler's annotation class (module docstring), and works
     without it.
     ``ring`` bounds in-memory retention (the spill file is the full
-    record); ``t0`` anchors relative timestamps and defaults to
-    construction time.
+    record); ``t0`` (a ``time.monotonic()`` reading) anchors relative
+    timestamps and defaults to construction time; ``cli.run`` passes the
+    process's start, so its spill reads in process age.
 
     The spill is TRUNCATED per run (the same overwrite-in-place
     discipline as ``checkpoint.pt``): timestamps are relative to this
-    tracer's construction, so appending a second run's spans onto a
-    first's would stack two timelines at t=0 and double-count every
-    report built from the file.
+    tracer's ``t0``, so appending a second run's spans onto a first's
+    would stack two timelines at t=0 and double-count every report
+    built from the file.
     """
 
     enabled = True
 
     def __init__(self, spill_path: Optional[str] = None, *,
-                 ring: int = 4096, host: int = 0):
+                 ring: int = 4096, host: int = 0,
+                 t0: Optional[float] = None):
         self.host = int(host)
         self.spill_path = spill_path
-        self._t0 = time.monotonic()
+        self._t0 = time.monotonic() if t0 is None else float(t0)
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(maxlen=ring)
         self._last: Dict[str, tuple] = {}
@@ -249,20 +255,23 @@ class SpanTracer:
     def add_span(self, phase: str, start_monotonic: float, dur_s: float,
                  step: Optional[int] = None, overlap: bool = False,
                  req: Optional[str] = None, n: Optional[int] = None,
-                 nbytes: Optional[int] = None) -> None:
+                 nbytes: Optional[int] = None,
+                 name: Optional[str] = None) -> None:
         """Record a span measured by the caller (``start_monotonic`` on
         the ``time.monotonic`` clock) — for sites that only know AFTER
         timing whether the interval was a real phase occurrence (e.g. the
         prefetch consumer's queue get, which may return the end-of-stream
-        sentinel rather than a batch)."""
+        sentinel rather than a batch), or that are told of it afterwards
+        (obs/startup.py; ``name``: the executable JAX prepared)."""
         self._record(phase, step, start_monotonic, dur_s, overlap, req,
-                     n, nbytes)
+                     n, nbytes, name)
 
     def _record(self, phase: str, step: Optional[int], start: float,
                 dur: float, overlap: bool, req: Optional[str] = None,
-                n: Optional[int] = None,
-                nbytes: Optional[int] = None) -> None:
-        rec = (phase, step, start - self._t0, dur, overlap, req, n, nbytes)
+                n: Optional[int] = None, nbytes: Optional[int] = None,
+                name: Optional[str] = None) -> None:
+        rec = (phase, step, start - self._t0, dur, overlap, req, n, nbytes,
+               name)
         # Serialize OUTSIDE the lock: json.dumps is pure CPU on local
         # data, and holding the one shared lock through it would make
         # every producer thread contend on exactly the work being timed.
@@ -277,6 +286,8 @@ class SpanTracer:
             body["n"] = n
         if nbytes is not None:
             body["nbytes"] = nbytes
+        if name is not None:  # JAX's preparation spans only
+            body["name"] = name
         line = (json.dumps(body) + "\n") if self._f is not None else None
         with self._lock:
             self._ring.append(rec)
@@ -302,6 +313,11 @@ class SpanTracer:
 
     # -- reading -----------------------------------------------------------
 
+    @property
+    def t0(self) -> float:
+        """The tracer's zero as a ``time.monotonic()`` reading."""
+        return self._t0
+
     def now(self) -> float:
         """Current time on the tracer's own clock (span ``start_s`` basis)
         — the window marker ``spans_since`` consumes."""
@@ -309,10 +325,10 @@ class SpanTracer:
 
     @staticmethod
     def _as_dict(rec: tuple) -> dict:
-        phase, step, start, dur, overlap, req, n, nbytes = rec
+        phase, step, start, dur, overlap, req, n, nbytes, name = rec
         return {"phase": phase, "step": step, "start_s": start,
                 "dur_s": dur, "overlap": overlap, "req": req,
-                "n": n, "nbytes": nbytes}
+                "n": n, "nbytes": nbytes, "name": name}
 
     def spans_since(self, t: float) -> List[dict]:
         """Completed spans whose start is at or after tracer-time ``t``

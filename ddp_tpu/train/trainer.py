@@ -133,6 +133,19 @@ class Trainer:
                  registry=None,
                  mirror=None,
                  step_probe=None):
+        # Telemetry (ddp_tpu/obs/): the span tracer every phase of the
+        # epoch loop reports into (default: the process tracer — a
+        # NullTracer unless cli.run installed a real one).  Known first,
+        # so that ``trainer_init`` holds this whole body (state built or
+        # restored, ZeRO's flat momentum, the resident table, the
+        # builders) and JAX's preparation of every executable from here
+        # on lands on the same timeline (obs/startup.py; nothing is
+        # imported, registered or read under a NullTracer).
+        self.tracer = tracer if tracer is not None else get_tracer()
+        if self.tracer.enabled:
+            from ..obs import startup
+            startup.attach(self.tracer)
+        init_span = self.tracer.span("trainer_init").__enter__()
         self.model = model
         self.train_loader = train_loader
         self.mesh = mesh
@@ -171,7 +184,7 @@ class Trainer:
         # the whole local checkpoint directory is gone (preemption
         # reclaims the VM's disk).  The store is resolved up front (the
         # resume below may need it); the uploader thread itself starts
-        # later in __init__, after the tracer lands.
+        # later in __init__.
         self._mirror = None
         self._mirror_store = None
         if mirror is not None and snapshot_path:
@@ -367,11 +380,7 @@ class Trainer:
         self.prefetch_depth = prefetch_depth
         self.prefetch_workers = prefetch_workers
         self.prefetch_stats = prefetch_stats
-        # Telemetry (ddp_tpu/obs/): the span tracer every phase of the
-        # epoch loop reports into (default: the process tracer — a
-        # NullTracer unless cli.run installed a real one) and the
-        # rolling live-stats engine (rank 0, obs/live.py).
-        self.tracer = tracer if tracer is not None else get_tracer()
+        # The rolling live-stats engine (rank 0, obs/live.py).
         self._live = live if self.gpu_id == 0 else None
         # Introspection probe (obs/inspect.py): one bounded callable per
         # optimizer step — the periodic .prom rewrite and the on-demand
@@ -479,6 +488,7 @@ class Trainer:
                                        every=drift_audit_every,
                                        action=drift_action,
                                        registry=registry)
+        init_span.end()
 
     def _ckpt_loader(self):
         """The lineage walk's candidate loader, bound to THIS run's mesh

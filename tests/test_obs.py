@@ -578,6 +578,19 @@ def test_cli_default_run_spills_and_reports(tmp_path, capsys, monkeypatch):
     assert (tmp_path / "trace_spill.jsonl").exists()
     # The run restored the process default tracer on exit.
     assert not get_tracer().enabled
+    # The spill reads in process age, set-up first: cli.run's three
+    # phases in order, then trainer_init, all at positive starts, and
+    # JAX's preparations with their names (obs/startup.py).
+    spill = export.read_spill(["trace_spill.jsonl"])
+    first = {}
+    for s in spill:
+        first.setdefault(s["phase"], s)
+    order = ["backend_start", "data_load", "model_init", "trainer_init",
+             "epoch_setup"]
+    starts = [first[p]["start_s"] for p in order]
+    assert starts == sorted(starts) and starts[0] > 0.0
+    assert first["prepare_compile"]["name"] \
+        and first["prepare_compile"]["n"] in (0, 1)
 
     # Metrics stream: live records with prefetch occupancy + stragglers.
     recs = [json.loads(l) for l in open("m.jsonl")]
