@@ -12,6 +12,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
                                                   against the XLA path and float32
     selscan    python -m ddp_tpu.ops.selscan      the second token model's scan kernels
                                                   against the XLA path and float32
+    moe        python -m ddp_tpu.models.moe       the routed experts' products over the live
+                                                  row tiles against the every-tile form
     sambay     python -m ddp_tpu.models.sambay    one step of the SambaY stage (layers
                                                   14-19 of 32): tiny, then published widths
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
@@ -377,38 +379,47 @@ class Smoke:
             fail(f"no 'gather: ok kernel={kernel}' line", cmd, out)
         return m.group(0)
 
+    def table_check(self, cmd, tag: str, ok: str) -> str:
+        """A child that prints a table of ``<tag>: ...`` lines, raises on a
+        failed check of its own and ends with ``<tag>: ok <ok> ...``: the
+        table is shown, the last line returned."""
+        out = self.run(cmd)
+        self.check_device(cmd, out)
+        for line in re.findall(rf"^{tag}: .*$", out, re.M)[:-1]:
+            print(f"[smoke]   {line}", flush=True)
+        m = re.search(rf"^{tag}: ok {ok} .*$", out, re.M)
+        if not m:
+            fail(f"no '{tag}: ok {ok}' line", cmd, out)
+        return m.group(0)
+
     def kernel_check(self, name: str) -> str:
         """``python -m ddp_tpu.ops.<name>``: a token-model kernel at the
         token cell's shape.  The child raises where the kernel is further
         from float32 than the XLA path or the mixer does not take it; its
         table (distances, milliseconds, block sweep, paths traced) is
         shown."""
-        cmd = [PY, "-m", f"ddp_tpu.ops.{name}"]
-        out = self.run(cmd)
-        self.check_device(cmd, out)
-        for line in re.findall(rf"^{name}: .*$", out, re.M)[:-1]:
-            print(f"[smoke]   {line}", flush=True)
         kernel = "pallas" if self.platform == "tpu" else "interpret"
-        m = re.search(rf"^{name}: ok kernel={kernel} .*$", out, re.M)
-        if not m:
-            fail(f"no '{name}: ok kernel={kernel}' line", cmd, out)
-        return m.group(0)
+        return self.table_check([PY, "-m", f"ddp_tpu.ops.{name}"], name,
+                                f"kernel={kernel}")
+
+    def moe(self) -> str:
+        """The expert layer's products alone at both routing cells' shapes
+        (tiny ones off the chip) with a quarter, a half and all of the
+        buffer live: the child raises where the live loop's result is not
+        the every-tile form's or, on the chip, a full buffer costs over 3%
+        more; its table of milliseconds is shown."""
+        return self.table_check([PY, "-m", "ddp_tpu.models.moe"], "moe",
+                                f"platform={self.platform}")
 
     def sambay(self) -> str:
         """One training step of the second token model's pipeline stage,
         at a tiny width and at the published widths (8,192 tokens): the
         child raises on a wrong logits shape or a loss or parameter that
         is not finite."""
-        cmd = [PY, "-m", "ddp_tpu.models.sambay", SAMBAY_CONFIG]
-        out = self.run(cmd)
-        self.check_device(cmd, out)
-        for line in re.findall(r"^sambay: .*$", out, re.M)[:-1]:
-            print(f"[smoke]   {line}", flush=True)
         steps = 2 if self.platform == "tpu" else 1
-        m = re.search(rf"^sambay: ok steps={steps} .*$", out, re.M)
-        if not m:
-            fail(f"no 'sambay: ok steps={steps}' line", cmd, out)
-        return m.group(0)
+        return self.table_check(
+            [PY, "-m", "ddp_tpu.models.sambay", SAMBAY_CONFIG], "sambay",
+            f"steps={steps}")
 
     def lm(self) -> str:
         cmd = [PY, "-m", "ddp_tpu.train.lm", "--steps", "6",
@@ -425,7 +436,7 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "attention", "ssd", "selscan", "sambay", "train",
+PHASES = ("gather", "attention", "ssd", "selscan", "moe", "sambay", "train",
           "train_again", "serve", "bf16", "resident", "shard_update",
           "resume", "lm", "generate")
 
@@ -444,6 +455,7 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
         "attention": lambda: s.kernel_check("attention"),
         "ssd": lambda: s.kernel_check("ssd"),
         "selscan": lambda: s.kernel_check("selscan"),
+        "moe": s.moe,
         "sambay": s.sambay,
         "train": lambda: s.train_dp("train"),
         "train_again": s.train_again,

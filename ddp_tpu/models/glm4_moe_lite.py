@@ -43,7 +43,8 @@ float32 accumulation; the router, softmax statistics, the rotary angles
 and rotation, and every norm's statistics in float32.
 
 State: per expert layer (and the module, ``mtp``) the router's ``e_bias``
-and the counters ``assignments`` and ``dropped`` (models/moe.py), and
+and the counters ``assignments``, ``dropped`` and ``live_tiles``
+(models/moe.py), and
 ``lm_loss``, the float leaf the loss core fills with each depth's loss.
 :data:`TRACED` tallies the layers traced by kind and which path took the
 attention core.
@@ -257,7 +258,9 @@ def build(config: dict):
                 # putting it into the weights) shows.
                 "e_bias": normal((dm["router"],), 0.05),
                 "assignments": jnp.zeros((dm["count"],), jnp.int32),
-                "dropped": jnp.zeros((), jnp.int32)}
+                "dropped": jnp.zeros((), jnp.int32),
+                "live_tiles": jnp.zeros((), jnp.int32),
+                "buffer_tiles": jnp.zeros((), jnp.int32)}
 
         layers, state = {}, {}
         for i in range(dm["layers"]):

@@ -12,17 +12,26 @@ on every cell that routes.
 ``dm`` holds what the layer reads: ``router`` (experts the router scores),
 ``first``/``count`` (the share held here), ``top_k``, ``norm_topk``,
 ``scale``.  The layer's state holds the router's ``e_bias``
-(``e_score_correction_bias``: state, not trained) and two integer
-counters, ``assignments`` (to each held expert) and ``dropped``, which a
-training step adds to (train/step.py sums integer state over replicas;
-the Trainer exports them where it flushes losses: obs/routing.py).
+(``e_score_correction_bias``: state, not trained) and four integer
+counters, ``assignments`` (to each held expert), ``dropped``,
+``live_tiles`` (the row tiles that held a row, which are the tiles the
+products visited) and ``buffer_tiles`` (the tiles the buffer offered: the
+live share's other side, counted where the live tiles are so that the
+quotient holds over replicas and micro-batches), which a training step
+adds to (train/step.py sums
+integer state over replicas; the Trainer exports them where it flushes
+losses: obs/routing.py).
 
 Scopes: ``moe_route`` (router, top-k, sort, gather in, scatter back),
-``moe_experts`` (the held experts' products over the row tiles),
-``moe_shared``.
+``moe_experts`` (the held experts' products over the live row tiles, in
+the backward pass too: :func:`tile_products`), ``moe_shared``.
+
+``python -m ddp_tpu.models.moe`` times the products alone at both routing
+cells' shapes against the form that computes every tile.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Tuple
 
 import jax
@@ -84,7 +93,9 @@ def row_plan(key, count: int, tile: int, tiles: int):
     it is held elsewhere.  Returns ``sizes`` [count] (assignments to each
     held expert), ``src`` [tiles * tile] (the assignment of a row; ``A``
     for a row of padding), ``tile_expert`` [tiles] and ``dropped`` (held
-    here and no room)."""
+    here and no room) and ``live``, the tiles that hold a row: an expert's
+    tiles follow the expert's before it, so they are tiles ``0 .. live-1``
+    and every tile from ``live`` on is padding whole."""
     n, cap = key.shape[0], tiles * tile
     sizes = jnp.sum(key[:, None] == jnp.arange(count), axis=0,
                     dtype=jnp.int32)
@@ -102,7 +113,8 @@ def row_plan(key, count: int, tile: int, tiles: int):
     tile_expert = jnp.minimum(jnp.searchsorted(
         ends, jnp.arange(tiles) * tile, side="right"), count - 1)
     dropped = jnp.sum((key_s < count) & (row >= cap), dtype=jnp.int32)
-    return sizes, src, tile_expert, dropped
+    live = jnp.minimum(ends[-1] // tile, tiles)
+    return sizes, src, tile_expert, dropped, live
 
 
 def buffer_tiles(n_tok: int, dm: dict, tile: int) -> int:
@@ -117,25 +129,104 @@ def buffer_tiles(n_tok: int, dm: dict, tile: int) -> int:
     return -(-int(rows) // tile) + count
 
 
+def _varying(x, vma: frozenset):
+    """``x`` varying over the mesh axes ``vma``.  Inside shard_map a
+    parameter and a fresh constant do not vary over the mesh and the data
+    does; a loop's carry must vary as its body's result does, and a
+    ``custom_vjp`` is given parameters that already vary, so that their
+    gradient is summed over the mesh by this cast's own transpose."""
+    missing = tuple(vma - jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
+def _tile(a, i):
+    return lax.dynamic_index_in_dim(a, i, keepdims=False)
+
+
+def _put_tile(a, v, i):
+    return lax.dynamic_update_index_in_dim(a, v.astype(a.dtype), i, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def tile_products(form: ExpertForm, cd, rows, stacks, tile_expert, live):
+    """The held experts' products over the live prefix of the row buffer.
+    ``rows`` [tiles,tile,d]; ``stacks``: the parameters ``form.routed``
+    names, ``[count, ...]`` each, multiplied in ``cd``; tile ``i`` is
+    expert ``tile_expert[i]``'s.  Returns ``out`` [tiles,tile,d]: tiles
+    ``0 .. live-1`` computed, the rest zeros, which is what either form
+    makes of their zero rows.
+
+    Both rules are a loop of ``live`` trips, so no pass multiplies a tile
+    of padding: the backward takes ``jax.vjp`` of one tile (any form
+    passes through it), writes the rows' cotangent a tile at a time and
+    adds the tile's weight gradients into its expert's slice of a float32
+    accumulator.  Each opens the ``moe_experts`` scope itself: a backward
+    rule is traced outside the layer's."""
+    return _products_fwd(form, cd, rows, stacks, tile_expert, live)[0]
+
+
+def _products_fwd(form, cd, rows, stacks, tile_expert, live):
+    vma = jax.typeof(rows).vma
+    with jax.named_scope("moe_experts"):
+        low = tuple(s.astype(cd) for s in stacks)
+
+        def one(i, out):
+            e = tile_expert[i]
+            return _put_tile(out, form.fn(
+                jnp.dot, _tile(rows, i), lambda j: _tile(low[j], e)), i)
+
+        out = lax.fori_loop(0, live, one, _varying(jnp.zeros(
+            rows.shape, jnp.promote_types(rows.dtype, cd)), vma))
+    return out, (rows, stacks, tile_expert, live)
+
+
+def _products_bwd(form, cd, res, g):
+    rows, stacks, tile_expert, live = res
+    vma = jax.typeof(rows).vma
+    with jax.named_scope("moe_experts"):
+        low = tuple(s.astype(cd) for s in stacks)
+
+        def one(i, carry):
+            d_rows, d_stacks = carry
+            e = tile_expert[i]
+            _, pull = jax.vjp(
+                lambda x, *w: form.fn(jnp.dot, x, lambda j: w[j]),
+                _tile(rows, i), *(_tile(s, e) for s in low))
+            dx, *dw = pull(_tile(g, i))
+            return _put_tile(d_rows, dx, i), tuple(
+                _put_tile(acc, _tile(acc, e) + v.astype(F32), e)
+                for acc, v in zip(d_stacks, dw))
+
+        d_rows, d_stacks = lax.fori_loop(0, live, one, (
+            _varying(jnp.zeros_like(rows), vma),
+            tuple(_varying(jnp.zeros(s.shape, F32), vma) for s in stacks)))
+        d_stacks = tuple(a.astype(s.dtype) for a, s in zip(d_stacks, stacks))
+    return d_rows, d_stacks, None, None
+
+
+tile_products.defvjp(_products_fwd, _products_bwd)
+
+
 def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
     """Returns ``(y, new layer state)``.  The held experts' products run
     over a buffer of row tiles, each tile one expert's (:func:`row_plan`),
-    a tile at a time with its expert's weights: every tile is computed
-    whether rows fell on it or not, so a step's time does not follow the
-    routers' load, and padding rows are zeros that pass through either
-    form's activation and products as zeros (no mask:
-    ``jax.lax.ragged_dot``, which this replaced, leaves the rows past its
-    groups as it finds them, and made the step's time follow the seed:
-    PERF.md, findings of PR 28).
+    a tile at a time with its expert's weights, over the tiles that hold
+    a row and no others (:func:`tile_products`): the rows are packed
+    expert after expert from the buffer's start, so those tiles are a
+    prefix, and what lies past it stays zero.  A step's time therefore
+    follows the held experts' load, up to the whole buffer's (every tile
+    live: what each step cost before PR 38).  Padding rows inside a live
+    tile are zeros that pass through either form's activation and
+    products as zeros (no mask: ``jax.lax.ragged_dot``, which the buffer
+    replaced, leaves the rows past its groups as it finds them, NaN on
+    the chip: PERF.md, findings of PR 28).
 
     The buffer (:func:`buffer_tiles`) holds ``MOE_LOAD_HEADROOM`` times
     the uniform load: no assignment is dropped while the held experts'
     load is within that, whatever its split among them, and ``dropped``
     counts those that found no room beyond it.  The buffer's size is
-    memory AND time: its rows are gathered, multiplied and scattered back
-    whether they hold a token or not (``nemotron_h``'s worst case, 6 rows
-    a token where uniform routing sends 0.375, would triple the layer's
-    time)."""
+    memory, and time in ``moe_route``: all its rows are gathered and
+    scattered back whether they hold a token or not."""
     bsz, t, d = x.shape
     n_tok, k = bsz * t, dm["top_k"]
     first, count, tile = dm["first"], dm["count"], MOE_ROW_TILE
@@ -146,7 +237,7 @@ def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
                                       precision=lax.Precision.HIGHEST))
         idx, w = route_weights(s, st["e_bias"], dm)
         held = (idx >= first) & (idx < first + count)
-        sizes, src, tile_expert, dropped = row_plan(
+        sizes, src, tile_expert, dropped, live = row_plan(
             jnp.where(held, idx - first, count).reshape(-1), count, tile,
             tiles)
         # Assignment a is slot a % k of token a // k; a row of padding
@@ -156,10 +247,10 @@ def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
         row_w = jnp.concatenate([w.reshape(-1), jnp.zeros((1,), w.dtype)])[
             src]
     with jax.named_scope("moe_experts"):
-        stacks = tuple(p[name].astype(cd) for name in form.routed)
-        out = lax.map(
-            lambda a: form.fn(jnp.dot, a[0], lambda i: stacks[i][a[1]]),
-            (rows.reshape(tiles, tile, d), tile_expert))
+        out = tile_products(
+            form, cd, rows.reshape(tiles, tile, d),
+            tuple(_varying(p[name], jax.typeof(rows).vma)
+                  for name in form.routed), tile_expert, live)
     with jax.named_scope("moe_route"):
         # Back to token order: each row adds its weighted result to its
         # token (padding to the row past the tokens' end).
@@ -172,5 +263,125 @@ def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
     if train:
         new_st = {"e_bias": st["e_bias"],
                   "assignments": st["assignments"] + sizes,
-                  "dropped": st["dropped"] + dropped}
+                  "dropped": st["dropped"] + dropped,
+                  "live_tiles": st["live_tiles"] + live,
+                  "buffer_tiles": st["buffer_tiles"] + tiles}
     return y.astype(cd).reshape(bsz, t, d), new_st
+
+
+# -- python -m ddp_tpu.models.moe ------------------------------------------------
+
+# (name, form, hidden, expert width, router experts, top_k) of the two
+# routing cells' expert layers, 8 experts held each, at the cells' 16,384
+# tokens a step; and what a process without a TPU runs instead.
+SELF_CHECK_LAYERS = (("nemotron_h", RELU2, 2688, 1856, 128, 6),
+                     ("glm4_moe_lite", SWIGLU, 2048, 1536, 64, 4))
+SELF_CHECK_TINY = (("relu2", RELU2, 128, 256, 32, 4),
+                   ("swiglu", SWIGLU, 128, 256, 16, 2))
+SELF_CHECK_SHARES = (0.25, 0.5, 1.0)
+
+
+def _every_tile(form, cd, rows, stacks, tile_expert, live):
+    """What :func:`tile_products` replaced, kept as the probe's yardstick:
+    every tile of the buffer multiplied, live or not."""
+    del live
+    low = tuple(s.astype(cd) for s in stacks)
+    return lax.map(
+        lambda a: form.fn(jnp.dot, a[0], lambda j: low[j][a[1]]),
+        (rows, tile_expert))
+
+
+def _probe_operands(form, d, h, count, tiles, tile, live, cd):
+    """A buffer whose first ``live`` tiles hold rows, shared evenly among
+    the experts in order, and the cotangent the layer would send back
+    (zero wherever the rows are padding)."""
+    keys = jax.random.split(jax.random.key(live), 2 + len(form.routed))
+    holds = (jnp.arange(tiles) < live)[:, None, None]
+    rows, g = (jnp.where(holds, jax.random.normal(k, (tiles, tile, d), F32),
+                         0).astype(cd) for k in keys[:2])
+    stacks = tuple(
+        0.02 * jax.random.normal(
+            k, (count, h, d) if name == "down" else (count, d, h), F32)
+        for k, name in zip(keys[2:], form.routed))
+    tile_expert = jnp.minimum(jnp.arange(tiles) * count // max(live, 1),
+                              count - 1).astype(jnp.int32)
+    return rows, stacks, tile_expert, jnp.int32(live), g
+
+
+def _self_check() -> None:
+    """The held experts' products alone, forward and with their backward,
+    at both routing cells' shapes (on a TPU; elsewhere tiny ones) with a
+    quarter, a half and all of the buffer live, against the form that
+    computes every tile: that the results are the same, and on a TPU the
+    milliseconds of both.  Raises where a result differs or, on a TPU, the
+    live loop over a FULL buffer costs a step (forward, then forward and
+    backward) over 3% more than the every-tile form."""
+    from ..ops.attention import _ms, _rel
+    from ..parallel.mesh import make_mesh
+    from ..utils.platform import device_line, enable_compile_cache
+
+    enable_compile_cache()
+    print(device_line(make_mesh()), flush=True)
+    on_chip = jax.default_backend() == "tpu"
+    cd, count = jnp.bfloat16, 8
+    tile, n_tok = (MOE_ROW_TILE, 16384) if on_chip else (16, 256)
+    for name, form, d, h, router, k in (SELF_CHECK_LAYERS if on_chip
+                                        else SELF_CHECK_TINY):
+        tiles = buffer_tiles(n_tok, {"top_k": k, "count": count,
+                                     "router": router}, tile)
+        print(f"moe: {name} d={d} expert={h} held={count} tiles={tiles} x "
+              f"{tile} rows, {len(form.routed)} matrices an expert, "
+              f"{jnp.dtype(cd).name}", flush=True)
+        if on_chip:
+            print("moe:   live tiles     ms forward: live, every tile   "
+                  "ms forward+backward: live, every tile")
+        for share in SELF_CHECK_SHARES:
+            live = round(share * tiles)
+            *args, g = _probe_operands(form, d, h, count, tiles, tile, live,
+                                       cd)
+            fwd = {n: jax.jit(functools.partial(f, form, cd))
+                   for n, f in (("live", tile_products),
+                                ("every", _every_tile))}
+            both = {n: jax.jit(lambda rows, stacks, te, lv, g, f=f: jax.vjp(
+                lambda r, s: f(r, s, te, lv), rows, stacks)[1](g))
+                for n, f in fwd.items()}
+            if not jnp.array_equal(fwd["live"](*args), fwd["every"](*args)):
+                raise RuntimeError(
+                    f"moe: {name} at {live} live tiles of {tiles}: the live "
+                    "loop's result is not the every-tile form's")
+            far = max(_rel(a, b) for a, b in zip(
+                *(jax.tree_util.tree_leaves(both[n](*args, g))
+                  for n in ("live", "every"))))
+            # The every-tile form adds a tile's weight gradient to its
+            # expert's in ``cd``, the live loop in float32.
+            if far > 0.01:
+                raise RuntimeError(
+                    f"moe: {name} at {live} live tiles of {tiles}: a "
+                    f"gradient is {far:.5f} from the every-tile form's")
+            if not on_chip:
+                print(f"moe:   {live:>3} of {tiles}: result equal, "
+                      f"gradients within {far:.5f}", flush=True)
+                continue
+            ms = [_ms(fn[n], *a) for fn, a in ((fwd, args), (both, (*args, g)))
+                  for n in ("live", "every")]
+            print(f"moe:   {live:>3} of {tiles}" + " " * 14
+                  + f"{ms[0]:9.2f}{ms[1]:9.2f}" + " " * 15
+                  + f"{ms[2]:9.2f}{ms[3]:9.2f}   gradients within "
+                  f"{far:.5f}", flush=True)
+            # What a step pays a layer: the forward, and under the block's
+            # checkpoint the forward again with the backward.
+            paid = ms[0] + ms[2], ms[1] + ms[3]
+            if live == tiles and paid[0] > 1.03 * paid[1]:
+                raise RuntimeError(
+                    f"moe: {name} with every tile live costs {paid[0]:.2f} "
+                    f"ms a step and layer against the every-tile form's "
+                    f"{paid[1]:.2f}: over 3% more")
+    print(f"moe: ok platform={jax.default_backend()} the live loop's "
+          "results are the every-tile form's at "
+          + ", ".join(f"{s:.0%}" for s in SELF_CHECK_SHARES) + " live",
+          flush=True)
+
+
+if __name__ == "__main__":
+    from ddp_tpu.models import moe
+    moe._self_check()

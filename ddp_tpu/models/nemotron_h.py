@@ -22,7 +22,8 @@ character of ``hybrid_override_pattern``:
   ``num_experts_per_tok`` largest ``s + b`` chosen; this chip computes
   the shared expert and the weighted results of the chosen experts it
   holds (``experts_held = [first, count]``), a tile of rows at a time over
-  row tiles sorted by expert: the layer of models/moe.py, which
+  the row tiles, sorted by expert, that hold a row (so the layer's time
+  follows the held experts' load): the layer of models/moe.py, which
   ``glm4_moe_lite`` shares, with this model's expert (two matrices around
   ``relu2``).  Dropless while the held experts' load is within
   ``moe.MOE_LOAD_HEADROOM`` times what uniform routing sends here;
@@ -33,9 +34,10 @@ float32 accumulation; the router, the softmax, ``dt``, ``A``, the state
 recurrence over chunks and every norm's statistics in float32.
 
 The model's state pytree holds, per expert layer, the router's
-``e_bias`` (``e_score_correction_bias``: state, not trained) and two
-integer counters, ``assignments`` (to each held expert) and ``dropped``,
-which a training step adds to (train/step.py sums integer state over
+``e_bias`` (``e_score_correction_bias``: state, not trained) and three
+integer counters, ``assignments`` (to each held expert), ``dropped`` and
+``live_tiles`` (row tiles that held a row: the tiles multiplied), which
+a training step adds to (train/step.py sums integer state over
 replicas; the Trainer exports them where it flushes losses).
 """
 from __future__ import annotations
@@ -335,7 +337,9 @@ def build(config: dict):
                     # choice (or putting it into the weights) shows.
                     "e_bias": normal((dm["router"],), 0.05),
                     "assignments": jnp.zeros((dm["count"],), jnp.int32),
-                    "dropped": jnp.zeros((), jnp.int32)}
+                    "dropped": jnp.zeros((), jnp.int32),
+                    "live_tiles": jnp.zeros((), jnp.int32),
+                    "buffer_tiles": jnp.zeros((), jnp.int32)}
             layers[layer_name(i)] = p
         # Embeddings at the residual stream's scale (torch's nn.Embedding
         # default): at 0.02 a token's identity is drowned by the first

@@ -24,7 +24,8 @@ spans.  A missing or torn bundle exits 2 with a one-line diagnosis.
 ``--prom RUN.prom`` is a third mode (no spill needed either): it parses
 a run's end-of-run registry exposition (``<metrics>.prom``) and prints an
 expert model's routing counters a layer — assignments to each expert held
-here, the busiest over the mean, and the assignments dropped
+here, the busiest over the mean, the assignments dropped, and the row
+tiles that held a row with the share of the buffer they are
 (obs/routing.py).
 
 Multi-host runs spill one file per host (``--trace_spill`` path plus

@@ -1,9 +1,11 @@
 """Routing counters of an expert model, host side.
 
 The device side counts inside the step: per expert layer the model's
-state pytree carries ``assignments`` (to each expert held here) and
-``dropped`` (assignments that found no room), integer and cumulative
-(models/nemotron_h.py; train/step.py sums integer state over replicas).
+state pytree carries ``assignments`` (to each expert held here),
+``dropped`` (assignments that found no room), ``live_tiles`` (row tiles
+that held a row: the tiles the experts' products visited) and
+``buffer_tiles`` (row tiles the buffer offered), integer and cumulative
+(models/moe.py; train/step.py sums integer state over replicas).
 The Trainer hands a copy of those leaves to :meth:`RoutingCounters.update`
 where it flushes an epoch's losses, so no step pays a device read.
 
@@ -13,6 +15,10 @@ Exported through the run's registry (obs/registry.py):
     ddp_moe_dropped_total{layer}              counter
     ddp_moe_load_max_over_mean{layer}         gauge: the busiest held
         expert's assignments over the mean, since the run began
+    ddp_moe_live_tiles_total{layer}           counter
+    ddp_moe_live_tile_share{layer}            gauge: live tiles over the
+        tiles the buffer offered (a step's buffer on every replica), since
+        the run began: the share of the buffer the products multiply
 
 ``expert`` is the index among the experts held here (the router's id is
 ``experts_held[0]`` more).  ``python -m ddp_tpu.obs --prom FILE`` prints
@@ -27,7 +33,11 @@ from typing import Dict, Optional
 import numpy as np
 
 FAMILIES = ("ddp_moe_assignments_total", "ddp_moe_dropped_total",
-            "ddp_moe_load_max_over_mean")
+            "ddp_moe_load_max_over_mean", "ddp_moe_live_tiles_total",
+            "ddp_moe_live_tile_share")
+# The scalar counters of a layer's state beside ``assignments``; a state
+# written before PR 38 has the first alone.
+_SCALARS = ("dropped", "live_tiles", "buffer_tiles")
 
 
 def _u32(x) -> np.ndarray:
@@ -43,6 +53,7 @@ class RoutingCounters:
         self.totals: Dict[str, dict] = {}
         self._last = baseline or {}
         self._assign = self._dropped = self._load = None
+        self._live = self._share = None
         if registry is not None:
             self._assign = registry.counter(
                 FAMILIES[0], "Assignments routed to an expert held here",
@@ -53,29 +64,48 @@ class RoutingCounters:
             self._load = registry.gauge(
                 FAMILIES[2], "Busiest held expert's assignments over the "
                 "mean, since the run began", ("layer",))
+            self._live = registry.counter(
+                FAMILIES[3], "Row tiles that held a row: the tiles the "
+                "experts' products visited", ("layer",))
+            self._share = registry.gauge(
+                FAMILIES[4], "Live row tiles over the tiles the buffer "
+                "offered, since the run began", ("layer",))
 
     def update(self, counters: dict) -> None:
-        """``counters``: layer -> {"assignments": int[E], "dropped": int},
-        as read from the device."""
+        """``counters``: layer -> {"assignments": int[E], "dropped": int,
+        "live_tiles": int, "buffer_tiles": int}, as read from the
+        device."""
         for layer, now in counters.items():
             last = self._last.get(layer, {})
             d_assign = (_u32(now["assignments"])
                         - _u32(last.get("assignments", 0))).astype(np.int64)
-            d_drop = int((_u32(now["dropped"])
-                          - _u32(last.get("dropped", 0))).astype(np.int64))
+            delta = {name: int((_u32(now.get(name, 0))
+                                - _u32(last.get(name, 0))).astype(np.int64))
+                     for name in _SCALARS}
             self._last[layer] = now
             tot = self.totals.setdefault(
                 layer, {"assignments": np.zeros_like(d_assign),
-                        "dropped": 0})
+                        **dict.fromkeys(_SCALARS, 0)})
             tot["assignments"] = tot["assignments"] + d_assign
-            tot["dropped"] += d_drop
+            for name in _SCALARS:
+                tot[name] += delta[name]
             if self._assign is not None:
                 for j, n in enumerate(d_assign):
                     self._assign.labels(layer=layer, expert=str(j)).inc(
                         float(n))
-                self._dropped.labels(layer=layer).inc(float(d_drop))
+                self._dropped.labels(layer=layer).inc(
+                    float(delta["dropped"]))
                 self._load.labels(layer=layer).set(
                     load_max_over_mean(tot["assignments"]))
+                self._live.labels(layer=layer).inc(
+                    float(delta["live_tiles"]))
+                self._share.labels(layer=layer).set(live_tile_share(tot))
+
+
+def live_tile_share(totals: dict) -> float:
+    """Live tiles over the tiles offered; 0.0 where none was offered."""
+    offered = totals["buffer_tiles"]
+    return totals["live_tiles"] / offered if offered else 0.0
 
 
 def load_max_over_mean(assignments) -> float:
@@ -93,20 +123,26 @@ def format_routing(families: dict) -> str:
     layers = sorted(samples(FAMILIES[0]))
     if not layers:
         return "no routing counters (ddp_moe_*) in this exposition"
+
+    def of_layer(family, layer):
+        return sum(v for (_n, labels), v in
+                   families.get(family, {}).get("samples", {}).items()
+                   if dict(labels)["layer"] == layer)
+
     lines = [f"{'layer':<10} {'assigned':>10} {'dropped':>8} "
-             f"{'max/mean':>8}  assignments by expert held"]
+             f"{'max/mean':>8} {'live tiles':>10} {'of buffer':>9}  "
+             "assignments by expert held"]
     for layer in layers:
         by_expert = sorted(
             (int(dict(labels)["expert"]), int(v)) for (_n, labels), v in
             families[FAMILIES[0]]["samples"].items()
             if dict(labels)["layer"] == layer)
         counts = [v for _j, v in by_expert]
-        dropped = sum(int(v) for (_n, labels), v in
-                      families.get(FAMILIES[1], {}).get("samples",
-                                                        {}).items()
-                      if dict(labels)["layer"] == layer)
-        lines.append(f"{layer:<10} {sum(counts):>10} {dropped:>8} "
-                     f"{load_max_over_mean(counts):>8.2f}  "
+        lines.append(f"{layer:<10} {sum(counts):>10} "
+                     f"{int(of_layer(FAMILIES[1], layer)):>8} "
+                     f"{load_max_over_mean(counts):>8.2f} "
+                     f"{int(of_layer(FAMILIES[3], layer)):>10} "
+                     f"{of_layer(FAMILIES[4], layer):>9.1%}  "
                      + " ".join(map(str, counts)))
     return "\n".join(lines)
 

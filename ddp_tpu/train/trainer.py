@@ -31,6 +31,7 @@ TPU analogue of ``pin_memory=True`` + worker prefetch (singlegpu.py:177).
 """
 from __future__ import annotations
 
+import contextlib
 import sys
 import threading
 import time
@@ -95,6 +96,25 @@ def _counters(model_state):
         elif jnp.issubdtype(getattr(sub, "dtype", jnp.float32),
                             jnp.integer):
             out[key] = sub
+    return out
+
+
+def _with_new_counters(loaded, like):
+    """A checkpoint's model state with the counters it lacks: an integer
+    leaf that ``like`` (this program's state) has and the file does not is
+    a counter added since the file was written, and starts at zero
+    (MIGRATING.md).  Nothing else is filled: any other difference still
+    fails where the step is traced."""
+    if not isinstance(loaded, dict) or not isinstance(like, dict):
+        return loaded
+    out = dict(loaded)
+    for key, sub in like.items():
+        if isinstance(sub, dict):
+            if key in loaded:
+                out[key] = _with_new_counters(loaded[key], sub)
+        elif key not in loaded and jnp.issubdtype(
+                getattr(sub, "dtype", jnp.float32), jnp.integer):
+            out[key] = jnp.zeros_like(sub)
     return out
 
 
@@ -284,7 +304,8 @@ class Trainer:
                 ckpt, used = loaded
                 self.state = TrainState(
                     jax.tree_util.tree_map(jnp.asarray, ckpt.params),
-                    jax.tree_util.tree_map(jnp.asarray, ckpt.batch_stats),
+                    jax.tree_util.tree_map(jnp.asarray, _with_new_counters(
+                        ckpt.batch_stats, batch_stats)),
                     jax.tree_util.tree_map(jnp.asarray, ckpt.opt_state),
                     jnp.asarray(ckpt.step, jnp.int32))
                 ds = ckpt.data_state
@@ -718,8 +739,14 @@ class Trainer:
         riders = _counters(self.state.batch_stats)
         if self._depth_losses:
             riders[LM_LOSS] = self.state.batch_stats[LM_LOSS]
-        self._pending_counters[start_step] = jax.tree_util.tree_map(
-            jnp.copy, riders)
+        # Each copy is a program of its own queued behind the steps in
+        # flight, and the host's wait for them lands on whichever program
+        # overflows the device's queue (the stack of the losses, or one of
+        # these): the boundary's span covers them too.
+        with (self.tracer.span("epoch_close", step=start_step) if riders
+              else contextlib.nullcontext()):
+            self._pending_counters[start_step] = jax.tree_util.tree_map(
+                jnp.copy, riders)
         prev, self._pending_losses = (self._pending_losses,
                                       (epoch, start_step, stacked))
         if prev is not None:
@@ -1004,7 +1031,8 @@ class Trainer:
         ckpt, used = loaded
         state = TrainState(
             jax.tree_util.tree_map(jnp.asarray, ckpt.params),
-            jax.tree_util.tree_map(jnp.asarray, ckpt.batch_stats),
+            jax.tree_util.tree_map(jnp.asarray, _with_new_counters(
+                ckpt.batch_stats, self.state.batch_stats)),
             jax.tree_util.tree_map(jnp.asarray, ckpt.opt_state),
             jnp.asarray(ckpt.step, jnp.int32))
         if self.tp_plan is not None and self.pp_plan is None:

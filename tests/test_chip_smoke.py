@@ -14,7 +14,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
     """The real commands as children — gather check, the attention kernel's
     and both models' scan kernels' checks (off the chip: through the
-    interpreter),
+    interpreter), the routed experts' products over the live row tiles
+    against the every-tile form,
     a step of the SambaY stage (off the chip: at the tiny width alone),
     train + checkpoint + eval, the same train again adding nothing to the
     compile cache,
@@ -28,8 +29,8 @@ def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     result = chip_smoke.run_smoke(
         "cpu", model="deepnn", batch=8, out=str(tmp_path / "out"),
-        phases=("gather", "attention", "ssd", "selscan", "sambay", "train",
-                "train_again", "serve", "resume"),
+        phases=("gather", "attention", "ssd", "selscan", "moe", "sambay",
+                "train", "train_again", "serve", "resume"),
         loss_band=(2.0, 2.7))
     assert result == {"ok": True, "device": {"platform": "cpu",
                                              "kind": "cpu", "count": 1}}

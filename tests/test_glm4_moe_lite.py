@@ -771,11 +771,15 @@ def test_the_parent_fails_the_new_cell_at_once():
 # the PR that lifted the expert layer into models/moe.py (c2e02eb), at the
 # tiny presets on a 2-device mesh: a refactor of the shared layer, of the
 # loss core or of ops/seq.py that changes either program shows here.
+# ``nemotron_h``'s two are PR 38's program, which changed by design (the
+# experts' products became a loop over the live row tiles with a backward
+# rule of its own, and the layer's state two counters): re-pinned there;
+# ``sambay``'s, which runs no expert layer, are still the parent's.
 PARENTS_STEP = {
     ("nemotron_h", "f32"):
-        "12b1360e1f20d59434da37e013f1fa8389377ad1136495f82d77f821f6aa30cf",
+        "b5d4725bb693fdf8e51a160aa77a3cb58088578e27ed9a858a0fd18e8ed06fdb",
     ("nemotron_h", "bf16"):
-        "fa31b65ef848eb6c0bd1d6bc1685a103c689e40af7ae9ad52a033bd56610c1d4",
+        "485a423c33937d729b82d2b8cdc3ffb84347bcb7ce8381f06907511a7cbf81ee",
     ("sambay", "f32"):
         "893a90a8584e719f21a44c58861f0d26e9a74e3d12663fad7b77e3eee4507d33",
     ("sambay", "bf16"):

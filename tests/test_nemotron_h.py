@@ -373,7 +373,7 @@ def test_row_plan_places_every_assignment_once(load):
            "one_expert": np.full(40, 2),
            "none_here": np.full(40, 4)}[load].astype(np.int32)
     sizes, src, tile_expert, dropped = map(np.asarray, moe.row_plan(
-        jnp.asarray(key), count, tile, tiles))
+        jnp.asarray(key), count, tile, tiles)[:4])
     here = np.flatnonzero(key < count)
     assert sizes.tolist() == [int((key == e).sum()) for e in range(count)]
     assert int(dropped) == 0
