@@ -3,11 +3,13 @@ die in VMEM.  Two forms of one algorithm (online softmax over the visited
 key tiles forward, keys-first backward from the saved log-sum-exp), chosen
 by what the input is (:func:`kernel_applies`), never by a switch:
 
-``causal_gqa(q [P,R,T,hd], k [P,T,hd], v [P,T,hd], scale)``: ``P`` is
-(sequence, key-value head) pairs and ``R`` the query heads that share a
-key-value head; keys and values are whole lanes of equal width
-(``nemotron_h``'s ``attn_core`` at 128, ``glm4_moe_lite``'s ``mla_core``
-at 256 with ``R`` = 1).  Nothing is repeated ``R`` times.  In plain XLA
+``causal_gqa(q [P,R,T,hd], k [P,T,hd], v [P,T,hd], scale, window=None)``:
+``P`` is (sequence, key-value head) pairs and ``R`` the query heads that
+share a key-value head; keys and values are whole lanes of equal width
+(``nemotron_h``'s ``attn_core`` at 128 with ``R`` = 16,
+``glm4_moe_lite``'s ``mla_core`` at 256 with ``R`` = 1, ``laguna``'s
+``full_core`` at 128 with ``R`` = 6 and its ``swa_core`` with ``R`` = 8
+under a window of 512).  Nothing is repeated ``R`` times.  In plain XLA
 (models/nemotron_h.py ``_attend``) every block of float32 scores is
 written to HBM, read by the softmax, written again as probabilities and
 read by the values' product, forward, under the checkpoint and backward:
@@ -62,11 +64,13 @@ queries and keys against ONE 128-wide value, causal or over a query's last
   pair's keys and lands in its own 64 lanes of dQ, and against its zeroed
   queries into its own 64 lanes of dK.  ``lam``'s gradient is the sum of
   the second map's ``sum(dO * o)`` rows, which the kernel writes.
-- **A lower key bound.**  With a static ``window`` :func:`tile_kinds` also
-  gives the first tile that holds a visible key and the tiles that cross
-  the window's lower edge (masked ``key > query - window``): the window
-  layer visits two tiles of 512 a query block, not sixteen.  ``window=
-  None`` walks exactly the tiles ``causal_gqa`` walks.
+- **A lower key bound**, which ``causal_gqa`` takes too.  With a static
+  ``window`` :func:`tile_kinds` also gives the first tile that holds a
+  visible key and the tiles that cross the window's lower edge (masked
+  ``key > query - window``): a window layer visits two tiles of 512 a
+  query block, not up to sixteen.  ``window=None`` walks the causal
+  tiles, and ``causal_gqa``'s bodies are then what they were before the
+  window (tests/test_attention_kernel.py holds them to a golden).
 
 Precision is the XLA loops': operands as they come, float32 accumulation
 and softmax statistics, ``scale`` applied to the float32 scores,
@@ -219,7 +223,7 @@ def _walk(i, bq: int, bkv: int, step, window: Optional[int] = None):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale: float, bq: int, bkv: int):
+                *, scale: float, bq: int, bkv: int, window):
     i = pl.program_id(2)
     q = q_ref[...]
     hd = q.shape[-1]
@@ -232,7 +236,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         s = lax.dot_general(q, k_ref[ks, :], _NT,
                             preferred_element_type=F32) * scale
         if masked:
-            s = jnp.where(_visible(i, j, bq, bkv, False), s, _MASKED)
+            s = jnp.where(_visible(i, j, bq, bkv, False, window), s, _MASKED)
         m_prev, l_prev = m_ref[...], l_ref[...]          # [bq, 128]
         m_next = jnp.maximum(m_prev, s.max(axis=1)[:, None])
         p = jnp.exp(s - jnp.tile(m_next, (1, bkv // _LANE)))
@@ -243,7 +247,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = jnp.tile(alpha, (1, hd // _LANE)) * acc_ref[...] \
             + jnp.dot(p.astype(v.dtype), v, preferred_element_type=F32)
 
-    _walk(i, bq, bkv, step)
+    _walk(i, bq, bkv, step, window)
     l = l_ref[...]
     o_ref[...] = (acc_ref[...] / jnp.tile(l, (1, hd // _LANE))).astype(
         o_ref.dtype)
@@ -254,7 +258,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                *, scale: float, bq: int, bkv: int):
+                *, scale: float, bq: int, bkv: int, window):
     r, i = pl.program_id(1), pl.program_id(2)
     last = (r == pl.num_programs(1) - 1) & (i == pl.num_programs(2) - 1)
 
@@ -272,7 +276,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         k, v = k_ref[ks, :], v_ref[ks, :]                # [bkv, hd]
         s = lax.dot_general(k, q, _NT, preferred_element_type=F32) * scale
         if masked:
-            s = jnp.where(_visible(i, j, bq, bkv, True), s, _MASKED)
+            s = jnp.where(_visible(i, j, bq, bkv, True, window), s, _MASKED)
         p = jnp.exp(s - lse)                             # [bkv, bq]
         dv_acc[ks, :] += jnp.dot(p.astype(do.dtype), do,
                                  preferred_element_type=F32)
@@ -282,7 +286,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dq_acc[...] += lax.dot_general(ds, k, _TN,
                                        preferred_element_type=F32)
 
-    _walk(i, bq, bkv, step)
+    _walk(i, bq, bkv, step, window)
     dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
     @pl.when(last)
@@ -324,14 +328,15 @@ def _params():
 
 
 def _forward(q, k, v, scale: float, blocks: Tuple[int, int],
-             interpret: bool):
+             interpret: bool, window: Optional[int] = None):
     """``(o [P,R,T,hd], lse f32[P,R,1,T])``."""
     bq, bkv = blocks
     p, r, t, hd = _check(q, k, v, bq, bkv)
     rows, stat, whole = _specs(t, hd, bq)
     vma = _varies(q, k, v)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv),
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          window=window),
         grid=(p, r, t // bq),
         in_specs=[rows, whole, whole],
         out_specs=[rows, stat],
@@ -347,7 +352,7 @@ def _forward(q, k, v, scale: float, blocks: Tuple[int, int],
 
 
 def _backward(q, k, v, o, lse, do, scale: float, blocks: Tuple[int, int],
-              interpret: bool):
+              interpret: bool, window: Optional[int] = None):
     """``(dq, dk, dv)``; ``dk`` and ``dv`` are summed over the ``R``
     heads inside the kernel."""
     bq, bkv = blocks
@@ -356,7 +361,8 @@ def _backward(q, k, v, o, lse, do, scale: float, blocks: Tuple[int, int],
     di = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1)[:, :, None, :]
     vma = _varies(q, k, v, do, lse)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, bq=bq, bkv=bkv),
+        functools.partial(_bwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          window=window),
         grid=(p, r, t // bq),
         in_specs=[rows, whole, whole, rows, stat, stat],
         out_specs=[rows, whole, whole],
@@ -372,22 +378,26 @@ def _backward(q, k, v, o, lse, do, scale: float, blocks: Tuple[int, int],
     )(q, k, v, do, lse, di)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def causal_gqa(q, k, v, scale: float, interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_gqa(q, k, v, scale: float, interpret: bool = False,
+               window: Optional[int] = None):
     """Causal attention of ``R`` query heads against the one key-value
     head they share, for ``P`` (sequence, key-value head) pairs: ``q``
-    [P,R,T,hd], ``k``/``v`` [P,T,hd] -> [P,R,T,hd].  ``interpret`` runs
-    the kernels in the Pallas interpreter (the CPU tests)."""
-    return _forward(q, k, v, scale, FWD_BLOCKS, interpret)[0]
+    [P,R,T,hd], ``k``/``v`` [P,T,hd] -> [P,R,T,hd].  Under a static
+    ``window`` a query sees its last ``window`` keys, itself counted, and
+    a query block walks only the tiles that hold one (:func:`tile_kinds`).
+    ``interpret`` runs the kernels in the Pallas interpreter (the CPU
+    tests)."""
+    return _forward(q, k, v, scale, FWD_BLOCKS, interpret, window)[0]
 
 
-def _causal_gqa_fwd(q, k, v, scale, interpret):
-    o, lse = _forward(q, k, v, scale, FWD_BLOCKS, interpret)
+def _causal_gqa_fwd(q, k, v, scale, interpret, window):
+    o, lse = _forward(q, k, v, scale, FWD_BLOCKS, interpret, window)
     return o, (q, k, v, o, lse)
 
 
-def _causal_gqa_bwd(scale, interpret, res, do):
-    return _backward(*res, do, scale, BWD_BLOCKS, interpret)
+def _causal_gqa_bwd(scale, interpret, window, res, do):
+    return _backward(*res, do, scale, BWD_BLOCKS, interpret, window)
 
 
 causal_gqa.defvjp(_causal_gqa_fwd, _causal_gqa_bwd)
@@ -700,10 +710,17 @@ SWEEP = tuple((bq, bkv) for bq in (256, 512, 1024)
               for bkv in (256, 512, 1024))
 
 
-def _xla_path(q, k, v, scale: float, cd):
-    """The loop ``attention_mixer`` runs where the kernel does not apply."""
-    from ..models.nemotron_h import _attend_head
-    return lax.map(lambda a: _attend_head(*a, scale=scale, cd=cd), (q, k, v))
+def _xla_path(q, k, v, scale: float, cd, window: Optional[int] = None):
+    """The loop the cores run where the kernel does not apply:
+    ``nemotron_h.attention_mixer``'s causal, ``laguna``'s under a
+    window."""
+    if window is None:
+        from ..models.nemotron_h import _attend_head
+        return lax.map(lambda a: _attend_head(*a, scale=scale, cd=cd),
+                       (q, k, v))
+    from ..models.laguna import _attend_head
+    return lax.map(lambda a: _attend_head(*a, scale=scale, cd=cd,
+                                          window=window), (q, k, v))
 
 
 def _shipped(r: int, t: int, scale: float, fwd, bwd):
@@ -752,6 +769,15 @@ SELF_CHECK_SHAPES = (((4, 16, 8192, 128), (2, 4, 1024, 128)),
 DIFF_SELF_CHECK_SHAPES = ((2, 8192, 20, 10), (1, 1024, 4, 2))
 DIFF_WINDOW = 512
 
+# (pairs, query heads a pair, tokens, head width, window) of the fourth
+# token cell's two cores (2 sequences x 8 key-value heads): the window
+# layer's R = 8 under 512 and the full layer's R = 6; and what a process
+# without a TPU runs instead (a window that is no whole block).
+GQA_WINDOW_SELF_CHECK_SHAPES = (((16, 8, 8192, 128, 512),
+                                 (2, 4, 1024, 128, 384)),
+                                ((16, 6, 8192, 128, None),
+                                 (2, 3, 1024, 128, None)))
+
 
 def _self_check() -> None:
     """On a TPU, at each cell's shape (:data:`SELF_CHECK_SHAPES`, bf16):
@@ -774,6 +800,10 @@ def _self_check() -> None:
     for i, shapes in enumerate(SELF_CHECK_SHAPES):
         _check_shape(*shapes[0 if on_chip else 1], on_chip=on_chip,
                      yardsticks=on_chip and i == 0)
+    for shapes in GQA_WINDOW_SELF_CHECK_SHAPES:
+        *shape, window = shapes[0 if on_chip else 1]
+        _check_shape(*shape, on_chip=on_chip, yardsticks=False,
+                     window=window)
     for window in (None, DIFF_WINDOW):
         _check_diff_shape(*DIFF_SELF_CHECK_SHAPES[0 if on_chip else 1],
                           window=window, on_chip=on_chip)
@@ -829,23 +859,58 @@ def _self_check() -> None:
             != (2, 0):
         raise RuntimeError("sambay.diff_core did not take the kernel at the "
                            "second token cell's shape on a TPU")
+    _trace_laguna(on_chip, cd, d)
     print(f"attention: ok kernel={'pallas' if on_chip else 'interpret'} "
           + " ".join(f"P={a} R={b} T={c} hd={e}" for a, b, c, e in (
               s[0 if on_chip else 1] for s in SELF_CHECK_SHAPES))
+          + "".join(f" P={a} R={b} T={c} hd={e} window={w}"
+                     for a, b, c, e, w in (
+                         s[0 if on_chip else 1]
+                         for s in GQA_WINDOW_SELF_CHECK_SHAPES))
           + f" two maps B={bsz} T={t3} pairs={pairs} over {kvp} 64|128"
           + " within a quarter of the XLA path's distance from float32",
           flush=True)
 
 
+def _trace_laguna(on_chip: bool, cd, d: int) -> None:
+    """Which path ``laguna.gated_attention`` traces each kind of core
+    through at the fourth token cell's shapes; on a TPU both must take the
+    kernel."""
+    from ..models import laguna
+    rope = {"rope_theta": 1e4, "partial_rotary_factor": 1}
+    for (p, r, t, hd, window), kind in zip(
+            (s[0 if on_chip else 1] for s in GQA_WINDOW_SELF_CHECK_SHAPES),
+            ("swa", "full")):
+        dm = {"kv_heads": p // 2, "hd": hd, "window": window}
+        heads = r * p // 2
+        weights = {name: jax.ShapeDtypeStruct(shape, F32) for name, shape in (
+            ("q", (d, heads * hd)), ("k", (d, p // 2 * hd)),
+            ("v", (d, p // 2 * hd)), ("o", (heads * hd, d)),
+            ("head_gate", (d, heads)))}
+        jax.eval_shape(
+            lambda w, x: laguna.gated_attention(
+                w, x, kind, dm, cd, {kind: laguna.rope_tables(t, rope, hd)}),
+            weights, jax.ShapeDtypeStruct((2, t, d), cd))
+    print(f"attention: laguna's cores traced through {laguna.TRACED}",
+          flush=True)
+    if on_chip and (laguna.TRACED["full_kernel"], laguna.TRACED["swa_kernel"],
+                    laguna.TRACED["full_xla"], laguna.TRACED["swa_xla"]) \
+            != (1, 1, 0, 0):
+        raise RuntimeError("laguna.gated_attention did not take the kernel "
+                           "for both kinds of core at the fourth token "
+                           "cell's shapes on a TPU")
+
+
 def _check_shape(p: int, r: int, t: int, hd: int, *, on_chip: bool,
-                 yardsticks: bool) -> None:
+                 yardsticks: bool, window: Optional[int] = None) -> None:
     cd, scale = jnp.bfloat16, 1.0 / math.sqrt(hd)
     keys = jax.random.split(jax.random.key(0), 4)
     q, w = (jax.random.normal(key, (p, r, t, hd), F32)
             for key in (keys[0], keys[3]))
     k, v = (jax.random.normal(key, (p, t, hd), F32) for key in keys[1:3])
-    print(f"attention: P={p} R={r} T={t} hd={hd} {jnp.dtype(cd).name} "
-          f"blocks fwd={FWD_BLOCKS} bwd={BWD_BLOCKS}", flush=True)
+    print(f"attention: P={p} R={r} T={t} hd={hd} window={window} "
+          f"{jnp.dtype(cd).name} blocks fwd={FWD_BLOCKS} bwd={BWD_BLOCKS}",
+          flush=True)
 
     def vjp_of(path):
         def run(q, k, v, do):
@@ -854,12 +919,13 @@ def _check_shape(p: int, r: int, t: int, hd: int, *, on_chip: bool,
         return run
 
     forward = {"kernel": lambda q, k, v: causal_gqa(q, k, v, scale,
-                                                    not on_chip),
-               "xla": functools.partial(_xla_path, scale=scale, cd=cd)}
+                                                    not on_chip, window),
+               "xla": functools.partial(_xla_path, scale=scale, cd=cd,
+                                        window=window)}
     low = tuple(a.astype(cd) for a in (q, k, v, w))
     with jax.default_matmul_precision("highest"):
         exact = jax.jit(vjp_of(functools.partial(
-            _xla_path, scale=scale, cd=F32)))(q, k, v, w)
+            _xla_path, scale=scale, cd=F32, window=window)))(q, k, v, w)
     dist = {name: [_rel(a, b) for a, b in zip(jax.jit(vjp_of(fn))(*low),
                                               exact)]
             for name, fn in forward.items()}
@@ -885,8 +951,8 @@ def _check_shape(p: int, r: int, t: int, hd: int, *, on_chip: bool,
               f"{_ms(jax.jit(vjp_of(fn)), *low):11.2f}", flush=True)
     if not yardsticks:
         return
-    _, res = jax.jit(_causal_gqa_fwd, static_argnums=(3, 4))(
-        *low[:3], scale, False)
+    _, res = jax.jit(_causal_gqa_fwd, static_argnums=(3, 4, 5))(
+        *low[:3], scale, False, window)
     print("attention: sweep (query block, key tile): ms forward, "
           "ms backward")
     for blocks in SWEEP:

@@ -1,9 +1,10 @@
 """What the token models' mixers share, in plain XLA: RMSNorm
-(``models/nemotron_h.py``, ``models/glm4_moe_lite.py``), the causal
-depthwise convolution of a state-space mixer and the blocked keys-first
-attention loop (``nemotron_h`` and ``glm4_moe_lite`` where the kernel does
-not apply, ``models/sambay.py`` always).  ``ops/__init__.py`` does not import this
-module: a classifier's process never sees it.
+(``models/nemotron_h.py``, ``models/glm4_moe_lite.py``,
+``models/laguna.py``), the causal depthwise convolution of a state-space
+mixer and the blocked keys-first attention loop, causal or under a window
+(``nemotron_h``, ``glm4_moe_lite`` and ``laguna`` where the kernel does
+not apply, ``models/sambay.py`` always).  ``ops/__init__.py`` does not
+import this module: a classifier's process never sees it.
 """
 from __future__ import annotations
 
@@ -58,22 +59,28 @@ def block_probs(q, k, *, start: int, scale: float, lo: int = 0,
     return jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=0)
 
 
-def attend(q, k, v, *, start: int, scale: float, cd):
+def attend(q, k, v, *, start: int, scale: float, cd, **seen):
     """One key-value head of one sequence: ``softmax(q k^T) v`` for a
-    block of queries against the keys up to their own position.  ``v``
-    [S,vd] may be wider than the keys; the result is [R,bq,vd]."""
+    block of queries against the keys up to their own position (``seen``:
+    :func:`block_probs`'s ``lo`` and ``window``).  ``v`` [S,vd] may be
+    wider than the keys; the result is [R,bq,vd]."""
     r, bq, _ = q.shape
-    probs = block_probs(q, k, start=start, scale=scale)
+    probs = block_probs(q, k, start=start, scale=scale, **seen)
     out = lax.dot_general(probs.astype(cd), v, (((0,), (0,)), ((), ())))
     return out.reshape(r, bq, v.shape[-1])
 
 
-def attend_head(q, k, v, *, scale: float, cd, block: int):
+def attend_head(q, k, v, *, scale: float, cd, block: int,
+                window: Optional[int] = None):
     """A block of queries at a time, each under its own checkpoint: the
     scores at [T,T] never exist at once, forward or backward.  ``q``
-    [R,T,hd], ``k`` [T,hd], ``v`` [T,vd]."""
-    out = [jax.checkpoint(functools.partial(
-        attend, start=s, scale=scale, cd=cd))(
-            q[:, s:s + block], k[:s + block], v[:s + block])
-        for s in range(0, q.shape[1], block)]
+    [R,T,hd], ``k`` [T,hd], ``v`` [T,vd].  Under ``window`` a block takes
+    the keys from its first query's window on."""
+    out = []
+    for s in range(0, q.shape[1], block):
+        lo = max(0, s - window) if window else 0
+        seen = dict(lo=lo, window=window) if window else {}
+        out.append(jax.checkpoint(functools.partial(
+            attend, start=s, scale=scale, cd=cd, **seen))(
+                q[:, s:s + block], k[lo:s + block], v[lo:s + block]))
     return jnp.concatenate(out, axis=1)

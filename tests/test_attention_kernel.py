@@ -1,8 +1,9 @@
 """The token models' attention kernels (ops/attention.py) on the CPU,
 through the Pallas interpreter at small shapes: against the XLA loop they
 replace on the chip and against a dense float32 answer, forward and the
-gradients; the sum over a key-value head's query heads; the tile
-classifier, with and without a window; a call inside ``shard_map``; who
+gradients; the sum over a key-value head's query heads; the same under a
+window at ``laguna``'s groups (R = 6 and 8); the tile classifier, with and
+without a window; a call inside ``shard_map``; who
 takes which path; the two-map form (``diff_attention``: a 64-wide key pair
 beside a 128-wide value, a window) as ``models/sambay.py`` runs it; that
 ``causal_gqa``'s kernels at the accepted cells' shapes are the parent's;
@@ -130,6 +131,92 @@ def test_dk_dv_are_summed_over_the_query_heads(blocks):
     assert rel(dk, sum(h[2] for h in heads)) < 1e-6
     assert rel(dv, sum(h[3] for h in heads)) < 1e-6
     assert rel(dk, heads[0][2]) > 0.1
+
+
+# -- causal_gqa under a window: laguna's window layers -------------------------
+
+def windowed_dense(q, k, v, window):
+    """Float32, the whole [T,T] square, a query's last ``window`` keys."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    t = q.shape[2]
+    qi, si = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = jnp.asarray((si <= qi) & (si > qi - window))
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("prtd,psd->prts", q, k) * SCALE
+        s = jnp.where(seen, s, -jnp.inf)
+        return jnp.einsum("prts,psd->prtd", jax.nn.softmax(s, axis=-1), v)
+
+
+def windowed_loop(q, k, v, window):
+    """The XLA loop ``laguna`` runs where the kernel does not apply."""
+    from ddp_tpu.ops import seq
+    return jax.lax.map(lambda a: seq.attend_head(
+        *a, scale=SCALE, cd=q.dtype, block=128, window=window), (q, k, v))
+
+
+@pytest.mark.parametrize("window", [128, 256, 200, 100, 1000])
+@pytest.mark.parametrize("r", [6, 8])
+def test_windowed_kernel_matches_the_loop_and_the_dense_answer(
+        blocks, r, window):
+    """``causal_gqa(..., window=w)`` in float32 at the fourth token cell's
+    groups (``R`` = 6 and 8), forward and the three gradients, against
+    the XLA loop and the dense masked answer: windows that are whole key
+    tiles and ones that are not, and one wider than the sequence."""
+    q, k, v, do = operands(512, r=r, seed=r)
+    got = out_and_grads(lambda q, k, v: attention.causal_gqa(
+        q, k, v, SCALE, True, window), q, k, v, do)
+    loop = out_and_grads(functools.partial(windowed_loop, window=window),
+                         q, k, v, do)
+    exact = out_and_grads(functools.partial(windowed_dense, window=window),
+                          q, k, v, do)
+    for name, g, lo, ex in zip(("o", "dq", "dk", "dv"), got, loop, exact):
+        assert g.shape == ex.shape
+        assert rel(g, ex) < 2e-6, (name, rel(g, ex))
+        assert rel(g, lo) < 2e-6, (name, rel(g, lo))
+    if window >= 512:
+        causal = out_and_grads(kernel, q, k, v, do)
+        for g, c in zip(got, causal):
+            assert rel(g, c) < 1e-6
+
+
+@pytest.mark.parametrize("r", [6, 8])
+def test_windowed_kernel_in_bf16_is_no_further_than_the_loop(blocks, r):
+    """In bf16 operands, the windowed kernel's distance from float32 is
+    within a quarter of the loop's, as the self-check holds it."""
+    q, k, v, do = operands(512, r=r, seed=r)
+    exact = out_and_grads(functools.partial(windowed_dense, window=200),
+                          q, k, v, do)
+    low = [a.astype(jnp.bfloat16) for a in (q, k, v, do)]
+    got = out_and_grads(lambda q, k, v: attention.causal_gqa(
+        q, k, v, SCALE, True, 200), *low)
+    loop = out_and_grads(functools.partial(windowed_loop, window=200), *low)
+    for name, g, lo, ex in zip(("o", "dq", "dk", "dv"), got, loop, exact):
+        assert g.dtype == jnp.bfloat16
+        assert 0 < rel(g, ex) < 1.25 * rel(lo, ex) < 0.02, \
+            (name, rel(g, ex), rel(lo, ex))
+
+
+def test_windowed_kernel_walks_only_the_windows_tiles(blocks, monkeypatch):
+    """At 128 x 128 blocks and a window of 128 a query block visits two
+    key tiles (the one across the window's lower edge and its diagonal),
+    whatever the sequence's length: what the forward kernel's walk calls,
+    counted at trace time."""
+    visits = []
+    real = attention._walk
+
+    def counted(i, bq, bkv, step, window=None):
+        visits.append(window)
+        return real(i, bq, bkv, step, window)
+
+    monkeypatch.setattr(attention, "_walk", counted)
+    q, k, v, _ = operands(512, r=2)
+    jax.make_jaxpr(lambda q, k, v: attention.causal_gqa(
+        q, k, v, SCALE, True, 128))(q, k, v)
+    assert visits and set(visits) == {128}
+    for i in range(4):
+        clear, visited, first, inside = attention.tile_kinds(i, 128, 128,
+                                                             128)
+        assert visited - first == (1 if i == 0 else 2)
 
 
 @pytest.mark.parametrize("bq,bkv", [(128, 128), (128, 256), (256, 128),

@@ -253,6 +253,39 @@ def test_attention_kernels_compile_for_v5e_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
+@pytest.mark.parametrize("r,window", [(8, 512), (6, None)],
+                         ids=["window", "full"])
+def test_laguna_attention_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, monkeypatch, r, window):
+    """The fourth token cell's two cores (ops/attention.py:causal_gqa; here
+    because the described chip's fixture lives in this file only): 2
+    sequences x 8 key-value heads of ``R`` query heads, 8,192 x 128 in
+    bf16, the window layer's under its 512, forward and backward, through
+    Mosaic within the module's VMEM limit and with no score left for
+    HBM."""
+    import math
+
+    from ddp_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    p, t, hd = 16, 8192, 128
+    assert attention.kernel_applies(t, hd, 2)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def out_and_grads(q, k, v, do):
+        o, pull = jax.vjp(lambda q, k, v: attention.causal_gqa(
+            q, k, v, 1.0 / math.sqrt(hd), window=window), q, k, v)
+        return (o,) + pull(do)
+
+    compiled = jax.jit(out_and_grads).lower(
+        spec(p, r, t, hd), spec(p, t, hd), spec(p, t, hd),
+        spec(p, r, t, hd)).compile()
+    text = compiled.as_text()
+    assert "causal_gqa_fwd" in text and "causal_gqa_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 @pytest.mark.parametrize("window", [None, 512], ids=["global", "window"])
 def test_two_map_attention_kernels_compile_for_v5e_at_the_cells_shape(
         one_chip, monkeypatch, window):
