@@ -12,8 +12,9 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
                                                   against the XLA path and float32
     selscan    python -m ddp_tpu.ops.selscan      the second token model's scan kernels
                                                   against the XLA path and float32
-    moe        python -m ddp_tpu.models.moe       the routed experts' products over the live
-                                                  row tiles against the every-tile form
+    moe        python -m ddp_tpu.models.moe       the routed half (gather, products, scatter-add)
+                                                  over the live row tiles against the
+                                                  whole-buffer form
     sambay     python -m ddp_tpu.models.sambay    one step of the SambaY stage (layers
                                                   14-19 of 32): tiny, then published widths
     train      python singlegpu.py 1 1 ...        8 steps, checkpoint, final eval
@@ -403,11 +404,12 @@ class Smoke:
                                 f"kernel={kernel}")
 
     def moe(self) -> str:
-        """The expert layer's products alone at both routing cells' shapes
-        (tiny ones off the chip) with a quarter, a half and all of the
-        buffer live: the child raises where the live loop's result is not
-        the every-tile form's or, on the chip, a full buffer costs over 3%
-        more; its table of milliseconds is shown."""
+        """The expert layer's routed half alone at the three routing cells'
+        shapes (tiny ones off the chip) with a quarter, a half and all of
+        the buffer live: the child raises where the live loop's result is
+        not the whole-buffer form's, where a compiled loop copies its
+        accumulator or, on the chip, a full buffer costs over 3% more; its
+        table of milliseconds is shown."""
         return self.table_check([PY, "-m", "ddp_tpu.models.moe"], "moe",
                                 f"platform={self.platform}")
 

@@ -15,19 +15,23 @@ on every cell that routes.
 (``e_score_correction_bias``: state, not trained) and four integer
 counters, ``assignments`` (to each held expert), ``dropped``,
 ``live_tiles`` (the row tiles that held a row, which are the tiles the
-products visited) and ``buffer_tiles`` (the tiles the buffer offered: the
-live share's other side, counted where the live tiles are so that the
-quotient holds over replicas and micro-batches), which a training step
-adds to (train/step.py sums
-integer state over replicas; the Trainer exports them where it flushes
-losses: obs/routing.py).
+layer's loop visited: gathered, multiplied and scattered back) and
+``buffer_tiles`` (the tiles the buffer offered: the live share's other
+side, counted where the live tiles are so that the quotient holds over
+replicas and micro-batches), which a training step adds to
+(train/step.py sums integer state over replicas; the Trainer exports them
+where it flushes losses: obs/routing.py).
 
-Scopes: ``moe_route`` (router, top-k, sort, gather in, scatter back),
-``moe_experts`` (the held experts' products over the live row tiles, in
-the backward pass too: :func:`tile_products`), ``moe_shared``.
+Scopes: ``moe_route`` (router, top-k, the row plan, and inside the loop
+over the live row tiles each tile's gather from the tokens and its
+scatter-add back), ``moe_experts`` (the held experts' products over those
+tiles, in the backward pass too: :func:`routed_products`),
+``moe_shared``.
 
-``python -m ddp_tpu.models.moe`` times the products alone at both routing
-cells' shapes against the form that computes every tile.
+``python -m ddp_tpu.models.moe`` runs ``models/moe_probe.py``: the routed
+half (gather, products, scatter-add) at the three routing cells' shapes
+against the form it replaced, which gathered and scattered the whole
+buffer.
 """
 from __future__ import annotations
 
@@ -39,6 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.layers import linear
+from ..ops.scatter import add_rows, row_shape
 
 F32 = jnp.float32
 MOE_ROW_TILE = 512
@@ -147,86 +152,131 @@ def _put_tile(a, v, i):
     return lax.dynamic_update_index_in_dim(a, v.astype(a.dtype), i, 0)
 
 
+def _rows(a, i, tile):
+    """Tile ``i``'s entries of a per-row array ``[tiles * tile, ...]``."""
+    return lax.dynamic_slice_in_dim(a, i * tile, tile)
+
+
+def _gather(a, tok):
+    """Rows ``tok`` of ``a``; a row of padding (``tok`` past the end)
+    reads zeros."""
+    return a.at[tok].get(mode="fill", fill_value=0)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def tile_products(form: ExpertForm, cd, rows, stacks, tile_expert, live):
-    """The held experts' products over the live prefix of the row buffer.
-    ``rows`` [tiles,tile,d]; ``stacks``: the parameters ``form.routed``
-    names, ``[count, ...]`` each, multiplied in ``cd``; tile ``i`` is
-    expert ``tile_expert[i]``'s.  Returns ``out`` [tiles,tile,d]: tiles
-    ``0 .. live-1`` computed, the rest zeros, which is what either form
-    makes of their zero rows.
+def routed_products(form: ExpertForm, cd, xf, token, row_w, stacks,
+                    tile_expert, live):
+    """The held experts' weighted results in token order, over the live
+    prefix of the row buffer.  ``xf`` [n_tok,d]; ``token``, ``row_w``
+    [tiles * tile]: a row's token (``n_tok`` for padding) and weight (0
+    for padding); ``stacks``: the parameters ``form.routed`` names,
+    ``[count, ...]`` each, multiplied in ``cd``; tile ``i`` is expert
+    ``tile_expert[i]``'s.  Returns ``routed`` [n_tok,d] float32: each row's
+    ``row_w * expert(xf[token])`` added to its token.
 
-    Both rules are a loop of ``live`` trips, so no pass multiplies a tile
-    of padding: the backward takes ``jax.vjp`` of one tile (any form
-    passes through it), writes the rows' cotangent a tile at a time and
-    adds the tile's weight gradients into its expert's slice of a float32
-    accumulator.  Each opens the ``moe_experts`` scope itself: a backward
-    rule is traced outside the layer's."""
-    return _products_fwd(form, cd, rows, stacks, tile_expert, live)[0]
+    Both rules are a loop of ``live`` trips, a tile each, so no pass
+    touches a tile of padding and no array of the buffer's rows exists:
+    a trip gathers its tile's rows from ``xf`` (``moe_route``), runs its
+    expert (``moe_experts``) and adds the weighted result into a float32
+    accumulator at the tile's tokens (``moe_route``).  The backward takes
+    ``jax.vjp`` of one tile (any form passes through it), writes the
+    tile's weights' cotangent, adds the rows' into a float32 accumulator
+    of ``xf``'s (cast once at the end) and the weight gradients into their
+    expert's slice of another.  Both accumulators of the tokens' rows are
+    added to in place by ``ops/scatter.py:add_rows`` (a DMA a row each
+    way on a TPU, which holds them as :func:`row_shape` says), which
+    needs a call's rows to name distinct tokens: a tile is one expert's,
+    and a token chooses an expert once.  Each rule opens its scopes
+    itself: a backward rule is traced outside the layer's."""
+    return _routed_fwd(form, cd, xf, token, row_w, stacks, tile_expert,
+                       live)[0]
 
 
-def _products_fwd(form, cd, rows, stacks, tile_expert, live):
-    vma = jax.typeof(rows).vma
+def _routed_fwd(form, cd, xf, token, row_w, stacks, tile_expert, live):
+    (n_tok, d), tile = xf.shape, token.shape[0] // tile_expert.shape[0]
+    vma = jax.typeof(xf).vma
     with jax.named_scope("moe_experts"):
         low = tuple(s.astype(cd) for s in stacks)
 
-        def one(i, out):
-            e = tile_expert[i]
-            return _put_tile(out, form.fn(
-                jnp.dot, _tile(rows, i), lambda j: _tile(low[j], e)), i)
+    def one(i, acc):
+        with jax.named_scope("moe_route"):
+            tok, e = _rows(token, i, tile), tile_expert[i]
+            rows = _gather(xf, tok)
+            with jax.named_scope("moe_experts"):
+                out = form.fn(jnp.dot, rows, lambda j: _tile(low[j], e))
+            return add_rows(
+                acc, tok, out.astype(F32) * _rows(row_w, i, tile)[:, None])
 
-        out = lax.fori_loop(0, live, one, _varying(jnp.zeros(
-            rows.shape, jnp.promote_types(rows.dtype, cd)), vma))
-    return out, (rows, stacks, tile_expert, live)
+    routed = lax.fori_loop(0, live, one, _varying(
+        jnp.zeros((n_tok,) + row_shape(d), F32), vma))
+    with jax.named_scope("moe_route"):
+        routed = routed.reshape(n_tok, d)
+    return routed, (xf, token, row_w, stacks, tile_expert, live)
 
 
-def _products_bwd(form, cd, res, g):
-    rows, stacks, tile_expert, live = res
-    vma = jax.typeof(rows).vma
+def _routed_bwd(form, cd, res, g):
+    xf, token, row_w, stacks, tile_expert, live = res
+    (n_tok, d), tile = xf.shape, token.shape[0] // tile_expert.shape[0]
+    vma = jax.typeof(xf).vma
     with jax.named_scope("moe_experts"):
         low = tuple(s.astype(cd) for s in stacks)
 
-        def one(i, carry):
-            d_rows, d_stacks = carry
-            e = tile_expert[i]
-            _, pull = jax.vjp(
-                lambda x, *w: form.fn(jnp.dot, x, lambda j: w[j]),
-                _tile(rows, i), *(_tile(s, e) for s in low))
-            dx, *dw = pull(_tile(g, i))
-            return _put_tile(d_rows, dx, i), tuple(
-                _put_tile(acc, _tile(acc, e) + v.astype(F32), e)
-                for acc, v in zip(d_stacks, dw))
+    def one(i, carry):
+        d_xf, d_row_w, d_stacks = carry
+        with jax.named_scope("moe_route"):
+            tok, w_i, e = _rows(token, i, tile), _rows(row_w, i, tile), \
+                tile_expert[i]
+            rows, g_rows = _gather(xf, tok), _gather(g, tok)
+            with jax.named_scope("moe_experts"):
+                out, pull = jax.vjp(
+                    lambda x, *w: form.fn(jnp.dot, x, lambda j: w[j]),
+                    rows, *(_tile(s, e) for s in low))
+                dx, *dw = pull((g_rows * w_i[:, None]).astype(out.dtype))
+                d_stacks = tuple(
+                    _put_tile(acc, _tile(acc, e) + v.astype(F32), e)
+                    for acc, v in zip(d_stacks, dw))
+            d_row_w = lax.dynamic_update_slice_in_dim(
+                d_row_w, jnp.sum(out.astype(F32) * g_rows, axis=-1),
+                i * tile, 0)
+            return add_rows(d_xf, tok, dx.astype(F32)), d_row_w, d_stacks
 
-        d_rows, d_stacks = lax.fori_loop(0, live, one, (
-            _varying(jnp.zeros_like(rows), vma),
-            tuple(_varying(jnp.zeros(s.shape, F32), vma) for s in stacks)))
+    d_xf, d_row_w, d_stacks = lax.fori_loop(0, live, one, (
+        _varying(jnp.zeros((n_tok,) + row_shape(d), F32), vma),
+        _varying(jnp.zeros(row_w.shape, F32), vma),
+        tuple(_varying(jnp.zeros(s.shape, F32), vma) for s in stacks)))
+    with jax.named_scope("moe_experts"):
         d_stacks = tuple(a.astype(s.dtype) for a, s in zip(d_stacks, stacks))
-    return d_rows, d_stacks, None, None
+    with jax.named_scope("moe_route"):
+        d_xf = d_xf.reshape(n_tok, d).astype(xf.dtype)
+    return (d_xf, None, d_row_w.astype(row_w.dtype),
+            d_stacks, None, None)
 
 
-tile_products.defvjp(_products_fwd, _products_bwd)
+routed_products.defvjp(_routed_fwd, _routed_bwd)
 
 
 def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
-    """Returns ``(y, new layer state)``.  The held experts' products run
-    over a buffer of row tiles, each tile one expert's (:func:`row_plan`),
-    a tile at a time with its expert's weights, over the tiles that hold
-    a row and no others (:func:`tile_products`): the rows are packed
-    expert after expert from the buffer's start, so those tiles are a
-    prefix, and what lies past it stays zero.  A step's time therefore
-    follows the held experts' load, up to the whole buffer's (every tile
-    live: what each step cost before PR 38).  Padding rows inside a live
-    tile are zeros that pass through either form's activation and
-    products as zeros (no mask: ``jax.lax.ragged_dot``, which the buffer
-    replaced, leaves the rows past its groups as it finds them, NaN on
-    the chip: PERF.md, findings of PR 28).
+    """Returns ``(y, new layer state)``.  The held experts' rows are laid
+    out in a buffer of row tiles, each tile one expert's (:func:`row_plan`),
+    and a loop visits the tiles that hold a row and no others
+    (:func:`routed_products`): the rows are packed expert after expert
+    from the buffer's start, so those tiles are a prefix.  A trip gathers
+    its tile's rows from the tokens, multiplies them with its expert's
+    weights and adds their weighted results back to their tokens; the
+    buffer itself is a plan of indices and weights, never an array of
+    rows.  A step's time therefore follows the held experts' load; with
+    every tile live it is below what the form that gathered and scattered
+    the whole buffer cost (``python -m ddp_tpu.models.moe`` holds it
+    there).  Padding rows inside a live tile
+    read zeros, which pass through either form's activation and products
+    as zeros, and weigh nothing (no mask: ``jax.lax.ragged_dot``, which
+    the buffer replaced, leaves the rows past its groups as it finds them,
+    NaN on the chip: PERF.md, section 6).
 
     The buffer (:func:`buffer_tiles`) holds ``MOE_LOAD_HEADROOM`` times
     the uniform load: no assignment is dropped while the held experts'
     load is within that, whatever its split among them, and ``dropped``
-    counts those that found no room beyond it.  The buffer's size is
-    memory, and time in ``moe_route``: all its rows are gathered and
-    scattered back whether they hold a token or not."""
+    counts those that found no room beyond it."""
     bsz, t, d = x.shape
     n_tok, k = bsz * t, dm["top_k"]
     first, count, tile = dm["first"], dm["count"], MOE_ROW_TILE
@@ -240,23 +290,15 @@ def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
         sizes, src, tile_expert, dropped, live = row_plan(
             jnp.where(held, idx - first, count).reshape(-1), count, tile,
             tiles)
-        # Assignment a is slot a % k of token a // k; a row of padding
-        # reads the zero row and weighs nothing.
+        # Assignment a is slot a % k of token a // k; a row of padding is
+        # token n_tok, past the end, and weighs nothing.
         token = src // k
-        rows = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])[token]
         row_w = jnp.concatenate([w.reshape(-1), jnp.zeros((1,), w.dtype)])[
             src]
-    with jax.named_scope("moe_experts"):
-        out = tile_products(
-            form, cd, rows.reshape(tiles, tile, d),
-            tuple(_varying(p[name], jax.typeof(rows).vma)
-                  for name in form.routed), tile_expert, live)
-    with jax.named_scope("moe_route"):
-        # Back to token order: each row adds its weighted result to its
-        # token (padding to the row past the tokens' end).
-        routed = jnp.zeros((n_tok + 1, d), F32).at[token].add(
-            out.reshape(tiles * tile, d).astype(F32) * row_w[:, None])[
-                :n_tok]
+    routed = routed_products(
+        form, cd, xf, token, row_w,
+        tuple(_varying(p[name], jax.typeof(xf).vma) for name in form.routed),
+        tile_expert, live)
     with jax.named_scope("moe_shared"):
         y = shared_expert(p, xf, cd, form).astype(F32) + routed
     new_st = st
@@ -269,119 +311,6 @@ def expert_layer(p, st, x, dm: dict, cd, *, train: bool, form: ExpertForm):
     return y.astype(cd).reshape(bsz, t, d), new_st
 
 
-# -- python -m ddp_tpu.models.moe ------------------------------------------------
-
-# (name, form, hidden, expert width, router experts, top_k) of the two
-# routing cells' expert layers, 8 experts held each, at the cells' 16,384
-# tokens a step; and what a process without a TPU runs instead.
-SELF_CHECK_LAYERS = (("nemotron_h", RELU2, 2688, 1856, 128, 6),
-                     ("glm4_moe_lite", SWIGLU, 2048, 1536, 64, 4))
-SELF_CHECK_TINY = (("relu2", RELU2, 128, 256, 32, 4),
-                   ("swiglu", SWIGLU, 128, 256, 16, 2))
-SELF_CHECK_SHARES = (0.25, 0.5, 1.0)
-
-
-def _every_tile(form, cd, rows, stacks, tile_expert, live):
-    """What :func:`tile_products` replaced, kept as the probe's yardstick:
-    every tile of the buffer multiplied, live or not."""
-    del live
-    low = tuple(s.astype(cd) for s in stacks)
-    return lax.map(
-        lambda a: form.fn(jnp.dot, a[0], lambda j: low[j][a[1]]),
-        (rows, tile_expert))
-
-
-def _probe_operands(form, d, h, count, tiles, tile, live, cd):
-    """A buffer whose first ``live`` tiles hold rows, shared evenly among
-    the experts in order, and the cotangent the layer would send back
-    (zero wherever the rows are padding)."""
-    keys = jax.random.split(jax.random.key(live), 2 + len(form.routed))
-    holds = (jnp.arange(tiles) < live)[:, None, None]
-    rows, g = (jnp.where(holds, jax.random.normal(k, (tiles, tile, d), F32),
-                         0).astype(cd) for k in keys[:2])
-    stacks = tuple(
-        0.02 * jax.random.normal(
-            k, (count, h, d) if name == "down" else (count, d, h), F32)
-        for k, name in zip(keys[2:], form.routed))
-    tile_expert = jnp.minimum(jnp.arange(tiles) * count // max(live, 1),
-                              count - 1).astype(jnp.int32)
-    return rows, stacks, tile_expert, jnp.int32(live), g
-
-
-def _self_check() -> None:
-    """The held experts' products alone, forward and with their backward,
-    at both routing cells' shapes (on a TPU; elsewhere tiny ones) with a
-    quarter, a half and all of the buffer live, against the form that
-    computes every tile: that the results are the same, and on a TPU the
-    milliseconds of both.  Raises where a result differs or, on a TPU, the
-    live loop over a FULL buffer costs a step (forward, then forward and
-    backward) over 3% more than the every-tile form."""
-    from ..ops.attention import _ms, _rel
-    from ..parallel.mesh import make_mesh
-    from ..utils.platform import device_line, enable_compile_cache
-
-    enable_compile_cache()
-    print(device_line(make_mesh()), flush=True)
-    on_chip = jax.default_backend() == "tpu"
-    cd, count = jnp.bfloat16, 8
-    tile, n_tok = (MOE_ROW_TILE, 16384) if on_chip else (16, 256)
-    for name, form, d, h, router, k in (SELF_CHECK_LAYERS if on_chip
-                                        else SELF_CHECK_TINY):
-        tiles = buffer_tiles(n_tok, {"top_k": k, "count": count,
-                                     "router": router}, tile)
-        print(f"moe: {name} d={d} expert={h} held={count} tiles={tiles} x "
-              f"{tile} rows, {len(form.routed)} matrices an expert, "
-              f"{jnp.dtype(cd).name}", flush=True)
-        if on_chip:
-            print("moe:   live tiles     ms forward: live, every tile   "
-                  "ms forward+backward: live, every tile")
-        for share in SELF_CHECK_SHARES:
-            live = round(share * tiles)
-            *args, g = _probe_operands(form, d, h, count, tiles, tile, live,
-                                       cd)
-            fwd = {n: jax.jit(functools.partial(f, form, cd))
-                   for n, f in (("live", tile_products),
-                                ("every", _every_tile))}
-            both = {n: jax.jit(lambda rows, stacks, te, lv, g, f=f: jax.vjp(
-                lambda r, s: f(r, s, te, lv), rows, stacks)[1](g))
-                for n, f in fwd.items()}
-            if not jnp.array_equal(fwd["live"](*args), fwd["every"](*args)):
-                raise RuntimeError(
-                    f"moe: {name} at {live} live tiles of {tiles}: the live "
-                    "loop's result is not the every-tile form's")
-            far = max(_rel(a, b) for a, b in zip(
-                *(jax.tree_util.tree_leaves(both[n](*args, g))
-                  for n in ("live", "every"))))
-            # The every-tile form adds a tile's weight gradient to its
-            # expert's in ``cd``, the live loop in float32.
-            if far > 0.01:
-                raise RuntimeError(
-                    f"moe: {name} at {live} live tiles of {tiles}: a "
-                    f"gradient is {far:.5f} from the every-tile form's")
-            if not on_chip:
-                print(f"moe:   {live:>3} of {tiles}: result equal, "
-                      f"gradients within {far:.5f}", flush=True)
-                continue
-            ms = [_ms(fn[n], *a) for fn, a in ((fwd, args), (both, (*args, g)))
-                  for n in ("live", "every")]
-            print(f"moe:   {live:>3} of {tiles}" + " " * 14
-                  + f"{ms[0]:9.2f}{ms[1]:9.2f}" + " " * 15
-                  + f"{ms[2]:9.2f}{ms[3]:9.2f}   gradients within "
-                  f"{far:.5f}", flush=True)
-            # What a step pays a layer: the forward, and under the block's
-            # checkpoint the forward again with the backward.
-            paid = ms[0] + ms[2], ms[1] + ms[3]
-            if live == tiles and paid[0] > 1.03 * paid[1]:
-                raise RuntimeError(
-                    f"moe: {name} with every tile live costs {paid[0]:.2f} "
-                    f"ms a step and layer against the every-tile form's "
-                    f"{paid[1]:.2f}: over 3% more")
-    print(f"moe: ok platform={jax.default_backend()} the live loop's "
-          "results are the every-tile form's at "
-          + ", ".join(f"{s:.0%}" for s in SELF_CHECK_SHARES) + " live",
-          flush=True)
-
-
 if __name__ == "__main__":
-    from ddp_tpu.models import moe
-    moe._self_check()
+    from ddp_tpu.models import moe_probe
+    moe_probe.main()

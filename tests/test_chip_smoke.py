@@ -14,8 +14,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_phase_runner_tiny_on_cpu(tmp_path, monkeypatch):
     """The real commands as children — gather check, the attention kernel's
     and both models' scan kernels' checks (off the chip: through the
-    interpreter), the routed experts' products over the live row tiles
-    against the every-tile form,
+    interpreter), the routed half over the live row tiles against the
+    whole-buffer form,
     a step of the SambaY stage (off the chip: at the tiny width alone),
     train + checkpoint + eval, the same train again adding nothing to the
     compile cache,

@@ -771,15 +771,16 @@ def test_the_parent_fails_the_new_cell_at_once():
 # the PR that lifted the expert layer into models/moe.py (c2e02eb), at the
 # tiny presets on a 2-device mesh: a refactor of the shared layer, of the
 # loss core or of ops/seq.py that changes either program shows here.
-# ``nemotron_h``'s two are PR 38's program, which changed by design (the
-# experts' products became a loop over the live row tiles with a backward
-# rule of its own, and the layer's state two counters): re-pinned there;
+# ``nemotron_h``'s two are re-pinned where its program changed by design:
+# the experts' products became a loop over the live row tiles with a
+# backward rule of its own (and the layer's state two counters), then the
+# rows' gather and the results' scatter-add moved into that loop;
 # ``sambay``'s, which runs no expert layer, are still the parent's.
 PARENTS_STEP = {
     ("nemotron_h", "f32"):
-        "b5d4725bb693fdf8e51a160aa77a3cb58088578e27ed9a858a0fd18e8ed06fdb",
+        "01dcbc89b8fc9c153d70287d336b3e1a09fbbf6b9e72e68f3db2497d50703004",
     ("nemotron_h", "bf16"):
-        "485a423c33937d729b82d2b8cdc3ffb84347bcb7ce8381f06907511a7cbf81ee",
+        "8a650dda39ea16136cff48f3527e3fe00c80d596d07a55138b76342b417f0632",
     ("sambay", "f32"):
         "893a90a8584e719f21a44c58861f0d26e9a74e3d12663fad7b77e3eee4507d33",
     ("sambay", "bf16"):

@@ -1,8 +1,11 @@
-"""The expert layer's products over the live prefix of the row buffer
-(models/moe.py:tile_products), at a small size on the CPU: against the
-form that computes every tile, written here; ``row_plan``'s count of live
-tiles; the live-tile counter from the step to the ``--prom`` table; and
-that the backward's products stay inside the ``moe_experts`` scope."""
+"""The expert layer's routed half over the live prefix of the row buffer
+(models/moe.py:routed_products: a tile's gather, products and scatter-add
+a trip), at a small size on the CPU: against the form that gathers,
+multiplies and scatters the whole buffer (``moe_probe.former``, the
+probe's yardstick too); that the step holds no array of the buffer's rows;
+``row_plan``'s count of live tiles; the live-tile counter from the step to
+the ``--prom`` table; and that the backward's products, gathers and
+scatters stay inside their scopes."""
 import json
 import os
 import re
@@ -12,14 +15,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.run import overlay  # noqa: E402
-from ddp_tpu.models import get_model, moe  # noqa: E402
+from ddp_tpu.models import get_model, moe, moe_probe  # noqa: E402
 
 F32 = jnp.float32
 # Widths that no two matrices share, so that a product's shapes say whose
@@ -42,16 +44,6 @@ LOADS = {
 }
 
 
-def every_tile(form, cd, rows, stacks, tile_expert, live):
-    """The layer's products before PR 38: every tile of the buffer, live
-    or not, through ``lax.map``."""
-    del live
-    low = tuple(s.astype(cd) for s in stacks)
-    return lax.map(
-        lambda a: form.fn(jnp.dot, a[0], lambda j: low[j][a[1]]),
-        (rows, tile_expert))
-
-
 def layer_operands(form, e_bias):
     keys = iter(jax.random.split(jax.random.key(0), 16))
 
@@ -72,7 +64,7 @@ def layer_operands(form, e_bias):
 
 def layer_and_grads(form, cd, p, st, x, cot):
     """``(y, new state), (dp, dx)`` of the layer as the module now stands
-    (the caller may have patched ``tile_products``)."""
+    (the caller may have patched ``routed_products``)."""
     def f(p, x):
         y, new = moe.expert_layer(p, st, x.astype(cd), DM, cd, train=True,
                                   form=form)
@@ -88,11 +80,11 @@ def rel(a, b):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-# -- (a) the live loop against the every-tile form ------------------------------
+# -- (a) the live loop against the whole buffer ---------------------------------
 
 @pytest.mark.parametrize("load", sorted(LOADS))
 @pytest.mark.parametrize("form", sorted(FORMS))
-def test_live_loop_is_the_every_tile_form(form, load, monkeypatch):
+def test_live_loop_is_the_whole_buffer_form(form, load, monkeypatch):
     form = FORMS[form]
     e_bias, headroom = LOADS[load]
     monkeypatch.setattr(moe, "MOE_ROW_TILE", TILE)
@@ -102,12 +94,14 @@ def test_live_loop_is_the_every_tile_form(form, load, monkeypatch):
     for cd, near in ((F32, 1e-6), (jnp.bfloat16, 2e-2)):
         (y, new), grads = layer_and_grads(form, cd, p, st, x, cot)
         with monkeypatch.context() as mp:
-            mp.setattr(moe, "tile_products", every_tile)
+            mp.setattr(moe, "routed_products", moe_probe.former)
             (y_ref, new_ref), grads_ref = layer_and_grads(
                 form, cd, p, st, x, cot)
-        # Bit for bit: a live tile is the same product of the same rows,
-        # a dead one the zeros either form makes of zero rows.
-        assert y.dtype == y_ref.dtype and np.array_equal(y, y_ref)
+        # A live tile is the same product of the same rows and a dead one
+        # adds nothing; a product may now reach its float32 weighting
+        # unrounded, a token's float32 sum runs in another order, and the
+        # rows' cotangent adds up in float32, not in ``cd``.
+        assert y.dtype == y_ref.dtype and rel(y, y_ref) < near
         assert jax.tree_util.tree_all(jax.tree_util.tree_map(
             np.array_equal, new, new_ref))
         flat, flat_ref = (jax.tree_util.tree_leaves_with_path(g)
@@ -135,6 +129,83 @@ def test_live_loop_is_the_every_tile_form(form, load, monkeypatch):
             assert 0 < live < tiles and int(new["dropped"]) == 0
             assert any(np.asarray(grads[0][name]).any()
                        for name in form.routed)
+
+
+# -- (a') no array of the buffer's rows ------------------------------------------
+
+def _layer_texts(form, cd):
+    """The lowered and the compiled text of the layer's value and gradient
+    at the module's widths, in which ``(tiles, TILE, D)`` and
+    ``(tiles * TILE, D)`` are no other array's shapes."""
+    p, st, x, cot = layer_operands(form, LOADS["even"][0])
+
+    def f(p, x):
+        y, _ = moe.expert_layer(p, st, x.astype(cd), DM, cd, train=True,
+                                form=form)
+        return jnp.sum(y.astype(F32) * cot)
+
+    lowered = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(p, x)
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("cd", ["float32", "bfloat16"])
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_step_holds_no_array_of_the_buffers_rows(form, cd, monkeypatch):
+    """Forward and backward: no array of the buffer's rows or of their
+    products, as the program asks for it or as XLA compiles it; every
+    gather and scatter of rows of the tokens' width is a loop's, a tile at
+    a time, and carries ``moe_route``."""
+    monkeypatch.setattr(moe, "MOE_ROW_TILE", TILE)
+    tiles = moe.buffer_tiles(B * T, DM, TILE)
+    assert len({tiles * TILE, B * T, D, H, SHARED, ROUTER}) == 6
+    lowered, compiled = _layer_texts(FORMS[form], jnp.dtype(cd))
+    assert not re.search(rf"\b{tiles}x{TILE}x{D}x|\b{tiles * TILE}x{D}x",
+                         lowered)
+    assert not re.search(rf"\[{tiles},{TILE},{D}\]|\[{tiles * TILE},{D}\]",
+                         compiled)
+    seen = {"gather": 0, "scatter": 0}
+    for ln in compiled.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (gather|scatter)\(", ln)
+        if m and str(D) in m.group(1).split(","):
+            op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+            dims = [int(n) for n in m.group(1).split(",")]
+            # A gather reads a tile's rows; a scatter adds into the
+            # accumulator of the tokens' rows.
+            assert (TILE in dims if m.group(2) == "gather"
+                    else dims == [B * T, D]), ln
+            assert "/while/body/" in op_name, ln
+            assert op_name.rsplit("/", 2)[-2] == "moe_route", ln
+            seen[m.group(2)] += 1
+    # Forward: the rows in, the results out.  Backward: the rows and their
+    # cotangent in, the rows' cotangent out.
+    assert seen == {"gather": 3, "scatter": 2}
+
+
+def test_accumulator_copies_reads_the_loop_bodies():
+    """The probe's check that a loop updates its accumulator in place
+    finds a copy of the accumulator's shape inside a loop body, and only
+    there."""
+    text = """HloModule m
+
+%body.1 (p: (s32[], f32[64,24])) -> (s32[], f32[64,24]) {
+  %a = f32[64,24]{1,0} get-tuple-element(%p), index=1
+  %c = f32[64,24]{1,0} copy(%a)
+  %d = f32[8,24]{1,0} copy(%e)
+  ROOT %t = (s32[], f32[64,24]{1,0}) tuple(%i, %c)
+}
+
+ENTRY %main (x: f32[64,24]) -> f32[64,24] {
+  %x2 = f32[64,24]{1,0} copy(%x)
+  %w = (s32[], f32[64,24]{1,0}) while(%t0), condition=%cond.1, body=%body.1
+}
+"""
+    found = moe_probe.accumulator_copies(text, "f32[64,24]")
+    assert len(found) == 1 and "%c = " in found[0]
+    assert moe_probe.accumulator_copies(text.replace("body=%body.1", "body=%b2"),
+                                  "f32[64,24]") == []
+    _, compiled = _layer_texts(moe.SWIGLU, jnp.bfloat16)
+    assert "while(" in compiled
+    assert moe_probe.accumulator_copies(compiled, f"f32[{B * T},{D}]") == []
 
 
 # -- (b) row_plan's live tiles --------------------------------------------------

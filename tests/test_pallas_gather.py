@@ -382,3 +382,41 @@ def test_selscan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
     assert text.count("tpu_custom_call") >= 2
     assert "selscan_fwd" in text and "selscan_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
+@pytest.mark.parametrize("layer", ["nemotron_h", "laguna"])
+def test_routed_half_compiles_for_v5e_adding_in_place(one_chip, monkeypatch,
+                                                       layer):
+    """The expert layer's routed half (models/moe.py, through the row-add
+    kernel of ops/scatter.py; here because the described chip's fixture
+    lives in this file only) at a routing cell's shapes with 16,384 tokens,
+    forward and backward: the kernel is in both loops, neither loop copies
+    its float32 accumulator of the tokens' rows, and the program holds
+    nothing near the buffer's rows (1.2 GB at the first cell in float32)."""
+    import functools
+
+    from ddp_tpu.models import moe, moe_probe
+    from ddp_tpu.ops import scatter
+    monkeypatch.setattr(scatter, "_use_pallas", lambda: True)
+    _, form, d, h, router, k, count = next(
+        row for row in moe_probe.SELF_CHECK_LAYERS if row[0] == layer)
+    n, tile = 16384, moe.MOE_ROW_TILE
+    tiles = moe.buffer_tiles(n, {"top_k": k, "count": count,
+                                 "router": router}, tile)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stacks = tuple(spec((count, h, d) if name == "down" else (count, d, h))
+                   for name in form.routed)
+    run = moe_probe._with_backward(functools.partial(
+        moe.routed_products, form, jnp.bfloat16))
+    compiled = jax.jit(run).lower(
+        spec((n, d), jnp.bfloat16), spec((tiles * tile,), jnp.int32),
+        spec((tiles * tile,)), stacks, spec((tiles,), jnp.int32),
+        spec((), jnp.int32), spec((n, d))).compile()
+    text = compiled.as_text()
+    acc = f"f32[{n},{d // 128},128]"
+    assert text.count("tpu_custom_call") >= 2 and "add_rows" in text
+    assert acc in text and moe_probe.accumulator_copies(text, acc) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
