@@ -8,7 +8,7 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
     gather     python -m ddp_tpu.ops.gather       Pallas row gather == table[idx]
     attention  python -m ddp_tpu.ops.attention    the token models' attention kernels
                                                   against the XLA loops and float32
-    ssd        python -m ddp_tpu.ops.ssd          the token model's scan kernels
+    ssd        python -m ddp_tpu.ops.ssd          the token models' scan kernels (8 groups; one of 64)
                                                   against the XLA path and float32
     selscan    python -m ddp_tpu.ops.selscan      the second token model's scan kernels
                                                   against the XLA path and float32

@@ -32,7 +32,8 @@ _MODULE_MODELS = {
 # name -> module with ``build(config) -> (init, apply, tokens)``: models
 # built from a configuration file (``--model_config``).
 _CONFIG_MODELS = {"nemotron_h": "nemotron_h", "sambay": "sambay",
-                  "glm4_moe_lite": "glm4_moe_lite", "laguna": "laguna"}
+                  "glm4_moe_lite": "glm4_moe_lite", "laguna": "laguna",
+                  "granite_hybrid": "granite_hybrid"}
 MODEL_NAMES = tuple(_MODULE_MODELS) + tuple(_CONFIG_MODELS)
 
 

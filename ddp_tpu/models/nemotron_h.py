@@ -184,6 +184,28 @@ def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int, cd):
     return y[:, :t]
 
 
+def mamba_params(dm: dict, normal, uniform, out_std: float) -> dict:
+    """A Mamba-2 mixer's parameters, as mamba_ssm initialises them:
+    ``normal(shape[, std])`` and ``uniform(shape, lo, hi)`` draw the next
+    key; matrices normal, the output's at ``out_std``, the convolution
+    uniform within ``1/sqrt(k)``, ``dt_bias`` the inverse softplus of a
+    log-uniform ``dt`` in ``[dt_min, dt_max]`` (floored), ``A`` uniform in
+    ``[1, 16]``, ``D`` and the norm's weight 1."""
+    d, h, bound = dm["d"], dm["h"], 1.0 / math.sqrt(dm["k"])
+    dt = jnp.exp(uniform((h,), math.log(dm["dt_min"]),
+                         math.log(dm["dt_max"])))
+    dt = jnp.maximum(dt, dm["dt_floor"])
+    return dict(
+        in_proj=normal((d, 2 * dm["d_inner"] + 2 * dm["g"] * dm["n"] + h)),
+        conv_w=uniform((dm["k"], dm["conv_dim"]), -bound, bound),
+        conv_b=uniform((dm["conv_dim"],), -bound, bound),
+        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+        A_log=jnp.log(uniform((h,), 1.0, 16.0)),
+        D=jnp.ones((h,), F32),
+        gate_norm=jnp.ones((dm["d_inner"],), F32),
+        out_proj=normal((dm["d_inner"], d), out_std))
+
+
 def mamba_mixer(p, x, dm: dict, cd):
     bsz, t, _ = x.shape
     d_inner, g, n = dm["d_inner"], dm["g"], dm["n"]
@@ -238,7 +260,8 @@ def attention_mixer(p, x, dm: dict, cd):
         v = linear(x, p["v"].astype(cd)).reshape(
             bsz, t, hkv, hd).transpose(0, 2, 1, 3)
     with jax.named_scope("attn_core"):
-        scale = 1.0 / math.sqrt(hd)
+        # A model with its own scale of the scores names it in ``dims``.
+        scale = dm.get("attn_scale", 1.0 / math.sqrt(hd))
         qkv = (q.reshape(bsz * hkv, hq // hkv, t, hd),
                k.reshape(bsz * hkv, t, hd), v.reshape(bsz * hkv, t, hd))
         # The blocked kernel where the shapes and the backend allow it
@@ -306,21 +329,7 @@ def build(config: dict):
         for i, kind in enumerate(dm["pattern"]):
             p = {"norm": jnp.ones((d,), F32)}
             if kind == "M":
-                h, bound = dm["h"], 1.0 / math.sqrt(dm["k"])
-                # dt_bias is the inverse softplus of a log-uniform dt.
-                dt = jnp.exp(uniform((h,), math.log(dm["dt_min"]),
-                                     math.log(dm["dt_max"])))
-                dt = jnp.maximum(dt, dm["dt_floor"])
-                p.update(
-                    in_proj=normal((d, 2 * dm["d_inner"] + 2 * dm["g"]
-                                    * dm["n"] + h)),
-                    conv_w=uniform((dm["k"], dm["conv_dim"]), -bound, bound),
-                    conv_b=uniform((dm["conv_dim"],), -bound, bound),
-                    dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-                    A_log=jnp.log(uniform((h,), 1.0, 16.0)),
-                    D=jnp.ones((h,), F32),
-                    gate_norm=jnp.ones((dm["d_inner"],), F32),
-                    out_proj=normal((dm["d_inner"], d), out_std))
+                p.update(mamba_params(dm, normal, uniform, out_std))
             elif kind == "*":
                 hq, hkv, hd = dm["heads"], dm["kv_heads"], dm["head_dim"]
                 p.update(q=normal((d, hq * hd)), k=normal((d, hkv * hd)),

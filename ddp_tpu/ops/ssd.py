@@ -34,6 +34,19 @@ bytes roofline on the chip (PERF.md).  Here:
   the cotangents of ``dt`` and of the cumulative ``dt*A``, and the
   partial sums of ``dD`` and of the norm's weight's gradient.
 
+**A group wider than a grid step** (``HEAD_TILE``: more heads than the
+shape the kernels were swept at, e.g. one group of 64 heads, 4,096 lanes,
+whose blocks would hold three times the VMEM budget) is scanned a tile of
+``HEAD_TILE`` heads a grid step: the grid's second axis runs over the
+tiles, every tile of a group reads the group's one ``B``, ``C`` block
+through its index map, and ``dB``/``dC`` leave the kernel as a tile's
+float32 partial sums that XLA adds up over the group's tiles.  The norm's
+group is then wider than a tile, so the gate and the norm are not the
+kernel's: the forward kernel writes float32 ``y`` and ``gated_norm`` of
+models/nemotron_h.py follows in XLA, whose backward hands the backward
+kernel ``dy``.  A group within a grid step (the first token cell's 8 heads)
+takes the kernels above, unchanged.
+
 A ``[Q,Q]`` tile wants a head's ``dt`` and cumulative ``dt*A`` along lanes
 and along sublanes; they come in as rows ``[R,Q]`` and are transposed in
 the kernel (a column block ``[Q,R]`` in HBM is padded to 128 lanes: 16
@@ -80,6 +93,8 @@ _LANE = 128
 # 128, bf16 (PERF.md section 6).
 FWD_CHUNKS = 4
 BWD_CHUNKS = 4
+# Heads a grid step where a group holds more than this many: that shape's.
+HEAD_TILE = 8
 VMEM_LIMIT_BYTES = 64 * 2**20
 # The segment sum above the diagonal: far below any, and finite.
 _MASKED = -0.7 * float(jnp.finfo(F32).max)
@@ -109,16 +124,26 @@ def _whole(t: int, h: int, p: int, g: int, n: int, q: int) -> bool:
                 or h // g > _LANE)
 
 
+def _head_tile(r: int, p: int) -> int:
+    """Heads a grid step of a group of ``r``: all of them, or
+    ``HEAD_TILE`` where they are more and fall into whole tiles of whole
+    lanes."""
+    if r <= HEAD_TILE or r % HEAD_TILE or HEAD_TILE * p % _LANE:
+        return r
+    return HEAD_TILE
+
+
 def kernel_applies(t: int, h: int, p: int, g: int, n: int, chunk: int,
                    itemsize: int = 4) -> bool:
     """Whether :func:`ssd_scan` can run this problem here: a TPU backend,
-    whole chunks and lanes (:func:`_whole`), a group's ``R`` heads whole
+    whole chunks and lanes (:func:`_whole`), a grid step's heads whole
     sublane tiles, and the blocks within the VMEM budget."""
-    if not (_use_pallas() and _whole(t, h, p, g, n, chunk)) or (h // g) % 8:
+    rt = _head_tile(h // g, p)
+    if not (_use_pallas() and _whole(t, h, p, g, n, chunk)) or rt % 8:
         return False
     steps = max(_steps(t // chunk, FWD_CHUNKS), _steps(t // chunk,
                                                        BWD_CHUNKS))
-    return _vmem_bytes(chunk, h // g * p, n, steps, itemsize) \
+    return _vmem_bytes(chunk, rt * p, n, steps, itemsize) \
         <= VMEM_LIMIT_BYTES
 
 
@@ -217,13 +242,22 @@ def _by_head(v, masks, cd):
          for m in masks], axis=0)
 
 
-def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
-                o_ref, *rest, q: int, p: int, eps: float):
-    """``rest``: where they are saved, float32 ``y`` and the entering
-    states' blocks, then the running state's scratch; where they are not,
-    the scratch and one for a chunk's ``y``."""
-    save = len(rest) == 3
-    y_ref, h_ref, ht_ref = rest if save else (rest[1], None, rest[0])
+def _fwd_kernel(*refs, q: int, p: int, eps: float, norm: bool):
+    """With the norm (``norm``): ``x, b, c, dt, cs, d, z, w``, the result
+    ``o``, then where they are saved float32 ``y`` and the entering states'
+    blocks and the running state's scratch, where they are not the scratch
+    and one for a chunk's ``y``.  Without it: ``x, b, c, dt, cs, d``, the
+    result float32 ``y``, where they are saved the entering states' blocks,
+    then the scratch."""
+    if norm:
+        x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref, o_ref, \
+            *rest = refs
+        save = len(rest) == 3
+        y_ref, h_ref, ht_ref = rest if save else (rest[1], None, rest[0])
+    else:
+        x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, y_ref, *rest = refs
+        save = len(rest) == 2
+        h_ref, ht_ref = rest if save else (None, rest[0])
     steps, r = dt_ref.shape[0], dt_ref.shape[1]
 
     @pl.when(pl.program_id(2) == 0)
@@ -235,7 +269,7 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
 
     def chunk(k, _):
         rows = pl.ds(pl.multiple_of(k * q, q), q)
-        y_rows = rows if save else slice(None)
+        y_rows = rows if save or not norm else slice(None)
         bm, cm = b_ref[rows, :], c_ref[rows, :]              # [Q,N]
         cd = bm.dtype
         dt_row, cs_row = dt_ref[k], cs_ref[k]                # [R,Q]
@@ -263,6 +297,8 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
                 + d_ref[:, lanes] * x_f
             xw = (x_f * _spread(f_col, heads, masks, x_f.shape)).astype(cd)
             ht_ref[:, lanes] = e_t[q - 1:q, :] * enter + _mm(bm, xw, _TN)
+        if not norm:
+            return
         # The gate and the group's norm: the group's lanes are the norm's.
         z = z_ref[rows, :].astype(F32)
         u = y_ref[y_rows, :] * (z * jax.nn.sigmoid(z))
@@ -272,19 +308,29 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
     lax.fori_loop(0, steps, chunk, None)
 
 
-def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
-                h_ref, y_ref, do_ref, dx_ref, db_ref, dc_ref, ddt_ref,
-                dcs_ref, dd_ref, dz_ref, dw_ref, dht_ref, dcs_col_ref,
-                df_col_ref, *, q: int, p: int, eps: float):
-    """The last two are ``[Q,128]`` scratch in which the heads' columns
-    are gathered, a lane a head, to be transposed into rows."""
+def _bwd_kernel(*refs, q: int, p: int, eps: float, norm: bool):
+    """With the norm (``norm``): ``x, b, c, dt, cs, d, z, w``, the entering
+    states, float32 ``y`` and ``do``; then ``dx, db, dc, ddt, dcs, dd, dz,
+    dw``.  Without it: ``x, b, c, dt, cs, d``, the entering states and
+    float32 ``dy``; then ``dx, db, dc, ddt, dcs, dd``.  Last the scratch:
+    ``dH`` and two ``[Q,128]`` in which the heads' columns are gathered, a
+    lane a head, to be transposed into rows."""
+    if norm:
+        (x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref, h_ref,
+         y_ref, do_ref, dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref, dd_ref,
+         dz_ref, dw_ref, dht_ref, dcs_col_ref, df_col_ref) = refs
+    else:
+        (x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, h_ref, dy_ref, dx_ref,
+         db_ref, dc_ref, ddt_ref, dcs_ref, dd_ref, dht_ref, dcs_col_ref,
+         df_col_ref) = refs
     steps, r = dt_ref.shape[0], dt_ref.shape[1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dht_ref[...] = jnp.zeros_like(dht_ref)
         dd_ref[...] = jnp.zeros_like(dd_ref)
-        dw_ref[...] = jnp.zeros_like(dw_ref)
+        if norm:
+            dw_ref[...] = jnp.zeros_like(dw_ref)
 
     causal = _causal(q)
     tiles = _lane_tiles(r, p, q)
@@ -292,21 +338,24 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
     def chunk(i, _):
         k = steps - 1 - i
         rows = pl.ds(pl.multiple_of(k * q, q), q)
-        # Through the norm and the gate: o = u rs w, u = y silu(z), rs =
-        # rsqrt(mean(u^2) + eps) over the group's lanes.
-        y = y_ref[rows, :]
-        z = z_ref[rows, :].astype(F32)
-        sig = jax.nn.sigmoid(z)
-        gate, dgate = z * sig, sig * (1.0 + z * (1.0 - sig))
-        u = y * gate
-        rs = lax.rsqrt(jnp.mean(u * u, axis=1, keepdims=True) + eps)
-        do = do_ref[rows, :].astype(F32)
-        dw_ref[...] += jnp.sum(do * (u * rs), axis=0, keepdims=True)
-        dn = do * w_ref[...]
-        du = rs * dn - u * (rs * rs * rs
-                            * jnp.mean(dn * u, axis=1, keepdims=True))
-        dz_ref[rows, :] = (du * y * dgate).astype(dz_ref.dtype)
-        dy = du * gate                                       # [Q,R*P]
+        if norm:
+            # Through the norm and the gate: o = u rs w, u = y silu(z), rs
+            # = rsqrt(mean(u^2) + eps) over the group's lanes.
+            y = y_ref[rows, :]
+            z = z_ref[rows, :].astype(F32)
+            sig = jax.nn.sigmoid(z)
+            gate, dgate = z * sig, sig * (1.0 + z * (1.0 - sig))
+            u = y * gate
+            rs = lax.rsqrt(jnp.mean(u * u, axis=1, keepdims=True) + eps)
+            do = do_ref[rows, :].astype(F32)
+            dw_ref[...] += jnp.sum(do * (u * rs), axis=0, keepdims=True)
+            dn = do * w_ref[...]
+            du = rs * dn - u * (rs * rs * rs
+                                * jnp.mean(dn * u, axis=1, keepdims=True))
+            dz_ref[rows, :] = (du * y * dgate).astype(dz_ref.dtype)
+            dy = du * gate                                   # [Q,R*P]
+        else:
+            dy = dy_ref[rows, :]
 
         bm, cm = b_ref[rows, :], c_ref[rows, :]
         cd = bm.dtype
@@ -385,10 +434,13 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, d_ref, z_ref, w_ref,
 
 def _check(x, dt, cs, b, c, d_row, z, w_row, g: int, q: int):
     """The flat operands' sizes: ``x``, ``z`` [B,T,H*P], ``b``/``c``
-    [B,T,G*N], ``d_row``/``w_row`` [1,H*P]."""
+    [B,T,G*N], ``d_row``/``w_row`` [1,H*P]; ``z`` and ``w_row`` None where
+    the norm is not the kernels'."""
     bsz, t, hp = x.shape
     h, n = dt.shape[-1], b.shape[-1] // g
     p = hp // h
+    if z is None:
+        z, w_row = x, d_row
     if dt.shape != (bsz, t, h) or cs.shape != dt.shape \
             or b.shape != (bsz, t, g * n) or c.shape != b.shape \
             or z.shape != x.shape or d_row.shape != (1, hp) \
@@ -416,13 +468,18 @@ def _from_rows(v):
     return v.transpose(0, 2, 4, 1, 3).reshape(bsz, nc * q, g * r)
 
 
-def _specs(q: int, rp: int, n: int, r: int, steps: int, block):
-    """Block specs over the grid ``(sequence, group, block of chunks)``;
-    ``block(i)`` is the block of chunks grid step ``i`` works on."""
+def _specs(q: int, rp: int, n: int, r: int, steps: int, block,
+           split: int = 1):
+    """Block specs over the grid ``(sequence, group, block of chunks)``
+    (``split`` > 1: ``(sequence, tile of a group's heads, block of
+    chunks)``, a group's ``B``, ``C`` read by each of its ``split``
+    tiles); ``block(i)`` is the block of chunks grid step ``i`` works on,
+    ``rp`` and ``r`` a grid step's lanes and heads."""
     wide = pl.BlockSpec((None, steps * q, rp),
                         lambda s, g, i: (s, block(i), g))
     state = pl.BlockSpec((None, steps * q, n),
-                         lambda s, g, i: (s, block(i), g))
+                         (lambda s, g, i: (s, block(i), g)) if split == 1
+                         else lambda s, j, i: (s, block(i), j // split))
     row = pl.BlockSpec((None, None, steps, r, q),
                        lambda s, g, i: (s, g, block(i), 0, 0))
     lane = pl.BlockSpec((1, rp), lambda s, g, i: (0, g))
@@ -438,76 +495,114 @@ def _params():
 
 
 def _forward(x, dt, cs, b, c, d_row, z, w_row, g: int, q: int, eps: float,
-             steps: int, save: bool, interpret: bool):
+             steps: int, save: bool, interpret: bool, split: int = 1):
     """The gated, normed ``o [B,T,H*P]`` in ``x``'s type and, where
     ``save``, float32 ``y [B,T,H*P]`` and the state entering each chunk
-    ``f32[B,G,nc,N,R*P]`` (else two None)."""
+    ``f32[B,G,nc,N,R*P]`` (else two None).  ``split`` > 1: a group's heads
+    in that many tiles (``z``, ``w_row`` None), no gate nor norm: float32
+    ``y`` and, where ``save``, the entering states ``f32[B,G*split,nc,N,
+    R*P/split]`` (else None)."""
     bsz, t, h, p, n = _check(x, dt, cs, b, c, d_row, z, w_row, g, q)
-    r, nc = h // g, t // q
+    r, nc = h // g // split, t // q
+    gs = g * split
     steps = _steps(nc, steps)
     wide, state, row, lane, enter = _specs(
-        q, r * p, n, r, steps, lambda i: i)
-    vma = _varies(x, dt, cs, b, c, d_row, z, w_row)
+        q, r * p, n, r, steps, lambda i: i, split)
+    vma = _varies(*(v for v in (x, dt, cs, b, c, d_row, z, w_row)
+                    if v is not None))
     out_specs = [wide]
-    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)]
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype if split == 1
+                                      else F32, vma=vma)]
     scratch = [pltpu.VMEM((n, r * p), F32)]
-    if save:
+    entering = jax.ShapeDtypeStruct((bsz, gs, nc, n, r * p), F32, vma=vma)
+    if split > 1:
+        operands = ()
+        if save:
+            out_specs.append(enter)
+            out_shape.append(entering)
+    elif save:
+        operands = (z, w_row)
         out_specs += [wide, enter]
-        out_shape += [
-            jax.ShapeDtypeStruct(x.shape, F32, vma=vma),
-            jax.ShapeDtypeStruct((bsz, g, nc, n, r * p), F32, vma=vma)]
+        out_shape += [jax.ShapeDtypeStruct(x.shape, F32, vma=vma), entering]
     else:
+        operands = (z, w_row)
         scratch.append(pltpu.VMEM((q, r * p), F32))
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, q=q, p=p, eps=eps),
-        grid=(bsz, g, nc // steps),
-        in_specs=[wide, state, state, row, row, lane, wide, lane],
+        functools.partial(_fwd_kernel, q=q, p=p, eps=eps, norm=split == 1),
+        grid=(bsz, gs, nc // steps),
+        in_specs=[wide, state, state, row, row, lane] + [wide, lane][
+            :len(operands)],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=_params(),
         name="ssd_fwd",
         interpret=interpret,
-    )(x, b, c, _rows(dt, g, q), _rows(cs, g, q), d_row, z, w_row)
+    )(x, b, c, _rows(dt, gs, q), _rows(cs, gs, q), d_row, *operands)
+    if split > 1:
+        return out[0], out[1] if save else None
     return tuple(out) if save else (out[0], None, None)
 
 
 def _backward(x, dt, cs, b, c, d_row, z, w_row, y, entering, do, g: int,
-              q: int, eps: float, steps: int, interpret: bool):
+              q: int, eps: float, steps: int, interpret: bool,
+              split: int = 1):
     """``(dx, ddt, dcs, db, dc, dd_row, dz, dw_row)``; ``db`` and ``dc``
-    are summed over a group's heads inside the kernel."""
+    are summed over a group's heads inside the kernel.  ``split`` > 1:
+    ``z``, ``w_row``, ``y`` None and ``do`` float32 ``dy``, no gate nor
+    norm: ``(dx, ddt, dcs, db, dc, dd_row)``, ``db`` and ``dc`` a tile's
+    float32 sums out of the kernel and added up over the group's tiles
+    here."""
     bsz, t, h, p, n = _check(x, dt, cs, b, c, d_row, z, w_row, g, q)
-    r, nc = h // g, t // q
+    r, nc = h // g // split, t // q
+    gs = g * split
     steps = _steps(nc, steps)
     blocks = nc // steps
     wide, state, row, lane, enter = _specs(
-        q, r * p, n, r, steps, lambda i: blocks - 1 - i)
+        q, r * p, n, r, steps, lambda i: blocks - 1 - i, split)
     sums = pl.BlockSpec((None, None, 1, r * p), lambda s, g, i: (s, g, 0, 0))
-    vma = _varies(x, dt, cs, b, c, d_row, z, w_row, y, entering, do)
+    vma = _varies(*(v for v in (x, dt, cs, b, c, d_row, z, w_row, y,
+                                entering, do) if v is not None))
 
     def shape(dims, dtype=F32):
         return jax.ShapeDtypeStruct(dims, dtype, vma=vma)
 
-    dx, db, dc, ddt, dcs, dd, dz, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, q=q, p=p, eps=eps),
-        grid=(bsz, g, blocks),
-        in_specs=[wide, state, state, row, row, lane, wide, lane, enter,
-                  wide, wide],
-        out_specs=[wide, state, state, row, row, sums, wide, sums],
-        out_shape=[shape(x.shape, x.dtype), shape(b.shape, b.dtype),
-                   shape(c.shape, c.dtype),
-                   shape((bsz, g, nc, r, q)), shape((bsz, g, nc, r, q)),
-                   shape((bsz, g, 1, r * p)), shape(z.shape, z.dtype),
-                   shape((bsz, g, 1, r * p))],
+    rows = (shape((bsz, gs, nc, r, q)), shape((bsz, gs, nc, r, q)),
+            shape((bsz, gs, 1, r * p)))
+    dt_rows, cs_rows = _rows(dt, gs, q), _rows(cs, gs, q)
+    if split == 1:
+        in_specs = [wide, state, state, row, row, lane, wide, lane, enter,
+                    wide, wide]
+        operands = (z, w_row, entering, y, do.astype(x.dtype))
+        out_specs = [wide, state, state, row, row, sums, wide, sums]
+        out_shape = [shape(x.shape, x.dtype), shape(b.shape, b.dtype),
+                     shape(c.shape, c.dtype), *rows,
+                     shape(z.shape, z.dtype), shape((bsz, g, 1, r * p))]
+    else:
+        in_specs = [wide, state, state, row, row, lane, enter, wide]
+        operands = (entering, do.astype(F32))
+        part = pl.BlockSpec((None, steps * q, n),
+                            lambda s, j, i: (s, blocks - 1 - i, j))
+        out_specs = [wide, part, part, row, row, sums]
+        out_shape = [shape(x.shape, x.dtype), shape((bsz, t, gs * n)),
+                     shape((bsz, t, gs * n)), *rows]
+    out = pl.pallas_call(
+        functools.partial(_bwd_kernel, q=q, p=p, eps=eps, norm=split == 1),
+        grid=(bsz, gs, blocks),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((n, r * p), F32),
                         pltpu.VMEM((q, _LANE), F32),
                         pltpu.VMEM((q, _LANE), F32)],
         compiler_params=_params(),
         name="ssd_bwd",
         interpret=interpret,
-    )(x, b, c, _rows(dt, g, q), _rows(cs, g, q), d_row, z, w_row, entering,
-      y, do.astype(x.dtype))
-    return (dx, _from_rows(ddt), _from_rows(dcs), db, dc,
-            dd.sum(axis=0).reshape(1, h * p), dz,
-            dw.sum(axis=0).reshape(1, h * p))
+    )(x, b, c, dt_rows, cs_rows, d_row, *operands)
+    dx, db, dc, ddt, dcs, dd = out[:6]
+    common = (dx, _from_rows(ddt), _from_rows(dcs))
+    if split > 1:
+        db, dc = (v.reshape(bsz, t, g, split, n).sum(axis=3).reshape(
+            b.shape).astype(b.dtype) for v in (db, dc))
+        return common + (db, dc, dd.sum(axis=0).reshape(1, h * p))
+    return common + (db, dc, dd.sum(axis=0).reshape(1, h * p), out[6],
+                     out[7].sum(axis=0).reshape(1, h * p))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
@@ -530,6 +625,30 @@ def _ssd_bwd(g, q, eps, interpret, res, do):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _scan_tiled(x, dt, cs, b, c, d_row, g: int, split: int, q: int,
+                interpret: bool):
+    """Float32 ``y`` of a group wider than a grid step, ``split`` tiles of
+    its heads: the scan alone (:func:`_forward`'s ``split``)."""
+    return _forward(x, dt, cs, b, c, d_row, None, None, g, q, 0.0,
+                    FWD_CHUNKS, False, interpret, split)[0]
+
+
+def _scan_tiled_fwd(x, dt, cs, b, c, d_row, g, split, q, interpret):
+    y, entering = _forward(x, dt, cs, b, c, d_row, None, None, g, q, 0.0,
+                           FWD_CHUNKS, True, interpret, split)
+    return y, (x, dt, cs, b, c, d_row, entering)
+
+
+def _scan_tiled_bwd(g, split, q, interpret, res, dy):
+    x, dt, cs, b, c, d_row, entering = res
+    return _backward(x, dt, cs, b, c, d_row, None, None, None, entering, dy,
+                     g, q, 0.0, BWD_CHUNKS, interpret, split)
+
+
+_scan_tiled.defvjp(_scan_tiled_fwd, _scan_tiled_bwd)
+
+
 def ssd_scan(x, dt, a, b, c, d_skip, z, weight, chunk: int, eps: float,
              interpret: bool = False):
     """``gated_norm(ssd_chunked(x, dt, a, b, c, d_skip), z, weight)`` of
@@ -537,8 +656,11 @@ def ssd_scan(x, dt, a, b, c, d_skip, z, weight, chunk: int, eps: float,
     ``b``/``c`` [B,T,G,N] in the compute type, ``dt`` [B,T,H] float32,
     ``a`` and ``d_skip`` [H], the gate ``z`` [B,T,H*P] and the norm's
     ``weight`` [H*P], whose groups are the scan's ``G``; the result
-    [B,T,H*P] in ``x``'s type.  ``T`` is whole chunks.  ``interpret`` runs
-    the kernels in the Pallas interpreter (the CPU tests)."""
+    [B,T,H*P] in ``x``'s type.  ``T`` is whole chunks.  A group of more
+    heads than a grid step takes (:func:`_head_tile`) is scanned a tile of
+    them at a time and gated and normed after the kernels, in XLA.
+    ``interpret`` runs the kernels in the Pallas interpreter (the CPU
+    tests)."""
     bsz, t, h, p = x.shape
     g, n = b.shape[2:]
     if dt.shape != (bsz, t, h) or b.shape != (bsz, t, g, n) \
@@ -562,7 +684,15 @@ def ssd_scan(x, dt, a, b, c, d_skip, z, weight, chunk: int, eps: float,
         missing = tuple(vma - jax.typeof(v).vma)
         return lax.pcast(v, missing, to="varying") if missing else v
 
-    d_row, w_row = row(jnp.repeat(d_skip, p)), row(weight)
+    d_row = row(jnp.repeat(d_skip, p))
+    split = h // g // _head_tile(h // g, p)
+    if split > 1:
+        from ..models.nemotron_h import gated_norm
+        y = _scan_tiled(x.reshape(bsz, t, h * p), dt, cs,
+                        b.reshape(bsz, t, g * n), c.reshape(bsz, t, g * n),
+                        d_row, g, split, chunk, interpret)
+        return gated_norm(y, z, weight, g, eps, x.dtype)
+    w_row = row(weight)
     # The kernels take a token's heads and groups flat, as the mixer's
     # projection leaves them: no [.., 64]-wide minor dimension exists.
     return _ssd(x.reshape(bsz, t, h * p), dt, cs, b.reshape(bsz, t, g * n),
@@ -628,29 +758,25 @@ def _vjp_of(path):
     return run
 
 
-def _self_check() -> None:
-    """On a TPU, at the token cell's shape (2 sequences of 8,192 tokens,
-    64 heads of 64 in 8 groups, state 128, chunks of 128, bf16): each
-    path's distance from the float32 answer (the result and the eight
-    gradients), milliseconds forward and forward plus backward for the
-    kernel and the XLA path, and the sweep of chunks a grid step.
-    Elsewhere: a small shape (three chunks) through the interpreter,
-    distances only.  Raises where the kernel is further from float32 than
-    the XLA path by more than a quarter."""
-    from ..models.nemotron_h import mamba_mixer
-    from ..parallel.mesh import make_mesh
-    from ..utils.platform import device_line, enable_compile_cache
+# (B, T, H, P, G, N, chunk) on a TPU and through the interpreter: the first
+# token cell's 8 groups of 8 heads, and the fifth's ONE group of 64 (two
+# grid steps of ``HEAD_TILE`` heads off the chip).
+SELF_CHECK_SHAPES = (((2, 8192, 64, 64, 8, 128, 128),
+                      (2, 384, 16, 64, 2, 128, 128)),
+                     ((2, 8192, 64, 64, 1, 128, 256),
+                      (2, 256, 16, 64, 1, 128, 128)))
+
+
+def _check_shape(bsz, t, h, p, g, n, q, *, on_chip: bool, sweep: bool):
+    """One shape of :func:`_self_check`; returns the mixer's path."""
+    from ..models.nemotron_h import gated_norm, mamba_mixer
     from .attention import _ms, _rel
 
-    enable_compile_cache()
-    print(device_line(make_mesh()), flush=True)
-    on_chip = _use_pallas()
-    bsz, t, h, p, g, n, q = (2, 8192, 64, 64, 8, 128, 128) if on_chip \
-        else (2, 384, 16, 64, 2, 128, 128)
     cd = jnp.bfloat16
+    split = h // g // _head_tile(h // g, p)
     print(f"ssd: B={bsz} T={t} H={h} P={p} G={g} N={n} chunk={q} "
           f"{jnp.dtype(cd).name} chunks a step fwd={FWD_CHUNKS} "
-          f"bwd={BWD_CHUNKS}", flush=True)
+          f"bwd={BWD_CHUNKS}, a group in {split} grid step(s)", flush=True)
     args, w = _operands(bsz, t, h, p, g, n)
     forward = {
         "kernel": lambda *a: ssd_scan(*a, q, _EPS, not on_chip),
@@ -662,6 +788,7 @@ def _self_check() -> None:
     dist = {name: [_rel(a, b) for a, b in zip(jax.jit(_vjp_of(fn))(low, w),
                                               exact)]
             for name, fn in forward.items()}
+    del exact
     print("ssd: distance from float32" + "".join(
         f"{name:>9}" for name in _NAMES))
     for name, d in dist.items():
@@ -678,6 +805,18 @@ def _self_check() -> None:
         for name, fn in forward.items():
             print(f"ssd:   {name:<8}{_ms(jax.jit(fn), *low):11.2f}"
                   f"{_ms(jax.jit(_vjp_of(fn)), low, w):11.2f}", flush=True)
+    if on_chip and split > 1:
+        # What the gate and the norm over a group wider than a grid step
+        # cost in XLA, beside the kernels' part.
+        y = jnp.zeros((bsz, t, h * p), F32)
+        x, dt, a, b, c, d, z, weight = low
+        norm = functools.partial(gated_norm, groups=g, eps=_EPS,
+                                 out_dtype=cd)
+        print(f"ssd:   {'norm':<8}{_ms(jax.jit(norm), y, z, weight):11.2f}"
+              f"{_ms(jax.jit(_vjp_of(norm)), (y, z, weight), w):11.2f}  "
+              "(the gate and the norm in XLA, of the kernel's line)",
+              flush=True)
+    if on_chip and sweep:
         x, dt, a, b, c, d, z, weight = low
         cs = jnp.cumsum((dt * a).reshape(bsz, t // q, q, h),
                         axis=2).reshape(bsz, t, h)
@@ -700,15 +839,40 @@ def _self_check() -> None:
                   f"{_ms(bw, *core, y, entering, w.astype(cd)):9.2f}",
                   flush=True)
     # The mixer itself at this shape: which path it is traced through.
+    before = dict(TRACED)
     dm, weights, x_in = _mixer_operands(bsz, t, h, p, g, n, q, cd)
     jax.eval_shape(lambda w, x: mamba_mixer(w, x, dm, cd), weights, x_in)
-    print(f"ssd: mixer traced through {TRACED}", flush=True)
-    if on_chip and TRACED != {"kernel": 1, "xla": 0}:
-        raise RuntimeError("mamba_mixer did not take the kernel at the "
-                           "token cell's shape on a TPU")
-    print(f"ssd: ok kernel={'pallas' if on_chip else 'interpret'} "
-          f"B={bsz} T={t} H={h} P={p} G={g} N={n} within a quarter of the "
-          f"XLA path's distance from float32", flush=True)
+    path = {k: TRACED[k] - before[k] for k in TRACED}
+    print(f"ssd: mixer traced through {path}", flush=True)
+    return path
+
+
+def _self_check() -> None:
+    """On a TPU, at the token cells' shapes (2 sequences of 8,192 tokens,
+    64 heads of 64 in 8 groups with chunks of 128 and in ONE group with
+    chunks of 256, state 128, bf16): each path's distance from the float32
+    answer (the result and the eight gradients), milliseconds forward and
+    forward plus backward for the kernel and the XLA path (at one group
+    also the gate and the norm alone, which follow the kernels in XLA
+    there), and at the first shape the sweep of chunks a grid step.
+    Elsewhere: small shapes through the interpreter, distances only.
+    Raises where the kernel is further from float32 than the XLA path by
+    more than a quarter, or, on a TPU, where the mixer did not take it."""
+    from ..parallel.mesh import make_mesh
+    from ..utils.platform import device_line, enable_compile_cache
+
+    enable_compile_cache()
+    print(device_line(make_mesh()), flush=True)
+    on_chip = _use_pallas()
+    for i, shapes in enumerate(SELF_CHECK_SHAPES):
+        shape = shapes[0 if on_chip else 1]
+        path = _check_shape(*shape, on_chip=on_chip, sweep=i == 0)
+        if on_chip and path != {"kernel": 1, "xla": 0}:
+            raise RuntimeError(f"mamba_mixer did not take the kernel at "
+                               f"{shape} on a TPU")
+        print(f"ssd: ok kernel={'pallas' if on_chip else 'interpret'} "
+              f"B, T, H, P, G, N, chunk = {shape} within a quarter of the "
+              f"XLA path's distance from float32", flush=True)
 
 
 if __name__ == "__main__":

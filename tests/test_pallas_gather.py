@@ -354,6 +354,40 @@ def test_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1100 * 2**20
 
 
+def test_one_group_scan_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, monkeypatch):
+    """The fifth token cell's Mamba-2 scan (ops/ssd.py; here because the
+    described chip's fixture lives in this file only): 2 sequences of
+    8,192 tokens, 64 heads of 64 in ONE group, state 128, chunks of 256 in
+    bf16, forward and backward, through Mosaic: both kernels are in the
+    program, a grid step holding ``HEAD_TILE`` heads of the group within
+    the VMEM limit the module sets (the whole group's blocks would need
+    three times it), and what is left for HBM is the entering states,
+    float32 ``y`` and its gate and norm, the tiles' partial ``dB``/``dC``,
+    not a ``[Q,Q]`` matrix a head a chunk (2.1 GB each)."""
+    from ddp_tpu.ops import ssd
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    bsz, t, h, p, g, n, q = 2, 8192, 64, 64, 1, 128, 256
+    assert ssd.kernel_applies(t, h, p, g, n, q, 2)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    args = (spec((bsz, t, h, p)), spec((bsz, t, h), f32), spec((h,), f32),
+            spec((bsz, t, g, n)), spec((bsz, t, g, n)), spec((h,), f32),
+            spec((bsz, t, h * p)), spec((h * p,), f32))
+    compiled = jax.jit(ssd._vjp_of(
+        lambda *a: ssd.ssd_scan(*a, q, 1e-5))).lower(
+            args, spec((bsz, t, h * p))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    # The entering states are 268 MB, float32 y and dy 268 MB each, the
+    # gate's and norm's float32 passes as much again.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2000 * 2**20
+
+
 def test_selscan_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
                                                             monkeypatch):
     """The second token cell's Mamba-1 selective scan (ops/selscan.py; here

@@ -2,7 +2,9 @@
 Pallas interpreter at small shapes: against ``ssd_chunked`` and
 ``gated_norm``, which they replace on the chip, and against the
 reference's step-by-step recurrence, the result and the eight gradients;
-the sum over a group's heads; the lane tiles;
+the sum over a group's heads; a group wider than a grid step, a tile of
+its heads at a time, one group of 16 heads as the cell's one group of 64;
+the lane tiles;
 a call inside ``shard_map``; who takes which path.  (The kernels at the
 cell's shape through the TPU's compiler: tests/test_pallas_gather.py,
 where the described chip's fixture lives; the classifier cells' fresh
@@ -31,8 +33,10 @@ from ddp_tpu.parallel.mesh import DATA_AXIS, make_mesh  # noqa: E402
 Q, N = 128, 128
 NAMES = ssd._NAMES   # o, dx, ddt, da, db, dc, dd, dz, dw
 EPS = ssd._EPS
-# Heads of 64 two a lane tile, a head a tile, four a tile; one group and two.
-SHAPES = {"p64": (4, 64, 2), "p128": (2, 128, 1), "p32": (8, 32, 2)}
+# Heads of 64 two a lane tile, a head a tile, four a tile; one group and two;
+# one group of 16 heads of 64, two grid steps of ``ssd.HEAD_TILE`` heads.
+SHAPES = {"p64": (4, 64, 2), "p128": (2, 128, 1), "p32": (8, 32, 2),
+          "g1": (16, 64, 1)}
 
 
 def rel(a, b):
@@ -77,7 +81,8 @@ def steps(monkeypatch, request):
 
 @pytest.mark.parametrize("t,shape,steps", [
     (128, "p64", (1, 1)), (384, "p64", (1, 1)), (512, "p64", (2, 4)),
-    (384, "p128", (4, 2)), (256, "p32", (2, 2))], indirect=["steps"])
+    (384, "p128", (4, 2)), (256, "p32", (2, 2)), (384, "g1", (1, 1)),
+    (512, "g1", (2, 4))], indirect=["steps"])
 def test_float32_matches_the_chunked_form_and_the_recurrence(t, shape,
                                                              steps):
     """Several chunks a sequence and several grid steps: the state is
@@ -97,7 +102,7 @@ def test_float32_matches_the_chunked_form_and_the_recurrence(t, shape,
 
 @pytest.mark.parametrize("t,shape,steps", [
     (384, "p64", (1, 1)), (512, "p64", (4, 2)), (256, "p128", (2, 2)),
-    (256, "p32", (2, 2))], indirect=["steps"])
+    (256, "p32", (2, 2)), (384, "g1", (1, 1))], indirect=["steps"])
 def test_bf16_is_no_further_from_float32_than_the_chunked_form(t, shape,
                                                                steps):
     """The kernel's precision is ``ssd_chunked``'s: operands in bf16 where
@@ -140,6 +145,39 @@ def test_db_dc_are_summed_over_the_groups_heads(steps):
     assert rel(db, db_h.sum(axis=3)) < 1e-5
     assert rel(dc, dc_h.sum(axis=3)) < 1e-5
     assert rel(db, db_h[:, :, :, 0]) > 0.1
+
+
+@pytest.mark.parametrize("h,tile", [(16, 8), (8, 2), (8, 4)])
+def test_one_group_wider_than_a_grid_step(h, tile, steps, monkeypatch):
+    """One group of ``h`` heads in tiles of ``tile`` heads (2 to 4 grid
+    steps a group): every tile reads the group's one ``B``, ``C``, whose
+    ``dB``/``dC`` are the sums over EVERY head of the group (the XLA path
+    with ``B``, ``C`` repeated a head, the norm's one group over all
+    ``h * 64`` lanes left as it is), and the result and the eight
+    gradients are the recurrence's."""
+    monkeypatch.setattr(ssd, "HEAD_TILE", tile)
+    assert ssd._head_tile(h, 64) == tile
+    args, w = ssd._operands(2, 256, h, 64, 1, N, seed=3)
+    got = out_and_grads(kernel, args, w)
+    for name, g, exact in zip(NAMES, got, out_and_grads(recurrence, args,
+                                                        w)):
+        assert rel(g, exact) < (1e-4 if name in ("ddt", "da") else 2e-5), \
+            (name, rel(g, exact))
+    x, dt, a, b, c, d, z, weight = args
+
+    def a_copy_a_head(b_h, c_h):
+        y = sysm.ssd_chunked(x, dt, a, b_h, c_h, d, Q, jnp.float32)
+        return sysm.gated_norm(y.reshape(z.shape), z, weight, 1, EPS,
+                               jnp.float32)
+
+    _, pull = jax.vjp(a_copy_a_head, jnp.repeat(b, h, axis=2),
+                      jnp.repeat(c, h, axis=2))
+    db_h, dc_h = pull(w)
+    db, dc = got[NAMES.index("db")], got[NAMES.index("dc")]
+    assert rel(db[:, :, 0], db_h.sum(axis=2)) < 1e-5
+    assert rel(dc[:, :, 0], dc_h.sum(axis=2)) < 1e-5
+    # A tile's heads alone are not the sum.
+    assert rel(db[:, :, 0], db_h[:, :, :tile].sum(axis=2)) > 0.1
 
 
 @pytest.mark.parametrize("r,p", [(8, 64), (2, 128), (1, 256), (16, 32),
@@ -303,3 +341,148 @@ def test_kernel_applies_at_the_cells_shape_and_the_mixer_takes_it(
     assert not ssd.kernel_applies(8192, 64, 64, 8, 128, 1024, 4)
     monkeypatch.setattr(ssd, "_use_pallas", lambda: False)
     assert not ssd.kernel_applies(8192, 64, 64, 8, 128, 128, 2)
+
+
+def test_kernel_applies_at_one_group_and_the_mixer_takes_it(monkeypatch):
+    """The one-group cell's shape (8,192 tokens, 64 heads of 64 in ONE
+    group, state 128, chunks of 256) passes in bf16 at the real constants,
+    a grid step holding ``HEAD_TILE`` heads (26 MiB by the VMEM count
+    against the whole group's 196 MiB); the mixer at one group takes the
+    tiled kernels and gates and norms over all of a token's lanes after
+    them."""
+    monkeypatch.setattr(ssd, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ssd, "TRACED", {"kernel": 0, "xla": 0})
+    assert ssd.kernel_applies(8192, 64, 64, 1, 128, 256, 2)
+    assert ssd._head_tile(64, 64) == ssd.HEAD_TILE == 8
+    assert ssd._vmem_bytes(256, 8 * 64, 128, 4, 2) < 27 * 2**20 \
+        < 3 * ssd.VMEM_LIMIT_BYTES < ssd._vmem_bytes(256, 64 * 64, 128, 4, 2)
+    grad, args = _mixer_grad(sysm.mamba_mixer, 256, 16, 64, 1, 128, 128)
+    text = str(jax.make_jaxpr(grad)(*args))
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert ssd.TRACED == {"kernel": 1, "xla": 0}
+
+
+# -- the first token cell's programs are the parent's ------------------------------
+
+GOLDEN = os.path.join(ROOT, "tests", "golden", "ssd_kernels.json")
+# (tokens, heads, head width, groups, state, chunk) of the first token
+# cell's layer, in bf16: 8 groups of 8 heads, a group within a grid step.
+FIRST_CELL = (8192, 64, 64, 8, 128, 128)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def kernel_fingerprint(t, h, p, g, n, q):
+    """What ``ssd_scan``'s two ``pallas_call`` sites hold at this shape,
+    forward and backward (tests/test_attention_kernel.py's measure): each
+    kernel's body as a jaxpr (which prints no source location), its grid,
+    every operand's block shape and index map, the scratch and the
+    compiler's parameters."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def spec(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    args = (spec((2, t, h, p)), spec((2, t, h), f32), spec((h,), f32),
+            spec((2, t, g, n)), spec((2, t, g, n)), spec((h,), f32),
+            spec((2, t, h * p)), spec((h * p,), f32))
+    jaxpr = jax.make_jaxpr(ssd._vjp_of(lambda *a: ssd.ssd_scan(
+        *a, q, EPS)))(args, spec((2, t, h * p))).jaxpr
+    calls = {}
+    for eqn in _pallas_calls(jaxpr):
+        grid = eqn.params["grid_mapping"]
+        calls[str(eqn.params["name"])] = {
+            "grid": [int(s) for s in grid.grid],
+            "blocks": [[str(b.block_shape), str(b.index_map_jaxpr)]
+                       for b in grid.block_mappings],
+            "operands": [str(v.aval) for v in eqn.params["jaxpr"].invars],
+            "scratch_operands": int(grid.num_scratch_operands),
+            "results": [str(a) for a in eqn.params["out_avals"]],
+            "compiler_params": str(eqn.params["compiler_params"]),
+            "body": str(eqn.params["jaxpr"]),
+        }
+    return calls
+
+
+def nemotron_step_text(cd):
+    """The lowered text of the tiny ``nemotron_h`` (9 layers, every kind)
+    training step on one device, locations stripped: what the model runs
+    off the chip, where no kernel applies."""
+    from ddp_tpu.models import get_model
+    from ddp_tpu.optim.sgd import SGDConfig
+    from ddp_tpu.train.step import init_train_state, make_train_step
+    config = dict(
+        hidden_size=64, norm_eps=1e-5, mamba_num_heads=8, mamba_head_dim=8,
+        n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=32,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        n_routed_experts=4, router_experts=16, experts_held=[4, 4],
+        num_experts_per_tok=6, moe_intermediate_size=48,
+        moe_shared_expert_intermediate_size=96, routed_scaling_factor=2.5,
+        norm_topk_prob=True, vocab_size=256, num_hidden_layers=9,
+        hybrid_override_pattern="MEMEM*EME", time_step_min=0.001,
+        time_step_max=0.1, time_step_floor=1e-4)
+    model = get_model("nemotron_h", config)
+    state = jax.eval_shape(
+        lambda: init_train_state(*model.init(jax.random.key(0))))
+    step = make_train_step(model, SGDConfig(), lambda s: 0.1, make_mesh(1),
+                           compute_dtype=cd)
+    ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    text = step.lower(state, {"image": ids, "label": ids},
+                      jax.random.key(0)).as_text()
+    return re.sub(r"loc\(.*?\)", "", text)
+
+
+def _golden():
+    import hashlib
+    return {"first_cell_kernels": kernel_fingerprint(*FIRST_CELL),
+            "nemotron_h_tiny_step_sha256": {
+                name: hashlib.sha256(nemotron_step_text(cd).encode())
+                .hexdigest() for name, cd in (("f32", None),
+                                              ("bf16", jnp.bfloat16))}}
+
+
+def test_the_first_cells_kernels_are_the_parents():
+    """A group within a grid step (the first token cell's 8 heads) runs the
+    kernels it ran before groups wider than a grid step arrived: body,
+    grid, block specs and scratch equal the golden written from the parent
+    commit (``python tests/test_ssd_kernel.py`` rewrites it from whatever
+    ``ddp_tpu`` is on the path)."""
+    import json
+    with open(GOLDEN) as f:
+        golden = json.load(f)["first_cell_kernels"]
+    got = kernel_fingerprint(*FIRST_CELL)
+    assert sorted(got) == sorted(golden) == ["ssd_bwd", "ssd_fwd"]
+    for name in got:
+        for part, value in got[name].items():
+            assert value == golden[name][part], (name, part)
+
+
+@pytest.mark.parametrize("cd", [None, jnp.bfloat16], ids=["f32", "bf16"])
+def test_nemotron_h_tiny_step_is_the_parents(cd):
+    """``nemotron_h``'s whole training step at its tiny size lowers to the
+    parent's text, in float32 and bf16: the mixers it shares with
+    ``granite_hybrid`` took sizes and the attention's scale through
+    ``dims`` without moving it."""
+    import hashlib
+    import json
+    with open(GOLDEN) as f:
+        golden = json.load(f)["nemotron_h_tiny_step_sha256"]
+    name = "f32" if cd is None else "bf16"
+    assert hashlib.sha256(nemotron_step_text(cd).encode()).hexdigest() \
+        == golden[name]
+
+
+if __name__ == "__main__":
+    import json
+    with open(GOLDEN, "w") as f:
+        json.dump(_golden(), f, indent=1, sort_keys=True)
+        f.write("\n")
+    print("wrote", GOLDEN, "from", ssd.__file__)
