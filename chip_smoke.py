@@ -12,6 +12,8 @@ per chip; weights random from a seed, depth of the run cut to 8 steps):
                                                   against the XLA path and float32
     selscan    python -m ddp_tpu.ops.selscan      the second token model's scan kernels
                                                   against the XLA path and float32
+    causal_conv  python -m ddp_tpu.ops.causal_conv  the state-space mixers' convolution
+                                                  kernels against the XLA form and float32
     moe        python -m ddp_tpu.models.moe       the routed half (gather, products, scatter-add)
                                                   over the live row tiles against the
                                                   whole-buffer form
@@ -438,9 +440,9 @@ class Smoke:
         return f"loss {losses[0]:.4f} -> {losses[-1]:.4f}"
 
 
-PHASES = ("gather", "attention", "ssd", "selscan", "moe", "sambay", "train",
-          "train_again", "serve", "bf16", "resident", "shard_update",
-          "resume", "lm", "generate")
+PHASES = ("gather", "attention", "ssd", "selscan", "causal_conv", "moe",
+          "sambay", "train", "train_again", "serve", "bf16", "resident",
+          "shard_update", "resume", "lm", "generate")
 
 
 def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
@@ -457,6 +459,7 @@ def run_smoke(platform: str, *, model: str = "vgg", batch: int = 512,
         "attention": lambda: s.kernel_check("attention"),
         "ssd": lambda: s.kernel_check("ssd"),
         "selscan": lambda: s.kernel_check("selscan"),
+        "causal_conv": lambda: s.kernel_check("causal_conv"),
         "moe": s.moe,
         "sambay": s.sambay,
         "train": lambda: s.train_dp("train"),

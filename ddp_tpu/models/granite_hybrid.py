@@ -40,7 +40,7 @@ bf16 stream rounds away, twenty times a step: that put the first step
 written out once (:func:`_normed`).  No state is carried (the state
 pytree stays empty).
 :data:`TRACED` tallies the layers traced by kind and which path
-took each scan and each attention core.
+took each scan, each convolution and each attention core.
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import attention, ssd
+from ..ops import attention, causal_conv, ssd
 from ..ops.layers import linear
 from ..ops.seq import rms_norm
 from . import nemotron_h
@@ -59,7 +59,7 @@ from . import nemotron_h
 F32 = jnp.float32
 
 TRACED = {"mamba": 0, "attention": 0, "scan_kernel": 0, "scan_xla": 0,
-          "core_kernel": 0, "core_xla": 0}
+          "conv_kernel": 0, "conv_xla": 0, "core_kernel": 0, "core_xla": 0}
 
 
 def dims(config: dict) -> dict:
@@ -140,8 +140,8 @@ def _normed(x, weight, dm: dict, cd):
 def _block(kind: str, p, x, dm: dict, cd):
     u = _normed(x, p["norm1"], dm, cd)
     if kind == "mamba":
-        y = _tally("scan", ssd.TRACED,
-                   lambda: nemotron_h.mamba_mixer(p, u, dm, cd))
+        y = _tally("conv", causal_conv.TRACED, lambda: _tally(
+            "scan", ssd.TRACED, lambda: nemotron_h.mamba_mixer(p, u, dm, cd)))
     else:
         y = _tally("core", attention.TRACED,
                    lambda: nemotron_h.attention_mixer(p, u, dm, cd))
@@ -212,8 +212,8 @@ def _main(argv) -> int:
     """``python -m ddp_tpu.models.granite_hybrid CONFIG``: trace one
     training step's forward and backward at the file's widths and
     ``seq_len`` (two sequences, bf16; shapes only, nothing runs) and print
-    :data:`TRACED`.  On a TPU it fails unless every scan took the
-    kernels."""
+    :data:`TRACED`.  On a TPU it fails unless every scan and every
+    convolution took the kernels."""
     import json
     import sys
 
@@ -236,6 +236,10 @@ def _main(argv) -> int:
           f"{TRACED}", flush=True)
     if _use_pallas() and TRACED["scan_xla"]:
         print("granite_hybrid: a scan took ssd_chunked on a TPU",
+              file=sys.stderr)
+        return 1
+    if _use_pallas() and TRACED["conv_xla"]:
+        print("granite_hybrid: a convolution took the XLA form on a TPU",
               file=sys.stderr)
         return 1
     return 0

@@ -14,7 +14,9 @@ character of ``hybrid_override_pattern``:
 - ``M``, a Mamba-2 mixer in the chunked form: products inside chunks of
   ``chunk_size`` tokens and a recurrence over the chunks' states: the
   blocked kernels of ops/ssd.py where they apply (a TPU, whole chunks and
-  lanes), else plain XLA with a ``lax.scan`` over the chunks.
+  lanes), else plain XLA with a ``lax.scan`` over the chunks; its causal
+  convolution through the kernel pair of ops/causal_conv.py where that
+  applies (``causal_conv.TRACED`` tallies the path), else plain XLA.
 - ``*``, grouped-query causal attention without positional encoding:
   the blocked kernel of ops/attention.py where it applies (a TPU, whole
   blocks), else a block of queries at a time against the keys before it.

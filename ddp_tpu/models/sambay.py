@@ -58,10 +58,13 @@ difference: the same sum).
 The trace-time tallies of :data:`TRACED` say which attention pattern and
 how many scans the compiled program holds and how many attention cores
 went through the kernel pair (``core_kernel``) and through the loop
-(``core_xla``), and ``ops/selscan.py:TRACED`` how many of the scans went
-through the kernel (the model has no data-dependent event to count, so no
-counter rides its state).  On the chip the cell's program reads
-``core_kernel`` 3, ``core_xla`` 0.
+(``core_xla``), how many of the mixers' convolutions went through the
+kernel pair of ``ops/causal_conv.py`` (``conv_kernel``) and through the
+XLA form (``conv_xla``), and ``ops/selscan.py:TRACED`` how many of the
+scans went through the kernel (the model has no data-dependent event to
+count, so no counter rides its state).  On the chip the cell's program
+reads ``core_kernel`` 3, ``core_xla`` 0, ``conv_kernel`` 2,
+``conv_xla`` 0.
 """
 from __future__ import annotations
 
@@ -73,7 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import attention, selscan, seq
+from ..ops import attention, causal_conv, selscan, seq
 from ..ops.layers import linear
 
 F32 = jnp.float32
@@ -87,9 +90,10 @@ ATTN_QUERY_BLOCK = 1024
 SCAN_CHUNK = 128
 _TN = (((0,), (0,)), ((), ()))
 
-# Layers traced by kind, and which path took an attention layer's core.
+# Layers traced by kind, and which path took an attention layer's core and
+# a Mamba layer's convolution.
 TRACED = {"mamba": 0, "window": 0, "full": 0, "cross": 0, "gmu": 0,
-          "core_kernel": 0, "core_xla": 0}
+          "core_kernel": 0, "core_xla": 0, "conv_kernel": 0, "conv_xla": 0}
 
 
 def kind_of(l: int, half: int) -> str:
@@ -206,7 +210,10 @@ def mamba_mixer(p, u, dm: dict, cd):
     with jax.named_scope("ssm_proj"):
         xs, z = jnp.split(linear(u, p["in_proj"].astype(cd)), 2, axis=-1)
     with jax.named_scope("ssm_conv"):
+        before = dict(causal_conv.TRACED)
         xs = seq.causal_conv_silu(xs, p["conv_w"], p["conv_b"], cd)
+        for path in ("kernel", "xla"):
+            TRACED[f"conv_{path}"] += causal_conv.TRACED[path] - before[path]
     with jax.named_scope("ssm_proj"):
         rbc = linear(xs, p["x_proj"].astype(cd))
         dt = jnp.matmul(rbc[..., :r], p["dt_proj"].astype(cd),
@@ -479,8 +486,8 @@ def _self_check(config_file: str) -> None:
     backend and, on a TPU, at the file's own widths and sequence length.
     Checks the logits' shape, that the loss starts near ``ln V``, that
     loss and every updated parameter are finite and, at the file's widths,
-    that every attention core took the kernel pair; prints the step
-    program's tallies.  Raises otherwise."""
+    that every attention core and every convolution took its kernel pair;
+    prints the step program's tallies.  Raises otherwise."""
     import json
     import time
 
@@ -541,6 +548,9 @@ def _self_check(config_file: str) -> None:
         if tag != "tiny" and TRACED["core_xla"]:
             raise RuntimeError(f"sambay ({tag}): {TRACED['core_xla']} "
                                "attention cores took the XLA loop on a TPU")
+        if tag != "tiny" and TRACED["conv_xla"]:
+            raise RuntimeError(f"sambay ({tag}): {TRACED['conv_xla']} "
+                               "convolutions took the XLA form on a TPU")
         del state
     print(f"sambay: ok steps={len(runs)} "
           + " ".join(f"[{tag}]" for tag, _ in runs), flush=True)

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import causal_conv
+
 F32 = jnp.float32
 
 
@@ -28,7 +30,20 @@ def rms_norm(x, weight, eps: float, out_dtype):
 def causal_conv_silu(x, w, b, cd):
     """``silu`` of the causal depthwise convolution over time, in float32:
     ``x`` [B,T,C], ``w`` [k,C] (tap k-1 multiplies the current position),
-    ``b`` [C]."""
+    ``b`` [C].  Through the kernel pair of ``ops/causal_conv.py`` where it
+    applies, else in plain XLA."""
+    k, t = w.shape[0], x.shape[1]
+    if causal_conv.kernel_applies(t, x.shape[2], k,
+                                  jnp.dtype(x.dtype).itemsize):
+        causal_conv.TRACED["kernel"] += 1
+        return causal_conv.conv_silu(x, w, b, cd)
+    causal_conv.TRACED["xla"] += 1
+    return conv_silu_xla(x, w, b, cd)
+
+
+def conv_silu_xla(x, w, b, cd):
+    """:func:`causal_conv_silu` in plain XLA: the input padded as float32,
+    four shifted products summed, ``silu``, the cast."""
     k, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
     x = sum(padded[:, i:i + t] * w[i] for i in range(k)) + b

@@ -768,8 +768,10 @@ SELF_CHECK_SHAPES = (((2, 8192, 64, 64, 8, 128, 128),
 
 
 def _check_shape(bsz, t, h, p, g, n, q, *, on_chip: bool, sweep: bool):
-    """One shape of :func:`_self_check`; returns the mixer's path."""
+    """One shape of :func:`_self_check`; returns the mixer's paths: the
+    scan's and (``conv_``) its convolution's."""
     from ..models.nemotron_h import gated_norm, mamba_mixer
+    from . import causal_conv
     from .attention import _ms, _rel
 
     cd = jnp.bfloat16
@@ -838,11 +840,14 @@ def _check_shape(bsz, t, h, p, g, n, q, *, on_chip: bool, sweep: bool):
                   f"{_ms(fs, *core):9.2f}"
                   f"{_ms(bw, *core, y, entering, w.astype(cd)):9.2f}",
                   flush=True)
-    # The mixer itself at this shape: which path it is traced through.
-    before = dict(TRACED)
+    # The mixer itself at this shape: which path its scan and its
+    # convolution are traced through.
+    before, conv_before = dict(TRACED), dict(causal_conv.TRACED)
     dm, weights, x_in = _mixer_operands(bsz, t, h, p, g, n, q, cd)
     jax.eval_shape(lambda w, x: mamba_mixer(w, x, dm, cd), weights, x_in)
     path = {k: TRACED[k] - before[k] for k in TRACED}
+    path.update({f"conv_{k}": causal_conv.TRACED[k] - conv_before[k]
+                 for k in causal_conv.TRACED})
     print(f"ssd: mixer traced through {path}", flush=True)
     return path
 
@@ -857,7 +862,8 @@ def _self_check() -> None:
     there), and at the first shape the sweep of chunks a grid step.
     Elsewhere: small shapes through the interpreter, distances only.
     Raises where the kernel is further from float32 than the XLA path by
-    more than a quarter, or, on a TPU, where the mixer did not take it."""
+    more than a quarter, or, on a TPU, where the mixer did not take it or
+    its convolution did not take ops/causal_conv.py's."""
     from ..parallel.mesh import make_mesh
     from ..utils.platform import device_line, enable_compile_cache
 
@@ -867,8 +873,9 @@ def _self_check() -> None:
     for i, shapes in enumerate(SELF_CHECK_SHAPES):
         shape = shapes[0 if on_chip else 1]
         path = _check_shape(*shape, on_chip=on_chip, sweep=i == 0)
-        if on_chip and path != {"kernel": 1, "xla": 0}:
-            raise RuntimeError(f"mamba_mixer did not take the kernel at "
+        if on_chip and path != {"kernel": 1, "xla": 0, "conv_kernel": 1,
+                                "conv_xla": 0}:
+            raise RuntimeError(f"mamba_mixer did not take the kernels at "
                                f"{shape} on a TPU")
         print(f"ssd: ok kernel={'pallas' if on_chip else 'interpret'} "
               f"B, T, H, P, G, N, chunk = {shape} within a quarter of the "

@@ -145,7 +145,8 @@ def test_matches_reference(groups, cd, traced):
         assert max(errs.values()) < 0.2, max(errs, key=errs.get)
         assert sorted(errs.values())[len(errs) // 2] < 0.05
     assert traced == {"mamba": 9, "attention": 1, "scan_kernel": 0,
-                      "scan_xla": 9, "core_kernel": 0, "core_xla": 1}
+                      "scan_xla": 9, "conv_kernel": 0, "conv_xla": 9,
+                      "core_kernel": 0, "core_xla": 1}
 
 
 @pytest.mark.parametrize("heads", [16, 24])
@@ -165,7 +166,8 @@ def test_kernel_path_matches_reference(heads, traced, monkeypatch):
     loss, grads, logits = system_loss_and_grads(config, params, state, ids,
                                                 targets)
     assert traced == {"mamba": 1, "attention": 1, "scan_kernel": 1,
-                      "scan_xla": 0, "core_kernel": 0, "core_xla": 1}
+                      "scan_xla": 0, "conv_kernel": 0, "conv_xla": 1,
+                      "core_kernel": 0, "core_xla": 1}
     r_loss, r_grads, r_logits = reference_loss_and_grads(
         config, params, state, ids, targets)
     assert abs(float(loss) - r_loss) < 2e-5
@@ -390,16 +392,19 @@ def test_the_cli_trains_the_model(tmp_path):
 def test_every_scan_takes_the_kernel_at_the_cells_widths(monkeypatch):
     """``python -m ddp_tpu.models.granite_hybrid FILE`` at the file's
     widths and 8,192 tokens, as a TPU would see it (traced, nothing run):
-    nine scans through the kernels, none through ``ssd_chunked``, the one
-    attention core on the XLA loop (heads 64 wide)."""
-    from ddp_tpu.ops import attention, gather
-    for module in (ssd, attention, gather):
+    nine scans and nine convolutions through the kernels, none through
+    ``ssd_chunked`` or the XLA form, the one attention core on the XLA
+    loop (heads 64 wide)."""
+    from ddp_tpu.ops import attention, causal_conv, gather
+    for module in (ssd, attention, gather, causal_conv):
         monkeypatch.setattr(module, "_use_pallas", lambda: True)
     monkeypatch.setattr(ssd, "TRACED", {"kernel": 0, "xla": 0})
+    monkeypatch.setattr(causal_conv, "TRACED", {"kernel": 0, "xla": 0})
     monkeypatch.setattr(sysm, "TRACED", dict.fromkeys(sysm.TRACED, 0))
     assert sysm._main([CONFIG_FILE]) == 0
     assert sysm.TRACED == {"mamba": 9, "attention": 1, "scan_kernel": 9,
-                           "scan_xla": 0, "core_kernel": 0, "core_xla": 1}
+                           "scan_xla": 0, "conv_kernel": 9, "conv_xla": 0,
+                           "core_kernel": 0, "core_xla": 1}
 
 
 # -- (e) the counts, the operations, the file --------------------------------------

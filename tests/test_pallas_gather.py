@@ -454,3 +454,34 @@ def test_routed_half_compiles_for_v5e_adding_in_place(one_chip, monkeypatch,
     assert text.count("tpu_custom_call") >= 2 and "add_rows" in text
     assert acc in text and moe_probe.accumulator_copies(text, acc) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("c", [4352, 6144, 5120])
+def test_conv_kernels_compile_for_v5e_at_the_cells_shape(one_chip,
+                                                          monkeypatch, c):
+    """The state-space mixers' causal convolution (ops/causal_conv.py; here
+    because the described chip's fixture lives in this file only) at the
+    three cells' shapes, 2 sequences of 8,192 tokens of 4,352 / 6,144 /
+    5,120 channels in bf16, four taps, at the module's constants, forward
+    and backward through ``ops/seq.py``'s dispatch: both kernels are in the
+    program within the VMEM limit the module sets, and nothing of the
+    convolution's size is left for HBM (no float32 residual)."""
+    import functools
+
+    from ddp_tpu.ops import causal_conv, seq
+    monkeypatch.setattr(causal_conv, "_use_pallas", lambda: True)
+    shape = (2, 8192, c)
+    assert causal_conv.kernel_applies(8192, c, 4, 2)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    run = causal_conv._vjp_of(functools.partial(seq.causal_conv_silu,
+                                                cd=jnp.bfloat16))
+    compiled = jax.jit(run).lower(
+        (spec(shape), spec((4, c), jnp.float32), spec((c,), jnp.float32)),
+        spec(shape)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

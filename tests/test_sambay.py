@@ -132,7 +132,8 @@ def test_matches_reference(config, cd, monkeypatch):
     attention_layers = sum(kinds.count(k) for k in ("window", "full", "cross"))
     assert sysm.TRACED == {
         **{k: kinds.count(k) for k in sysm.TRACED},
-        "core_kernel": 0, "core_xla": attention_layers}
+        "core_kernel": 0, "core_xla": attention_layers,
+        "conv_kernel": 0, "conv_xla": kinds.count("mamba")}
     with jax.default_matmul_precision("highest"):
         r_loss, r_grads, _ = ref.loss_and_grads(
             config, params, state, np.asarray(ids), np.asarray(targets))
